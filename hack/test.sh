@@ -31,7 +31,7 @@ python -m compileall -q kubernetes_tpu tests bench.py hack
 
 # kube-vet: the govet analog (ref: hack/test-go.sh gating on govet).
 # Invariant rules in kubernetes_tpu/analysis (donation-safety, clone-
-# mutation, thread-discipline, py310-compat, metrics-sync, unused) over
+# mutation, thread-discipline, metrics-sync, unused) over
 # the whole tree; waivers require a rule id + reason. Also enforced as
 # a tier-1 test (tests/test_vet.py::test_tree_is_vet_clean).
 echo "=== kube-vet (hack/vet.py) ==="

@@ -10,7 +10,7 @@ Usage::
 
     python hack/vet.py                      # whole tree, all rules
     python hack/vet.py path/to/file.py ...  # specific files
-    python hack/vet.py --rules unused,py310-compat
+    python hack/vet.py --rules unused,metrics-sync
     python hack/vet.py --list-rules
     python hack/vet.py --show-waived        # audit every active waiver
     python hack/vet.py --json               # machine-readable findings

@@ -177,7 +177,7 @@ def load_context(path: str, root: str) -> FileContext:
 
 _SKIP_DIRS = {"__pycache__", ".git", ".ktpu_cache", "www", "node_modules"}
 _DEFAULT_TOPS = ("kubernetes_tpu", "hack", "tests", "examples", "native")
-_DEFAULT_FILES = ("bench.py",)
+_DEFAULT_FILES = ("bench.py", "chip_smoke.py")
 
 
 def default_paths(root: str) -> List[str]:

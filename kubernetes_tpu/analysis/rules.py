@@ -17,7 +17,7 @@ from kubernetes_tpu.analysis.engine import (FileContext, Rule, Violation,
                                             register)
 
 __all__ = ["DonationSafetyRule", "CloneMutationRule", "ThreadDisciplineRule",
-           "Py310CompatRule", "MetricsSyncRule", "UnusedNamesRule"]
+           "MetricsSyncRule", "UnusedNamesRule"]
 
 
 def _parent_map(tree: ast.AST) -> Dict[ast.AST, ast.AST]:
@@ -458,149 +458,6 @@ class ThreadDisciplineRule(Rule):
                 f"(PR 4 sized every watcher queue for exactly this); "
                 f"bound it or waive with the reason the producer is "
                 f"bounded elsewhere")
-
-
-# ---------------------------------------------------------------------------
-# py310-compat — the PR 1 muted-test-modules class
-# ---------------------------------------------------------------------------
-
-# APIs that import/attribute-resolve fine on 3.11+ but crash (or do not
-# exist) on the 3.10 interpreter this repo pins. Names are fully dotted
-# post-import-resolution.
-_PY311_APIS: Dict[str, str] = {
-    "datetime.UTC": "3.11 (use datetime.timezone.utc)",
-    "enum.StrEnum": "3.11 (use str + Enum mixin)",
-    "enum.ReprEnum": "3.11",
-    "asyncio.TaskGroup": "3.11",
-    "asyncio.Runner": "3.11",
-    "asyncio.timeout": "3.11 (use asyncio.wait_for)",
-    "asyncio.timeout_at": "3.11",
-    "asyncio.Barrier": "3.11",
-    "contextlib.chdir": "3.11",
-    "typing.Self": "3.11",
-    "typing.LiteralString": "3.11",
-    "typing.Never": "3.11",
-    "typing.assert_never": "3.11",
-    "typing.assert_type": "3.11",
-    "typing.dataclass_transform": "3.11",
-    "typing.Required": "3.11",
-    "typing.NotRequired": "3.11",
-    "math.cbrt": "3.11",
-    "math.exp2": "3.11",
-    "operator.call": "3.11",
-    "hashlib.file_digest": "3.11",
-    "inspect.getmembers_static": "3.11",
-    "sys.exception": "3.11",
-    "itertools.batched": "3.12",
-}
-_PY311_MODULES: Dict[str, str] = {"tomllib": "3.11"}
-_PY311_BUILTINS: Dict[str, str] = {"ExceptionGroup": "3.11",
-                                   "BaseExceptionGroup": "3.11"}
-# keyword-only: valid call shape on 3.11+, TypeError on 3.10 — the
-# kubelet process-runtime hit exactly this with Popen(process_group=)
-_PY311_KWARGS: Dict[str, Tuple[str, ...]] = {
-    "process_group": ("subprocess.Popen", "subprocess.run",
-                      "subprocess.call", "subprocess.check_call",
-                      "subprocess.check_output"),
-}
-
-
-@register
-class Py310CompatRule(Rule):
-    """The whole tree must parse and run on Python 3.10.
-
-    Motivating incident: PR 1 found (and fixed) an f-string nested-quote
-    SyntaxError in util/metrics.py that silently killed COLLECTION of 13
-    test modules on py3.10 — the suite went green by not running. A
-    second instance of the class: ``Popen(process_group=...)`` is a
-    py3.11 keyword that fails only when the spawn path executes.
-    ``ast.parse(feature_version=(3, 10))`` catches the syntax half at
-    vet time; a denylist of py3.11+-only stdlib APIs catches the
-    runtime half.
-    """
-
-    id = "py310-compat"
-    doc = "tree parses and runs on python 3.10"
-
-    def applies_to(self, rel: str) -> bool:   # tests too: muted test
-        return True                           # modules WERE the incident
-
-    def check(self, ctx: FileContext) -> Iterable[Violation]:
-        try:
-            ast.parse(ctx.source, filename=ctx.rel,
-                      feature_version=(3, 10))
-        except SyntaxError as e:
-            v = Violation(rule=self.id, path=ctx.rel, line=e.lineno or 1,
-                          col=(e.offset or 1) - 1,
-                          message=f"does not parse as python 3.10: "
-                                  f"{e.msg} (the PR 1 class: one "
-                                  f"SyntaxError silently mutes every "
-                                  f"importer)",
-                          span=(e.lineno or 1, e.lineno or 1))
-            yield v
-            return
-        if ctx.tree is None:
-            return
-        imports = _import_map(ctx.tree)
-        for node in ast.walk(ctx.tree):
-            if isinstance(node, ast.Import):
-                for a in node.names:
-                    mod = a.name.split(".")[0]
-                    if mod in _PY311_MODULES:
-                        yield ctx.violation(
-                            self.id, node,
-                            f"import {a.name}: module requires python "
-                            f">= {_PY311_MODULES[mod]}")
-            elif isinstance(node, ast.ImportFrom) and node.module:
-                for a in node.names:
-                    dotted = f"{node.module}.{a.name}"
-                    if dotted in _PY311_APIS:
-                        yield ctx.violation(
-                            self.id, node,
-                            f"from {node.module} import {a.name}: "
-                            f"requires python >= {_PY311_APIS[dotted]}")
-            elif isinstance(node, ast.Attribute):
-                full = _resolve(node, imports)
-                ver = _PY311_APIS.get(full or "")
-                # flag only when the chain head is a real module (it was
-                # imported here, or is a known stdlib module name) — a
-                # local variable named `math` must not trip the rule
-                head = (full or "").split(".")[0]
-                if ver and (head in imports or head in _STDLIB_HEADS):
-                    yield ctx.violation(
-                        self.id, node,
-                        f"{full}: requires python >= {ver}")
-            elif isinstance(node, ast.Name) and isinstance(node.ctx,
-                                                           ast.Load):
-                if node.id in _PY311_BUILTINS and node.id not in imports:
-                    yield ctx.violation(
-                        self.id, node,
-                        f"{node.id}: builtin requires python >= "
-                        f"{_PY311_BUILTINS[node.id]}")
-                else:
-                    full = imports.get(node.id)
-                    ver = _PY311_APIS.get(full or "")
-                    if ver:
-                        yield ctx.violation(
-                            self.id, node,
-                            f"{full}: requires python >= {ver}")
-            elif isinstance(node, ast.Call):
-                callee = _resolve(node.func, imports) or ""
-                for kw in node.keywords:
-                    funcs = _PY311_KWARGS.get(kw.arg or "")
-                    if funcs and callee in funcs:
-                        yield ctx.violation(
-                            self.id, node,
-                            f"{callee}({kw.arg}=...): keyword requires "
-                            f"python >= 3.11 (use a preexec_fn shim — "
-                            f"kubelet/process_runtime._spawn is the "
-                            f"in-tree pattern)")
-
-
-# `math.cbrt` in a file that (unusually) lacks the `import math` line —
-# e.g. the module object was passed in — still deserves a flag when the
-# chain head is a known stdlib module name.
-_STDLIB_HEADS = {d.split(".")[0] for d in _PY311_APIS}
 
 
 # ---------------------------------------------------------------------------

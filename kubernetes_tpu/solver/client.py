@@ -70,8 +70,8 @@ class SolverBusy(Exception):
 
 class RemoteSolver:
     # the reply deadline must clear a COLD solve: the daemon's first wave
-    # of a new shape bucket pays an XLA compile (seconds on CPU, tens of
-    # seconds over a TPU tunnel), and treating that as a dead connection
+    # of a new shape bucket pays an XLA compile (seconds on CPU, up to
+    # minutes on a TPU), and treating that as a dead connection
     # would re-send the wave and solve it twice
     def __init__(self, address: str, timeout_s: float = 180.0,
                  connect_timeout_s: float = 2.0, fallback: bool = True,

@@ -2,8 +2,8 @@
 json.loads and stays < 1.5 KB regardless of how much detail the run
 produced (BENCH_r05.json had parsed:null because one giant line with
 inline runs_s arrays truncated in capture); the full record goes to the
-detail sidecar. The replay path must honor the same contract when
-re-emitting pre-contract committed records.
+detail sidecar. Every record names the device it ran on, and a run that
+finds no TPU (and was not given --smoke) prints an error record.
 
 Also the CHURN_MP_* record schema (hack/churn_mp.py validate_record):
 committed churn records must carry the delta-wire evidence (hit rate,
@@ -53,7 +53,7 @@ def _fat_record():
         "metric": "pods_scheduled_per_sec_10000pods_5000nodes",
         "value": 75028.5, "unit": "pods/s", "vs_baseline": 7.503,
         "timing": bench.TIMING_DESC,
-        "backend": "tpu", "configs": cfgs,
+        "configs": cfgs,
     }
 
 
@@ -354,16 +354,46 @@ def test_committed_churn_records_conform():
         assert churn_mp.validate_record(rec, round_no=round_no) == [], path
 
 
-def test_replay_of_committed_records_stays_compact():
-    """The repo's committed pre-contract records carry inline arrays; a
-    replay emission must still satisfy the line contract."""
-    repo = os.path.dirname(os.path.abspath(bench.__file__))
-    if not any(f.startswith(("TPUBENCH_r", "CPUBENCH_r"))
-               for f in os.listdir(repo)):
-        return  # nothing committed to replay against
-    line = bench._find_replay_record("unit test replay")
-    assert line is not None
-    assert len(line) < _LIMIT, len(line)
-    rec = json.loads(line)
-    assert "replayed_from" in rec
-    assert "metric" in rec
+def test_no_chip_and_no_smoke_fails_with_an_error_record(capsys):
+    """A measurement path that finds no TPU fails: an error record that
+    names the device JAX reported, exit 1, and never a committed record
+    printed as its own."""
+    assert bench.main(["--configs", "basic"]) == 1
+    lines = capsys.readouterr().out.strip().splitlines()
+    assert len(lines) == 1 and len(lines[0]) < _LIMIT
+    rec = json.loads(lines[0])
+    assert "no TPU" in rec["error"] and rec["value"] == 0.0
+    assert rec["platform"] == "cpu" and rec["device_count"] >= 1
+    assert "replayed_from" not in rec and "configs" not in rec
+
+
+def test_compact_line_keeps_the_device_stamp():
+    rec = dict(_fat_record(), platform="tpu", device_kind="TPU v5 lite",
+               device_count=1)
+    out = json.loads(bench._compact_record(rec))
+    assert (out["platform"], out["device_kind"], out["device_count"]) == \
+        ("tpu", "TPU v5 lite", 1)
+
+
+def test_platform_ambient_gives_the_chip_to_exactly_one_child(monkeypatch):
+    """hack/churn_mp.py --platform ambient: a chip belongs to one process,
+    so one child inherits the ambient environment and all others are
+    pinned to the CPU backend."""
+    import pytest
+
+    churn_mp = _load_churn_mp()
+    ambient = {k: v for k, v in churn_mp.ENV.items() if k != "JAX_PLATFORMS"}
+    monkeypatch.setattr(churn_mp, "ENV", ambient)
+    children = ["storeserver", "apiserver0", "apiserver1", "solverd",
+                "scheduler0", "scheduler1", "descheduler"]
+
+    def off_cpu(owner):
+        return [c for c in children
+                if churn_mp.child_env_for(c, owner).get("JAX_PLATFORMS")
+                != "cpu"]
+
+    assert off_cpu(churn_mp.chip_child("cpu", True, 2)) == []
+    assert off_cpu(churn_mp.chip_child("ambient", True, 2)) == ["solverd"]
+    assert off_cpu(churn_mp.chip_child("ambient", False, 1)) == ["scheduler0"]
+    with pytest.raises(ValueError, match="needs --solverd"):
+        churn_mp.chip_child("ambient", False, 2)

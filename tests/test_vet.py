@@ -115,67 +115,6 @@ class TestDonationSafety:
 
 
 # ---------------------------------------------------------------------------
-# py310-compat
-# ---------------------------------------------------------------------------
-
-class TestPy310Compat:
-    def test_pr1_fstring_form_flagged(self, tmp_path):
-        # the PR 1 incident: an f-string whose braces reuse the outer
-        # quote — a SyntaxError on py3.10 that silently mutes every
-        # importer of the module
-        bad = 'x = f"metric {d["name"]} ready"\n'
-        active, _ = _vet_source(tmp_path, bad, rules=["py310-compat"])
-        assert _rules_of(active) == ["py310-compat"]
-        assert "3.10" in active[0].message
-
-    def test_popen_process_group_flagged(self, tmp_path):
-        active, _ = _vet_source(
-            tmp_path,
-            "import subprocess\n"
-            "p = subprocess.Popen(['ls'], process_group=0)\n",
-            rules=["py310-compat"])
-        assert _rules_of(active) == ["py310-compat"]
-        assert "process_group" in active[0].message
-
-    def test_popen_imported_name_flagged(self, tmp_path):
-        active, _ = _vet_source(
-            tmp_path,
-            "from subprocess import Popen\n"
-            "p = Popen(['ls'], process_group=0)\n",
-            rules=["py310-compat"])
-        assert _rules_of(active) == ["py310-compat"]
-
-    def test_datetime_utc_and_exceptiongroup_flagged(self, tmp_path):
-        active, _ = _vet_source(
-            tmp_path,
-            "import datetime\n"
-            "t = datetime.datetime.now(datetime.UTC)\n"
-            "e = ExceptionGroup('x', [])\n",
-            rules=["py310-compat"])
-        assert sorted(_rules_of(active)) == ["py310-compat",
-                                            "py310-compat"]
-
-    def test_py310_clean_form(self, tmp_path):
-        active, _ = _vet_source(
-            tmp_path,
-            "import datetime\n"
-            "import subprocess\n"
-            "import os\n"
-            'x = f"metric {d[chr(39)]} ready"\n'
-            "t = datetime.datetime.now(datetime.timezone.utc)\n"
-            "p = subprocess.Popen(['ls'], preexec_fn=os.setpgrp)\n",
-            rules=["py310-compat"])
-        assert active == []
-
-    def test_tests_are_in_scope(self, tmp_path):
-        # muted TEST modules were the incident — tests/ is not exempt
-        active, _ = _vet_source(tmp_path, "import tomllib\n",
-                                rel="tests/test_x.py",
-                                rules=["py310-compat"])
-        assert _rules_of(active) == ["py310-compat"]
-
-
-# ---------------------------------------------------------------------------
 # thread-discipline
 # ---------------------------------------------------------------------------
 
@@ -574,20 +513,11 @@ class TestCli:
         assert r.returncode == 1, r.stdout + r.stderr
         assert "[donation-safety]" in r.stdout
 
-    def test_cli_flags_py311_syntax_file(self, tmp_path):
-        bad = tmp_path / "py311.py"
-        # except* is py3.11-only syntax: must fail the 3.10 parse gate
-        bad.write_text("try:\n    pass\nexcept* ValueError:\n    pass\n")
-        r = self._run("--rules", "py310-compat", str(bad))
-        assert r.returncode == 1, r.stdout + r.stderr
-        assert "[py310-compat]" in r.stdout
-
     def test_list_rules(self):
         r = self._run("--list-rules")
         assert r.returncode == 0
         for rid in ("donation-safety", "clone-mutation",
-                    "thread-discipline", "py310-compat", "metrics-sync",
-                    "unused"):
+                    "thread-discipline", "metrics-sync", "unused"):
             assert rid in r.stdout
 
 

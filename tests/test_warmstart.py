@@ -77,3 +77,53 @@ def test_warmstart_default_dir_is_repo_local(monkeypatch):
     repo = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(warmstart.__file__))))
     assert d == os.path.join(repo, ".ktpu_cache")
+
+
+def _fresh_enable(monkeypatch):
+    """enable() as a new process would run it, with its process-global
+    effects (the idempotence latch, the default router's store, jax's
+    threshold) put back afterwards."""
+    import jax
+
+    from kubernetes_tpu.models.batch_solver import default_router
+    monkeypatch.setattr(warmstart, "_active_dir", None)
+    monkeypatch.setattr(default_router, "_cal_path", None)
+    monkeypatch.setenv("KTPU_WARM_START", "auto")
+    return jax, (jax.config.jax_compilation_cache_dir,
+                 jax.config.jax_persistent_cache_min_compile_time_secs)
+
+
+def _restore(jax, saved):
+    jax.config.update("jax_compilation_cache_dir", saved[0])
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", saved[1])
+
+
+def test_enable_leaves_jax_cache_dir_alone_when_env_sets_it(monkeypatch,
+                                                            tmp_path):
+    """Where JAX_COMPILATION_CACHE_DIR is set the program keeps its
+    compile cache there and sets no other in code; the calibration
+    stores still live under cache_dir()."""
+    jax, saved = _fresh_enable(monkeypatch)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "theirs"))
+    base = str(tmp_path / "ktpu")
+    try:
+        assert warmstart.enable(base) == base
+        assert jax.config.jax_compilation_cache_dir == saved[0]
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+        assert os.path.isdir(base)
+        assert not os.path.exists(os.path.join(base, "jax"))
+    finally:
+        _restore(jax, saved)
+
+
+def test_enable_sets_the_fixed_dir_when_env_is_silent(monkeypatch, tmp_path):
+    jax, saved = _fresh_enable(monkeypatch)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    base = str(tmp_path / "ktpu")
+    try:
+        assert warmstart.enable(base) == base
+        assert jax.config.jax_compilation_cache_dir == \
+            os.path.join(base, "jax")
+        assert os.path.isdir(os.path.join(base, "jax"))
+    finally:
+        _restore(jax, saved)
