@@ -126,6 +126,15 @@ class _Handler(BaseHTTPRequestHandler):
     disable_nagle_algorithm = True
     server_version = "kubernetes-tpu-apiserver"
 
+    def handle(self):
+        # one thread a connection: its CPU is the handlers' until a watch
+        # stream re-marks it (process_role_cpu_seconds_total)
+        tracing.role("http")
+        try:
+            super().handle()
+        finally:
+            tracing.role_end()
+
     def parse_request(self) -> bool:
         """Lean replacement for the stdlib parse (same observable
         behavior for HTTP/1.0-1.1 clients: keep-alive semantics, Expect:
@@ -445,13 +454,16 @@ class _Handler(BaseHTTPRequestHandler):
             chaos.delay_if_armed("apiserver.dispatch")
             if flow:
                 chaos.delay_if_armed("apiserver.dispatch." + flow)
-            if self._trace_ctx is not None:
-                with tracing.span("http." + verb_label,
-                                  parent=self._trace_ctx,
-                                  path=parsed.path):
-                    code = self._dispatch_path(method, parts, query, user,
-                                               raw_body)
-            else:
+            # every request is a ktpu/http.<verb>.<resource> annotation (a
+            # profiler trace shows which handlers ran while the wave loop
+            # held a phase open); only a request that carried the header
+            # records a kube-trace span
+            with tracing.phase("http." + verb_label,
+                               detail=fairshed_mod.route_info(parts)[1]
+                               or self._metric_resource,
+                               parent=self._trace_ctx,
+                               traced=self._trace_ctx is not None,
+                               path=parsed.path):
                 code = self._dispatch_path(method, parts, query, user,
                                            raw_body)
         except errors.StatusError as e:
@@ -855,6 +867,7 @@ class _Handler(BaseHTTPRequestHandler):
         ticket = getattr(self, "_fs_ticket", None)
         if ticket is not None:
             ticket.release()
+        tracing.role("watch_send")   # this thread streams from here on
         try:
             lagged = False
             while not lagged:
@@ -864,20 +877,22 @@ class _Handler(BaseHTTPRequestHandler):
                     linger=apisrv.watch_write_linger)
                 if batch is None:
                     break
-                t0 = time.monotonic()
-                parts, lagged = self._translate_batch(batch, translate,
-                                                      version, ws_frames=False)
-                if parts:
-                    apisrv.metric_fanout_frames.observe(len(parts))
-                    self.wfile.write(b"".join(parts))
-                    self.wfile.flush()
-                    apisrv.metric_fanout_seconds.observe(
-                        time.monotonic() - t0)
+                with tracing.phase("watch.send", traced=False):
+                    t0 = time.monotonic()
+                    parts, lagged = self._translate_batch(
+                        batch, translate, version, ws_frames=False)
+                    if parts:
+                        apisrv.metric_fanout_frames.observe(len(parts))
+                        self.wfile.write(b"".join(parts))
+                        self.wfile.flush()
+                        apisrv.metric_fanout_seconds.observe(
+                            time.monotonic() - t0)
             self.wfile.write(b"0\r\n\r\n")
             self.wfile.flush()
         except (BrokenPipeError, ConnectionResetError, socket.timeout):
             pass
         finally:
+            tracing.role("http")
             watcher.stop()
             apisrv.untrack_watcher(watcher)
             self.close_connection = True
@@ -928,6 +943,7 @@ class _Handler(BaseHTTPRequestHandler):
 
         threading.Thread(target=reader, daemon=True,
                          name="ws-watch-reader").start()
+        tracing.role("watch_send")
         try:
             lagged = False
             while not lagged:
@@ -950,6 +966,7 @@ class _Handler(BaseHTTPRequestHandler):
         except (BrokenPipeError, ConnectionResetError, socket.timeout):
             pass
         finally:
+            tracing.role("http")
             watcher.stop()
             apisrv.untrack_watcher(watcher)
             self.close_connection = True

@@ -24,6 +24,7 @@ from kubernetes_tpu.api import errors
 from kubernetes_tpu.api import labels as labels_pkg
 from kubernetes_tpu.api import types as api
 from kubernetes_tpu.api.meta import accessor
+from kubernetes_tpu.util import tracing
 from kubernetes_tpu.util.retry import Backoff
 
 __all__ = ["meta_namespace_key_func", "Store", "FIFO", "ListWatch", "Reflector",
@@ -193,19 +194,29 @@ class FIFO:
 
     Items added while present are coalesced (update-in-place keeps queue
     position); Pop blocks until an item is available.
+
+    ``wait_hist`` (a metrics.Histogram) is observed once per popped item
+    with the seconds from the key's FIRST add — a coalesced re-add keeps
+    the first stamp — to the pop that hands it out.
     """
 
-    def __init__(self, key_func: Callable[[Any], str] = meta_namespace_key_func):
+    def __init__(self,
+                 key_func: Callable[[Any], str] = meta_namespace_key_func,
+                 wait_hist=None):
         self._cond = threading.Condition()
         self._items: Dict[str, Any] = {}
         self._queue: List[str] = []
         self.key_func = key_func
+        self._wait_hist = wait_hist
+        self._added: Dict[str, float] = {}   # key -> monotonic first add
 
     def add(self, obj: Any) -> None:
         with self._cond:
             key = self.key_func(obj)
             if key not in self._items:
                 self._queue.append(key)
+                if self._wait_hist is not None:
+                    self._added[key] = time.monotonic()
             self._items[key] = obj
             self._cond.notify()
 
@@ -215,6 +226,7 @@ class FIFO:
         with self._cond:
             key = self.key_func(obj)
             self._items.pop(key, None)
+            self._added.pop(key, None)
             # key stays in _queue; Pop skips missing items (ref: fifo.go Pop)
 
     def get_by_key(self, key: str) -> Optional[Any]:
@@ -229,6 +241,10 @@ class FIFO:
         with self._cond:
             self._items = {self.key_func(o): o for o in objs}
             self._queue = list(self._items.keys())
+            if self._wait_hist is not None:
+                now = time.monotonic()
+                self._added = {k: self._added.get(k, now)
+                               for k in self._queue}
             self._cond.notify_all()
 
     def pop(self, timeout: Optional[float] = None) -> Any:
@@ -239,6 +255,10 @@ class FIFO:
                 while self._queue:
                     key = self._queue.pop(0)
                     if key in self._items:
+                        if self._wait_hist is not None:
+                            now = time.monotonic()
+                            self._wait_hist.observe(
+                                now - self._added.pop(key, now))
                         return self._items.pop(key)
                 remaining = None
                 if deadline is not None:
@@ -315,17 +335,21 @@ class Reflector:
         return _join_thread(self._thread, timeout)
 
     def _run_loop(self) -> None:
-        while not self._stop.is_set():
-            try:
-                self._list_and_watch()
-                self._backoff.reset()  # listed fine: the source is healthy
-            except Exception:
-                if self._stop.is_set():
-                    return
-                # interruptible backoff: stop() during an outage must not
-                # hold the thread for the full capped delay
-                if self._stop.wait(self._backoff.next()):
-                    return
+        tracing.role("reflector")
+        try:
+            while not self._stop.is_set():
+                try:
+                    self._list_and_watch()
+                    self._backoff.reset()  # listed fine: source is healthy
+                except Exception:
+                    if self._stop.is_set():
+                        return
+                    # interruptible backoff: stop() during an outage must
+                    # not hold the thread for the full capped delay
+                    if self._stop.wait(self._backoff.next()):
+                        return
+        finally:
+            tracing.role_end()
 
     def _list_and_watch(self) -> None:
         lst = self.lw.list_fn()
@@ -406,8 +430,12 @@ class Poller:
             pass
 
     def _loop(self):
-        while not self._stop.wait(self.period):
-            self._run_once()
+        tracing.role("reflector")   # the node source's reflector, by polls
+        try:
+            while not self._stop.wait(self.period):
+                self._run_once()
+        finally:
+            tracing.role_end()
 
     def stop(self):
         self._stop.set()

@@ -417,6 +417,7 @@ class HTTPTransport:
         watcher = watchpkg.Watcher(on_stop=on_stop)
 
         def pump():
+            tracing.role("reflector")   # the stream reader behind one
             try:
                 for line in resp:
                     if stopped.is_set():
@@ -439,6 +440,7 @@ class HTTPTransport:
                 except Exception:
                     pass
                 watcher.close()
+                tracing.role_end()
 
         threading.Thread(target=pump, daemon=True, name="http-watch").start()
         return watcher

@@ -82,7 +82,7 @@ from kubernetes_tpu.ops.kernels import (
     spread_score as _spread_score,
     u64_mod_small as _u64_mod,
 )
-from kubernetes_tpu.util import metrics
+from kubernetes_tpu.util import metrics, tracing
 
 __all__ = ["solve", "solve_jit", "solve_device", "SolverInputs",
            "decisions_to_names", "WaveRouter", "WavePlan", "default_router",
@@ -862,6 +862,20 @@ def wave_programs() -> metrics.Counter:
         ("program", "platform"))
 
 
+_WAVE_PART_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+                      0.02, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5)
+
+
+def wave_parts() -> metrics.Histogram:
+    """Seconds of each part of a wave: the parts of the solve timed here
+    (``solve.route`` ... ``solve.post``) and those the wave loop times
+    (scheduler/tpu_batch.py). One observation a wave and part."""
+    return metrics.default_registry().histogram(
+        "scheduler_wave_part_seconds",
+        "Wall seconds per wave of one part of the wave loop's phases",
+        ("part",), buckets=_WAVE_PART_BUCKETS)
+
+
 def peer_bound_of(source) -> int:
     """Largest initial per-group peer total — the pallas-eligibility bound
     on spread/anti-affinity arithmetic. ``source`` is anything carrying a
@@ -1103,33 +1117,48 @@ def solve(snap: ClusterSnapshot,
     want resident planes use the daemon). Decisions are bit-identical
     either way (parallel/mesh.py contract); the gang post-pass is applied
     here exactly as on the router path."""
+    # the phases hang on the caller's ambient span (the wave's wave.solve);
+    # off the wave loop (solverd) there is none and they only keep time
+    part = wave_parts()
+    inp = None
     if host is None:
-        host = snapshot_to_host_inputs(snap)
+        with tracing.phase("wave.solve.hostprep", part, "solve.hostprep"):
+            host = snapshot_to_host_inputs(snap)
     has_gangs = snap.has_gangs
-    peer_bound = peer_bound_of(snap)
-    if mesh is not None and int(host.cap.shape[0]) >= _mesh_min_nodes():
+    with tracing.phase("wave.solve.route", part, "solve.route"):
+        peer_bound = peer_bound_of(snap)
+        sharded = mesh is not None and \
+            int(host.cap.shape[0]) >= _mesh_min_nodes()
+        if not sharded:
+            plan = default_router.plan_for(host, snap.policy, has_gangs,
+                                           peer_bound)
+    if sharded:
         from kubernetes_tpu.parallel.mesh import solve_sharded
         chosen, scores = solve_sharded(host, mesh, pol=snap.policy,
                                        gangs=has_gangs,
                                        peer_bound=peer_bound)
+    else:
+        with tracing.phase("wave.solve.ship", part, "solve.ship"):
+            inp = ship_inputs(host, plan.device)
+        with tracing.phase("wave.solve.launch", part, "solve.launch"):
+            chosen, scores = solve_device(
+                inp, snap.policy, has_gangs, peer_bound,
+                force_scan=plan.device is not None)
+        with tracing.phase("wave.solve.readback", part, "solve.readback"):
+            # ONE device->host readback, not two: at churn rates a second
+            # sync per wave starves the feeder and watch pumps
+            both = np.asarray(jnp.stack([chosen, scores]))
+        chosen, scores = both[0], both[1]
+    with tracing.phase("wave.solve.post", part, "solve.post"):
+        # the wave's device inputs are let go here, inside the last part,
+        # not in this frame's teardown after it: freeing ~30 device arrays
+        # gives the interpreter away once each
+        inp = None
         if has_gangs:
             chosen = gang.apply_all_or_nothing(snap.pod_rid, chosen)
+            # keep the chosen/score pairing: rolled-back members'
+            # tentative winning scores are as stale as their hosts
             scores = np.where(chosen < 0, np.int32(NEG), scores)
-        return chosen, scores
-    plan = default_router.plan_for(host, snap.policy, has_gangs, peer_bound)
-    inp = ship_inputs(host, plan.device)
-    chosen, scores = solve_device(
-        inp, snap.policy, has_gangs, peer_bound,
-        force_scan=plan.device is not None)
-    # ONE device->host readback, not two: at churn rates a second sync
-    # per wave starves the feeder and watch pumps
-    both = np.asarray(jnp.stack([chosen, scores]))
-    chosen, scores = both[0], both[1]
-    if has_gangs:
-        chosen = gang.apply_all_or_nothing(snap.pod_rid, chosen)
-        # keep the chosen/score pairing: rolled-back members' tentative
-        # winning scores are as stale as their hosts
-        scores = np.where(chosen < 0, np.int32(NEG), scores)
     return chosen, scores
 
 

@@ -315,7 +315,14 @@ class ConfigFactory:
     def __init__(self, client, node_poll_period: float = 10.0):
         self.client = client
         self.node_poll_period = node_poll_period
-        self.pod_queue = FIFO()              # unassigned pods
+        # unassigned pods; how long each waited for a wave is the one
+        # queueing delay on the timed path
+        self.pod_queue = FIFO(wait_hist=metrics.default_registry().histogram(
+            "scheduler_queue_wait_seconds",
+            "Seconds a pod sat in the scheduler's FIFO: first add of its "
+            "key to the pop that handed it to a wave",
+            buckets=(0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
+                     0.5, 1.0, 2.5, 5.0, 10.0)))
         self.scheduled_pods = Store()        # assigned pods
         self.node_store = Store()
         self.service_store = Store()

@@ -70,7 +70,8 @@ from kubernetes_tpu.models import preempt as preempt_mod
 from kubernetes_tpu.models.batch_solver import (decisions_to_names,
                                                 peer_bound_of,
                                                 snapshot_to_host_inputs,
-                                                solve, warm_compile)
+                                                solve, warm_compile,
+                                                wave_parts)
 from kubernetes_tpu.models.incremental import IncrementalEncoder
 from kubernetes_tpu.models.policy import BatchPolicy, batch_policy_from
 from kubernetes_tpu.models.snapshot import encode_snapshot
@@ -115,8 +116,20 @@ class _WaveMetrics:
             "scheduler_wave_commit_seconds",
             "Bind + assume time per wave (the store round-trips)",
             buckets=buckets)
+        # the parts of the phases above and the phases with no histogram
+        # of their own (drain.wait, solve.ship, commit.bind, ...)
+        self.part = wave_parts()
         self.pods = reg.counter(
             "scheduler_wave_pods_total", "Pods drained into waves")
+        self.cut = reg.counter(
+            "scheduler_wave_cut_total",
+            "Why a wave's drain ended: full (wave_size reached), linger "
+            "(the deadline passed between two pops: pods were still "
+            "coming), empty (a pop ran into the deadline: the queue was "
+            "dry)", label_names=("reason",))
+        self.queue_left = reg.counter(
+            "scheduler_wave_queue_left_total",
+            "Pods still in the FIFO when a wave's drain ended, summed")
         self.resyncs = reg.counter(
             "scheduler_wave_encode_resyncs_total",
             "Full-list encoder syncs (vs O(changed) delta waves)")
@@ -322,6 +335,11 @@ class BatchScheduler:
         # back through the scheduler's own watch stream. Bounded — a pod
         # whose confirm never arrives must not leak the map.
         self._pod_lat = metrics.pod_latency_metrics()
+        # the wait for a wave's first pod, carried over the loop's empty
+        # ticks: (tracing.clocks() at its start, the wave's trace context);
+        # and the context handed from _drain_wave to the wave it drained
+        self._wait = None
+        self._drain_tctx = None
         self._bind_t: "OrderedDict[str, float]" = OrderedDict()
         # deliveries that beat the arming loop: the batch bind commits
         # server-side before bind_many returns, so the reflector can
@@ -360,17 +378,50 @@ class BatchScheduler:
 
     # -- wave assembly ------------------------------------------------------
     def _drain_wave(self, timeout: Optional[float]) -> List[api.Pod]:
-        pods: List[api.Pod] = [self.config.next_pod(timeout)]
-        deadline = time.monotonic() + self.wave_linger_s
-        while len(pods) < self.wave_size:
-            remaining = deadline - time.monotonic()
-            if remaining <= 0:
-                break
+        wm = _wave_metrics()
+        if self._wait is None:
+            self._wait = (tracing.clocks(), tracing.new_ctx())
+        since, tctx = self._wait
+        with tracing.phase("wave.drain.wait", wm.part, "drain.wait",
+                           parent=tctx, since=since) as ph:
             try:
-                pods.append(self.config.next_pod(remaining))
+                pods: List[api.Pod] = [self.config.next_pod(timeout)]
             except TimeoutError:
-                break
+                # an empty tick is no wave: its wait belongs to the wave
+                # that follows (self._wait stays)
+                ph.cancel()
+                raise
+        self._wait = None
+        self._drain_tctx = tctx
+        with tracing.phase("wave.drain.collect", wm.part, "drain.collect",
+                           parent=tctx) as ph:
+            deadline = time.monotonic() + self.wave_linger_s
+            cut = "full"
+            while len(pods) < self.wave_size:
+                remaining = deadline - time.monotonic()
+                if remaining <= 0:
+                    cut = "linger"
+                    break
+                try:
+                    pods.append(self.config.next_pod(remaining))
+                except TimeoutError:
+                    cut = "empty"
+                    break
+            ph.set(pods=len(pods), cut=cut)
+        wm.cut.inc(cut)
+        queue = getattr(self.factory, "pod_queue", None)
+        if queue is not None:
+            wm.queue_left.inc(by=len(queue))
         return pods
+
+    def _wave_ctx(self, pods):
+        """The trace context of the wave just drained: the one _drain_wave
+        hung its own spans on, or a fresh one where _drain_wave was
+        replaced on the instance. None for an idle tick or tracing off."""
+        tctx, self._drain_tctx = self._drain_tctx, None
+        if not pods:
+            return None
+        return tctx if tctx is not None else tracing.new_ctx()
 
     def _make_get_existing(self):
         """Lazy memoized existing-pod list: materialized only when
@@ -421,17 +472,16 @@ class BatchScheduler:
     # -- solving ------------------------------------------------------------
     def _encode_wave(self, nodes, pending, services, get_existing,
                      tctx=None):
-        t0 = time.perf_counter()
-        with tracing.span("wave.encode", parent=tctx, pods=len(pending)):
+        wm = _wave_metrics()
+        with tracing.phase("wave.encode", wm.encode, parent=tctx,
+                           pods=len(pending)) as ph:
             if self._encoder is not None:
                 snap = self._encode_incremental(nodes, pending, services,
                                                 get_existing)
             else:
                 snap = encode_snapshot(nodes, get_existing(), pending,
                                        services, policy=self.batch_policy)
-        dt = time.perf_counter() - t0
-        _wave_metrics().encode.observe(dt)
-        _wave_metrics().note_stall(dt)
+        wm.note_stall(ph.wall_s)
         return snap
 
     def _solve_snap(self, snap, n_pending: int, tctx=None):
@@ -450,8 +500,10 @@ class BatchScheduler:
         pins. Safe on the solve thread: the encoder is only mutated
         after this wave's decisions are collected (speculation ordering
         in _pipelined_cycle)."""
+        wm = _wave_metrics()
         t0 = time.perf_counter()
-        with tracing.span("wave.solve", parent=tctx, pods=n_pending):
+        with tracing.phase("wave.solve", wm.solve, parent=tctx,
+                           pods=n_pending) as ph:
             if self.solver is not None:
                 chosen, scores = self.solver.solve(snap)
             elif self._prewarm is not None:
@@ -460,42 +512,43 @@ class BatchScheduler:
                 # zero extra cost (solve() needs the host inputs anyway);
                 # the snap reference is the exemplar the prewarm thread
                 # pads to the queued target bucket
-                host = snapshot_to_host_inputs(snap)
-                self._prewarm_snap = snap
-                actual = {"P": n_pending}
-                if self._encoder is not None:
-                    actual.update(self._encoder.fill_dims())
-                from kubernetes_tpu.solver.service import _dims_of
-                self._prewarm.observe(actual, _dims_of(host))
+                with tracing.phase("wave.solve.hostprep", wm.part,
+                                   "solve.hostprep"):
+                    host = snapshot_to_host_inputs(snap)
+                    self._prewarm_snap = snap
+                    actual = {"P": n_pending}
+                    if self._encoder is not None:
+                        actual.update(self._encoder.fill_dims())
+                    from kubernetes_tpu.solver.service import _dims_of
+                    self._prewarm.observe(actual, _dims_of(host))
                 chosen, scores = solve(snap, host=host, mesh=self._mesh)
             else:
                 chosen, scores = solve(snap, mesh=self._mesh)
-        dt_solve = time.perf_counter() - t0
-        _wave_metrics().solve.observe(dt_solve)
-        _wave_metrics().note_stall(dt_solve)
-        _wave_metrics().pods.inc(by=n_pending)
-        hosts = decisions_to_names(snap, chosen)
-        victims = [None] * len(hosts)
-        if any(preempt_mod.is_preempt_score(int(s))
-               for s in scores[:len(hosts)]):
-            if self._encoder is not None:
-                victims = preempt_mod.assign_victims(
-                    chosen, scores, snap.band_prio, n_pods=len(hosts),
-                    node_pods=self._encoder.resident_on)
-            else:
-                # the full-encoder path has no resident pod registry to
-                # name victims from: fail those pods back to the queue
-                # (preemption requires the incremental encoder, like
-                # speculation; policies it cannot model keep the serial
-                # no-preemption behavior)
-                if not getattr(self, "_warned_preempt_encoder", False):
-                    self._warned_preempt_encoder = True
-                    _log.warning(
-                        "preemption decisions need the incremental "
-                        "encoder's pod registry; requeueing preempting "
-                        "pods (policy forces the full encoder)")
-                hosts = [None if preempt_mod.is_preempt_score(int(s))
-                         else h for h, s in zip(hosts, scores)]
+        wm.note_stall(ph.wall_s)
+        wm.pods.inc(by=n_pending)
+        with tracing.phase("wave.names", wm.part, "names", parent=tctx):
+            hosts = decisions_to_names(snap, chosen)
+            victims = [None] * len(hosts)
+            if any(preempt_mod.is_preempt_score(int(s))
+                   for s in scores[:len(hosts)]):
+                if self._encoder is not None:
+                    victims = preempt_mod.assign_victims(
+                        chosen, scores, snap.band_prio, n_pods=len(hosts),
+                        node_pods=self._encoder.resident_on)
+                else:
+                    # the full-encoder path has no resident pod registry
+                    # to name victims from: fail those pods back to the
+                    # queue (preemption requires the incremental encoder,
+                    # like speculation; policies it cannot model keep the
+                    # serial no-preemption behavior)
+                    if not getattr(self, "_warned_preempt_encoder", False):
+                        self._warned_preempt_encoder = True
+                        _log.warning(
+                            "preemption decisions need the incremental "
+                            "encoder's pod registry; requeueing preempting "
+                            "pods (policy forces the full encoder)")
+                    hosts = [None if preempt_mod.is_preempt_score(int(s))
+                             else h for h, s in zip(hosts, scores)]
         return _WaveDecisions(hosts, victims, t0, snap, chosen, scores)
 
     def _default_solve(self, nodes, existing, pending, services, tctx=None):
@@ -787,13 +840,14 @@ class BatchScheduler:
         item 409; the victims' DELETE watch events then drive kubelet
         teardown and the encoder's resident-plane removal exactly like
         any other delete."""
-        with tracing.span("wave.commit", parent=tctx, pods=len(placed)):
+        with tracing.phase("wave.commit", _wave_metrics().commit,
+                           parent=tctx, pods=len(placed)):
             return self._commit_wave_inner(placed, assumed, preempt_t0)
 
     def _commit_wave_inner(self, placed, assumed: Optional[list] = None,
                            preempt_t0: Optional[float] = None):
-        t_commit0 = time.perf_counter()
         c = self.config
+        part = _wave_metrics().part
 
         def mk_binding(pod, host, victims) -> api.Binding:
             refs = [api.ObjectReference(kind="Pod", namespace=v.namespace,
@@ -811,13 +865,28 @@ class BatchScheduler:
         # preserved — a lost race invalidates only that pod, which requeues
         bind_many = getattr(c.binder, "bind_many", None)
         outcomes: List[Optional[Exception]] = [None] * len(placed)
+        lists: list = []
         if bind_many is not None:
-            by_ns: dict = {}
-            for idx, (pod, host, vict) in enumerate(placed):
-                by_ns.setdefault(pod.metadata.namespace, []).append(idx)
-            for ns, idxs in by_ns.items():
-                blist = api.BindingList(items=[
-                    mk_binding(*placed[i]) for i in idxs])
+            with tracing.phase("wave.commit.build", part, "commit.build"):
+                by_ns: dict = {}
+                for idx, (pod, host, vict) in enumerate(placed):
+                    by_ns.setdefault(pod.metadata.namespace, []).append(idx)
+                lists = [(ns, idxs, api.BindingList(items=[
+                    mk_binding(*placed[i]) for i in idxs]))
+                    for ns, idxs in by_ns.items()]
+        else:  # custom binder without the batch seam: reference behavior
+            _wave_metrics().bind_fallback.inc()
+            if not getattr(self, "_warned_bind_fallback", False):
+                self._warned_bind_fallback = True
+                _log.warning(
+                    "binder %s has no bind_many: committing waves one "
+                    "bind round-trip per pod (scheduler_bind_fallback_"
+                    "total counts affected waves)",
+                    type(c.binder).__name__)
+        # the bind call(s) as the scheduler waits for them: the server's
+        # own apiserver_batch_bind_seconds less this is HTTP and waiting
+        with tracing.phase("wave.commit.bind", part, "commit.bind"):
+            for ns, idxs, blist in lists:
                 try:
                     results = bind_many(ns, blist)
                     for i, r in zip(idxs, results.items):
@@ -830,111 +899,98 @@ class BatchScheduler:
                 except Exception as e:
                     for i in idxs:
                         outcomes[i] = e
-        else:  # custom binder without the batch seam: reference behavior
-            _wave_metrics().bind_fallback.inc()
-            if not getattr(self, "_warned_bind_fallback", False):
-                self._warned_bind_fallback = True
-                _log.warning(
-                    "binder %s has no bind_many: committing waves one "
-                    "bind round-trip per pod (scheduler_bind_fallback_"
-                    "total counts affected waves)",
-                    type(c.binder).__name__)
-            for idx, (pod, host, vict) in enumerate(placed):
-                try:
-                    c.binder.bind(mk_binding(pod, host, vict))
-                except Exception as e:
-                    outcomes[idx] = e
+            if bind_many is None:
+                for idx, (pod, host, vict) in enumerate(placed):
+                    try:
+                        c.binder.bind(mk_binding(pod, host, vict))
+                    except Exception as e:
+                        outcomes[idx] = e
 
-        if assumed is None:
-            # value copy before mutating (the popped pod may be shared);
-            # deep_clone, not copy.deepcopy — at churn rates the stdlib
-            # deepcopy was the scheduler's single largest CPU sink
-            assumed = []
-            for pod, host, _vict in placed:
-                cl = deep_clone(pod)
-                cl.spec.host = host
-                cl.status.host = host
-                assumed.append(cl)
+        with tracing.phase("wave.commit.assume", part, "commit.assume"):
+            if assumed is None:
+                # value copy before mutating (the popped pod may be shared);
+                # deep_clone, not copy.deepcopy — at churn rates the stdlib
+                # deepcopy was the scheduler's single largest CPU sink
+                assumed = []
+                for pod, host, _vict in placed:
+                    cl = deep_clone(pod)
+                    cl.spec.host = host
+                    cl.status.host = host
+                    assumed.append(cl)
 
-        # preemption outcome accounting (scheduler_preemption_* family)
-        pmx = None
-        now_p = time.perf_counter()
-        for (pod, host, vict), err in zip(placed, outcomes):
-            if not vict:
-                continue
-            if pmx is None:
-                pmx = metrics.preemption_metrics()
-            if err is None:
-                pmx.attempts.inc()
-                pmx.victims.inc(by=len(vict))
-                p_prio = api.pod_priority(pod)
-                bad = sum(1 for v in vict if v.priority >= p_prio)
-                if bad:
-                    pmx.higher_evictions.inc(by=bad)
-                if preempt_t0 is not None:
-                    pmx.bind_seconds.observe(max(0.0, now_p - preempt_t0))
-            elif getattr(err, "code", None) == 409:
-                # only true CAS losses count as conflicts; other failure
-                # classes (transport faults, 4xx validation) stay visible
-                # as requeues instead of masquerading as benign CAS churn
-                pmx.conflicts.inc()
+            # preemption outcome accounting (scheduler_preemption_* family)
+            pmx = None
+            now_p = time.perf_counter()
+            for (pod, host, vict), err in zip(placed, outcomes):
+                if not vict:
+                    continue
+                if pmx is None:
+                    pmx = metrics.preemption_metrics()
+                if err is None:
+                    pmx.attempts.inc()
+                    pmx.victims.inc(by=len(vict))
+                    p_prio = api.pod_priority(pod)
+                    bad = sum(1 for v in vict if v.priority >= p_prio)
+                    if bad:
+                        pmx.higher_evictions.inc(by=bad)
+                    if preempt_t0 is not None:
+                        pmx.bind_seconds.observe(max(0.0, now_p - preempt_t0))
+                elif getattr(err, "code", None) == 409:
+                    # only true CAS losses count as conflicts; other failure
+                    # classes (transport faults, 4xx validation) stay visible
+                    # as requeues instead of masquerading as benign CAS churn
+                    pmx.conflicts.inc()
 
-        bound = 0
-        now_m = time.monotonic()
-        now_w = time.time()
-        for (pod, host, _vict), cl, err in zip(placed, assumed, outcomes):
-            if err is not None:
-                # lost a CAS race: requeue; next wave sees fresh state
-                self._record(pod, "FailedScheduling",
-                             "Binding rejected: %s", err)
-                c.error(pod, err)
-                continue
-            self._record(pod, "Scheduled", "Successfully assigned %s to %s",
-                         pod.metadata.name, host)
-            c.modeler.assume_pod(cl)
-            bound += 1
-            # pod-lifecycle latency: create -> bind committed (the
-            # creationTimestamp is second-granular — fine at contract
-            # load, where e2e is dominated by wave queueing), and arm
-            # the bind -> watch-observe leg for the reflector hook
-            ct = pod.metadata.creation_timestamp
-            if ct is not None:
-                ts = ct.timestamp() if ct.tzinfo is not None else \
-                    ct.replace(tzinfo=timezone.utc).timestamp()
-                self._pod_lat.e2e.observe(max(0.0, now_w - ts))
-            with self._bind_t_lock:
-                obs = self._obs_t.pop(pod.metadata.uid, None)
-                if obs is None:
-                    self._bind_t[pod.metadata.uid] = now_m
-                    while len(self._bind_t) > self._BIND_T_MAX:
-                        self._bind_t.popitem(last=False)
-            if obs is not None:
-                # the watch delivery beat this arming loop (the bind was
-                # already committed server-side): the fan-out leg was
-                # effectively instantaneous relative to the commit
-                self._pod_lat.watch_observe.observe(max(0.0, obs - now_m))
-        _wave_metrics().commit.observe(time.perf_counter() - t_commit0)
+            bound = 0
+            now_m = time.monotonic()
+            now_w = time.time()
+            for (pod, host, _vict), cl, err in zip(placed, assumed, outcomes):
+                if err is not None:
+                    # lost a CAS race: requeue; next wave sees fresh state
+                    self._record(pod, "FailedScheduling",
+                                 "Binding rejected: %s", err)
+                    c.error(pod, err)
+                    continue
+                self._record(pod, "Scheduled",
+                             "Successfully assigned %s to %s",
+                             pod.metadata.name, host)
+                c.modeler.assume_pod(cl)
+                bound += 1
+                # pod-lifecycle latency: create -> bind committed (the
+                # creationTimestamp is second-granular — fine at contract
+                # load, where e2e is dominated by wave queueing), and arm
+                # the bind -> watch-observe leg for the reflector hook
+                ct = pod.metadata.creation_timestamp
+                if ct is not None:
+                    ts = ct.timestamp() if ct.tzinfo is not None else \
+                        ct.replace(tzinfo=timezone.utc).timestamp()
+                    self._pod_lat.e2e.observe(max(0.0, now_w - ts))
+                with self._bind_t_lock:
+                    obs = self._obs_t.pop(pod.metadata.uid, None)
+                    if obs is None:
+                        self._bind_t[pod.metadata.uid] = now_m
+                        while len(self._bind_t) > self._BIND_T_MAX:
+                            self._bind_t.popitem(last=False)
+                if obs is not None:
+                    # the watch delivery beat this arming loop (the bind was
+                    # already committed server-side): the fan-out leg was
+                    # effectively instantaneous relative to the commit
+                    self._pod_lat.watch_observe.observe(max(0.0, obs - now_m))
         return outcomes, bound
 
     def schedule_wave(self, timeout: Optional[float] = None) -> int:
         """Drain, solve, commit — the causal wave. Returns the number of
         pods bound."""
         c = self.config
-        t_dr0 = time.monotonic_ns()
         pods = self._drain_wave(timeout)
         # one trace per wave: a bare root context (no span of its own) the
-        # stage spans attach to — drain/prepare are recorded retroactively
-        # so the context need not exist while they run. Empty idle ticks
-        # are not waves and must not churn the ring.
-        tctx = tracing.new_ctx() if pods else None
-        if pods:
-            tracing.record("wave.drain", t_dr0, time.monotonic_ns(),
-                           parent=tctx, pods=len(pods))
-        t_pr0 = time.monotonic_ns()
-        prep = self._prepare_wave(pods)
-        if tctx is not None:
-            tracing.record("wave.prepare", t_pr0, time.monotonic_ns(),
-                           parent=tctx)
+        # stage spans attach to; _drain_wave opened it for its own two
+        # spans. Empty idle ticks are not waves and must not churn the
+        # ring.
+        tctx = self._wave_ctx(pods)
+        with tracing.phase("wave.prepare", _wave_metrics().part, "prepare",
+                           parent=tctx):
+            prep = self._prepare_wave(pods)
         if prep is None:
             return 0
         pending, nodes, services, get_existing = prep
@@ -989,6 +1045,7 @@ class BatchScheduler:
         unverifiable)."""
         t0 = time.perf_counter()
         enc = self._encoder
+        wm = _wave_metrics()
         if any(enc.has_pod(p.metadata.uid) for p in predicted):
             # a predicted pod is already resident (e.g. a stale requeue of
             # a pod another scheduler bound — its CAS will lose): applying
@@ -1002,10 +1059,11 @@ class BatchScheduler:
             return _SpecResult(None, None, False, "lister_error",
                                time.perf_counter() - t0)
         pending = gang.order_wave(pods)  # identity: wave is gang-free
-        t_enc0 = time.monotonic_ns()
-        snap = enc.encode_delta(nodes, predicted, [], pending, services)
-        tracing.record("wave.encode", t_enc0, time.monotonic_ns(),
-                       parent=tctx, pods=len(pending), speculative=True)
+        with tracing.phase("wave.encode", wm.encode, parent=tctx,
+                           pods=len(pending), speculative=True) as ph:
+            snap = enc.encode_delta(nodes, predicted, [], pending, services)
+            if snap is None:
+                ph.cancel()   # declined: the causal encode that follows counts
         if snap is None:
             # encode_delta declines before applying anything when the
             # node/service planes changed, but an overflow is detected
@@ -1013,7 +1071,6 @@ class BatchScheduler:
             applied = any(enc.has_pod(p.metadata.uid) for p in predicted)
             return _SpecResult(None, None, applied, "encoder_fallback",
                                time.perf_counter() - t0)
-        _wave_metrics().encode.observe(time.perf_counter() - t0)
         return _SpecResult(snap, pending, True, "", time.perf_counter() - t0)
 
     def _verify_speculation(self, spec: _SpecResult, predicted, outcomes):
@@ -1065,10 +1122,9 @@ class BatchScheduler:
             return None
         if tctx is None:
             tctx = tracing.new_ctx()
-        t_pr0 = time.monotonic_ns()
-        prep = self._prepare_wave(pods)
-        tracing.record("wave.prepare", t_pr0, time.monotonic_ns(),
-                       parent=tctx)
+        with tracing.phase("wave.prepare", _wave_metrics().part, "prepare",
+                           parent=tctx):
+            prep = self._prepare_wave(pods)
         if prep is None:
             return None
         pending, nodes, services, get_existing = prep
@@ -1105,32 +1161,26 @@ class BatchScheduler:
             # for an empty drain (the stale in-flight wave would then be
             # committed twice by the next iteration).
             try:
-                t_dr0 = time.monotonic_ns()
                 pods = self._drain_wave(timeout=0.2)
             except TimeoutError:
                 return None
-            tctx = tracing.new_ctx() if pods else None
-            if pods:
-                tracing.record("wave.drain", t_dr0, time.monotonic_ns(),
-                               parent=tctx, pods=len(pods))
-            return self._dispatch_causal(pods, solve_pool, pm, tctx=tctx)
+            return self._dispatch_causal(pods, solve_pool, pm,
+                                         tctx=self._wave_ctx(pods))
         pending = inflight.pending
         # overlap 1: drain wave k+1 while wave k solves
         t0 = time.perf_counter()
-        t_dr0 = time.monotonic_ns()
         next_pods: List[api.Pod] = []
         try:
             next_pods = self._drain_wave(timeout=self.wave_linger_s)
         except TimeoutError:
-            pass
+            # the rest of this cycle is work, not waiting for a pod: the
+            # next drain starts its wait anew
+            self._wait = None
         drain_s = time.perf_counter() - t0
         # wave k+1's trace opens at its drain; every later leg (spec
         # encode, solve, commit — or the causal re-encode on divergence)
         # attaches to this context
-        next_tctx = tracing.new_ctx() if next_pods else None
-        if next_pods:
-            tracing.record("wave.drain", t_dr0, time.monotonic_ns(),
-                           parent=next_tctx, pods=len(next_pods))
+        next_tctx = self._wave_ctx(next_pods)
         try:
             decisions = inflight.fut.result()
         except Exception as e:
@@ -1268,13 +1318,17 @@ class BatchScheduler:
             self._prewarm.stop()
 
     def _loop(self) -> None:
-        if self.pipeline:
-            if self._can_pipeline():
-                return self._loop_pipelined()
-            _log.warning("pipeline mode unavailable (%s); falling back to "
-                         "the causal wave loop",
-                         self._pipeline_unavailable_reason())
-        self._loop_causal()
+        tracing.role("wave_loop")
+        try:
+            if self.pipeline:
+                if self._can_pipeline():
+                    return self._loop_pipelined()
+                _log.warning("pipeline mode unavailable (%s); falling back "
+                             "to the causal wave loop",
+                             self._pipeline_unavailable_reason())
+            self._loop_causal()
+        finally:
+            tracing.role_end()
 
     def _loop_causal(self) -> None:
         # per-pod and per-wave failures are evented + requeued inside
@@ -1299,10 +1353,13 @@ class BatchScheduler:
             "scheduler_wave_loop_errors_total",
             "exceptions escaping the tpu-batch wave loop")
         pm = _pipeline_metrics()
+        # the loop's two side threads are the wave loop too
         solve_pool = cf.ThreadPoolExecutor(
-            max_workers=1, thread_name_prefix="tpu-batch-solve")
+            max_workers=1, thread_name_prefix="tpu-batch-solve",
+            initializer=tracing.role, initargs=("wave_loop",))
         commit_pool = cf.ThreadPoolExecutor(
-            max_workers=1, thread_name_prefix="tpu-batch-commit")
+            max_workers=1, thread_name_prefix="tpu-batch-commit",
+            initializer=tracing.role, initargs=("wave_loop",))
         inflight: Optional[_Inflight] = None
         try:
             while not self._stop.is_set():

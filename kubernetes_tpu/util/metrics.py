@@ -12,7 +12,9 @@ from __future__ import annotations
 import os
 import threading
 import time
-from typing import Dict, List, Optional, Sequence, Tuple
+from bisect import bisect_left
+from itertools import accumulate
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 __all__ = ["Counter", "Gauge", "Histogram", "Registry", "default_registry",
            "DEFAULT_BUCKETS", "APISERVER_BUCKETS", "POD_E2E_BUCKETS",
@@ -70,7 +72,7 @@ class Counter(_Metric):
         self._values: Dict[Tuple[str, ...], float] = {}
 
     def inc(self, *label_values: str, by: float = 1.0) -> None:
-        key = tuple(str(v) for v in label_values)
+        key = tuple(map(str, label_values))
         with self._lock:
             self._values[key] = self._values.get(key, 0.0) + by
 
@@ -126,22 +128,38 @@ class Histogram(_Metric):
     def __init__(self, name, help_, label_names=(), buckets=DEFAULT_BUCKETS):
         super().__init__(name, help_, label_names)
         self.buckets = tuple(sorted(buckets))
-        # per label-set: (bucket counts, total count, sum)
-        self._series: Dict[Tuple[str, ...], Tuple[List[int], int, float]] = {}
+        # per label-set: [count per bucket (its own, not cumulative; one
+        # more slot for values past the last bound), total count, sum] —
+        # an observe touches one slot, the readers accumulate
+        self._series: Dict[Tuple[str, ...], list] = {}
 
     def observe(self, value: float, *label_values: str) -> None:
-        key = tuple(str(v) for v in label_values)
+        key = tuple(map(str, label_values))
+        i = bisect_left(self.buckets, value)   # first bound >= value
         with self._lock:
-            counts, n, total = self._series.get(
-                key, ([0] * len(self.buckets), 0, 0.0))
-            for i, b in enumerate(self.buckets):
-                if value <= b:
-                    counts[i] += 1
-            self._series[key] = (counts, n + 1, total + value)
+            s = self._series.get(key)
+            if s is None:
+                s = self._series[key] = [[0] * (len(self.buckets) + 1),
+                                         0, 0.0]
+            s[0][i] += 1
+            s[1] += 1
+            s[2] += value
+
+    def _cumulative(self):
+        """[(label values, cumulative bucket counts, count, sum)], a
+        consistent copy."""
+        with self._lock:
+            return [(k, list(accumulate(c[:-1])), n, t)
+                    for k, (c, n, t) in self._series.items()]
 
     def count(self, *label_values: str) -> int:
         s = self._series.get(tuple(str(v) for v in label_values))
         return s[1] if s else 0
+
+    def sum(self, *label_values: str) -> float:
+        """Sum of the observed values (what ``_sum`` renders)."""
+        s = self._series.get(tuple(str(v) for v in label_values))
+        return s[2] if s else 0.0
 
     def quantile(self, q: float, *label_values: str) -> Optional[float]:
         """Interpolation-free bucket quantile: the UPPER BOUND of the
@@ -167,16 +185,14 @@ class Histogram(_Metric):
             return None
         counts, n, _ = s
         rank = max(1.0, q * n)
-        for i, b in enumerate(self.buckets):
-            if counts[i] >= rank:
+        for b, c in zip(self.buckets, accumulate(counts)):
+            if c >= rank:
                 return b
         return float("inf")
 
     def render(self) -> List[str]:
         out = [f"# HELP {self.name} {self.help}", f"# TYPE {self.name} {self.typ}"]
-        with self._lock:
-            items = sorted((k, (list(c), n, t)) for k, (c, n, t) in self._series.items())
-        for key, (counts, n, total) in items:
+        for key, counts, n, total in sorted(self._cumulative()):
             for b, c in zip(self.buckets, counts):
                 le = 'le="' + _num(b) + '"'
                 out.append(f"{self.name}_bucket"
@@ -197,11 +213,8 @@ class Histogram(_Metric):
         'no data' exactly when it matters — plus ``_sum``/``_count`` as
         counters (their rates are the observe rate and the mean
         numerator)."""
-        with self._lock:
-            items = [(k, (list(c), n, t))
-                     for k, (c, n, t) in self._series.items()]
         out: List[Tuple[str, str, float]] = []
-        for key, (counts, n, total) in items:
+        for key, counts, n, total in self._cumulative():
             for b, c in zip(self.buckets, counts):
                 le = 'le="' + _num(b) + '"'
                 out.append((f"{self.name}_bucket"
@@ -232,6 +245,21 @@ class Registry:
     def __init__(self):
         self._lock = threading.Lock()
         self._metrics: Dict[str, _Metric] = {}
+        self._collectors: List[Callable[[], None]] = []
+
+    def add_collector(self, fn: Callable[[], None]) -> None:
+        """``fn()`` runs before every render/sample to bring series that
+        nothing updates on a hot path (read from a clock, say) up to now."""
+        with self._lock:
+            self._collectors.append(fn)
+
+    def _all(self) -> List[_Metric]:
+        with self._lock:
+            collectors = list(self._collectors)
+        for fn in collectors:
+            fn()
+        with self._lock:
+            return [self._metrics[k] for k in sorted(self._metrics)]
 
     def counter(self, name, help_="", label_names=()) -> Counter:
         return self._get_or_make(name, Counter, help_, label_names)
@@ -266,20 +294,16 @@ class Registry:
                 f"{m.label_names}, requested {cls.__name__}{tuple(label_names)}")
 
     def render_text(self) -> str:
-        with self._lock:
-            metrics = [self._metrics[k] for k in sorted(self._metrics)]
         lines: List[str] = []
-        for m in metrics:
+        for m in self._all():
             lines.extend(m.render())
         return "\n".join(lines) + "\n"
 
     def sample(self) -> List[Tuple[str, str, float]]:
         """Every series in the registry as (name-with-labels, type,
         value) — one flight-recorder snapshot tick's raw material."""
-        with self._lock:
-            metrics = [self._metrics[k] for k in sorted(self._metrics)]
         out: List[Tuple[str, str, float]] = []
-        for m in metrics:
+        for m in self._all():
             out.extend(m.samples())
         return out
 

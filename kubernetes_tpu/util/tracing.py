@@ -49,6 +49,17 @@ Wire forms:
   in the solve header. v1/v2 clients simply omit it and are served
   untraced.
 
+**One helper, three sinks.** ``phase()`` is the one instrumentation
+point of a timed phase (the wave loop's stages and their parts): from ONE
+pair of clock readings it feeds the always-on histogram and the off-CPU
+counter, enters a ``jax.profiler.TraceAnnotation`` named ``ktpu/<name>``
+(inert without a profiler session; with one, the span lies on the device
+events' clock), and records the kube-trace span when tracing is on. This
+module never imports JAX: the annotation class is found through
+``sys.modules``, so a process that never loaded JAX writes none.
+``role()`` marks a thread's role once; a render-time collector turns the
+marks into ``process_role_cpu_seconds_total{role}``.
+
 Span taxonomy, wire encodings, and the merge pipeline are documented in
 docs/design/observability.md.
 """
@@ -58,13 +69,17 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 import uuid
 from typing import Any, Dict, Iterable, List, Optional, Tuple
 
-__all__ = ["HEADER", "enabled", "enable", "disable", "span", "child_span",
-           "start", "record", "current", "new_ctx", "wire", "parse",
+from kubernetes_tpu.util import metrics
+
+__all__ = ["HEADER", "ANNOTATION_PREFIX", "enabled", "enable", "disable",
+           "span", "child_span", "phase", "clocks", "record", "current",
+           "new_ctx", "wire", "parse", "role", "role_end", "role_cpu_seconds",
            "drain", "loss_peek", "chrome_trace", "NOP"]
 
 HEADER = "X-KTPU-Trace"
@@ -216,9 +231,6 @@ class _NopSpan:
     def set(self, **attrs):
         return self
 
-    def finish(self, **attrs):
-        return None
-
 
 NOP = _NopSpan()
 
@@ -242,9 +254,12 @@ class _Span:
         self._t0 = 0
         self._pushed = False
 
-    def __enter__(self):
+    def push(self):
         _ctx_stack().append(self.ctx)
         self._pushed = True
+
+    def __enter__(self):
+        self.push()
         self._t0 = time.monotonic_ns()
         return self
 
@@ -252,12 +267,7 @@ class _Span:
         self.attrs.update(attrs)
         return self
 
-    def finish(self, **attrs):
-        self.attrs.update(attrs)
-        self.__exit__(None, None, None)
-
-    def __exit__(self, exc_type, exc, tb):
-        end = time.monotonic_ns()
+    def close(self, t0, end, exc_type, emit=True):
         if self._pushed:
             st = _ctx_stack()
             if st and st[-1] == self.ctx:
@@ -265,7 +275,11 @@ class _Span:
             self._pushed = False
         if exc_type is not None:
             self.attrs.setdefault("error", exc_type.__name__)
-        _emit(self.name, self.ctx, self.psid, self._t0, end, self.attrs)
+        if emit:
+            _emit(self.name, self.ctx, self.psid, t0, end, self.attrs)
+
+    def __exit__(self, exc_type, exc, tb):
+        self.close(self._t0, time.monotonic_ns(), exc_type)
         return False
 
 
@@ -293,16 +307,151 @@ def child_span(name: str, **attrs):
     return _Span(name, st[-1], attrs)
 
 
-def start(name: str, parent=_AMBIENT, **attrs):
-    """Manually-finished span for lifetimes that cross threads: returns
-    a handle with ``.ctx`` and ``.finish(**attrs)``. Unlike ``span()``
-    it does NOT install ambient context (the owner may finish it from
-    another thread)."""
-    if not _on:
-        return NOP
-    s = _Span(name, parent, attrs)
-    s._t0 = time.monotonic_ns()
-    return s
+# -- phases: one instrumentation point, three sinks ---------------------------
+
+ANNOTATION_PREFIX = "ktpu/"
+
+_OFFCPU = metrics.default_registry().counter(
+    "scheduler_wave_offcpu_seconds_total",
+    "Seconds a thread held a phase open without running: wall less the "
+    "thread's own CPU time (waiting for the device, a socket, a condition "
+    "or the interpreter lock)", ("span",))
+
+# jax.profiler.TraceAnnotation, once JAX has been loaded by somebody else
+_annotation = None
+
+
+def _find_annotation():
+    """The profiler's annotation class if this process has loaded JAX, else
+    None (asked again next time: JAX may be imported later, or be halfway
+    through its import on another thread right now)."""
+    global _annotation
+    cls = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+    if cls is not None:
+        _annotation = cls
+    return cls
+
+
+_CPU_REUSE_NS = 20_000
+
+
+def _thread_cpu_ns(now_ns: int) -> int:
+    """The calling thread's CPU clock at ``now_ns``, a monotonic reading
+    taken just before. The clock is a system call, and on some hosts a slow
+    one (5.8 us on the benchmark's, sixty monotonic readings). Phases
+    follow and nest in one another within microseconds, so a reading
+    younger than 20 us is carried forward as if the thread had run since:
+    in so short a gap it cannot have waited for long. Only fresh readings
+    are kept, so carried ones never chain."""
+    last = getattr(_tls, "cpu", None)
+    if last is not None and 0 <= now_ns - last[0] < _CPU_REUSE_NS:
+        return last[1] + now_ns - last[0]
+    cpu = time.thread_time_ns()
+    _tls.cpu = (now_ns, cpu)
+    return cpu
+
+
+def clocks() -> Tuple[int, int]:
+    """``(monotonic_ns, thread CPU ns)`` now: a phase's ``since=`` when its
+    start lies before the ``with`` block (a wait carried over empty ticks)."""
+    now = time.monotonic_ns()
+    return now, _thread_cpu_ns(now)
+
+
+class _Phase:
+    __slots__ = ("name", "hist", "label", "wall_s", "_span", "_ann", "_t0",
+                 "_c0", "_live")
+
+    def __init__(self, name, hist, label, span, ann, since):
+        self.name = name
+        self.hist = hist
+        self.label = label
+        self._span = span
+        self._ann = ann
+        self._live = True
+        self.wall_s = 0.0            # set on exit
+        self._t0, self._c0 = since if since is not None else (0, 0)
+
+    @property
+    def ctx(self):
+        return self._span.ctx if self._span is not None else None
+
+    def set(self, **attrs):
+        if self._span is not None:
+            self._span.attrs.update(attrs)
+        return self
+
+    def cancel(self):
+        """Feed neither the histogram nor kube-trace on exit (an empty
+        tick of a polling loop is not an occurrence of the phase)."""
+        self._live = False
+        return self
+
+    def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self._span is not None:
+            self._span.push()
+        if not self._t0:
+            self._t0 = time.monotonic_ns()
+            if self.hist is not None:
+                self._c0 = _thread_cpu_ns(self._t0)
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        end = time.monotonic_ns()
+        span = self._span
+        if span is not None:
+            span.close(self._t0, end, exc_type, self._live)
+        wall = end - self._t0
+        self.wall_s = wall * 1e-9
+        if self._live and self.hist is not None:
+            # a start taken on another thread (``since``) has another CPU
+            # clock: the clamp keeps 0 <= offcpu <= wall whatever it read
+            off = min(max(wall - (_thread_cpu_ns(end) - self._c0), 0), wall)
+            if self.label is None:
+                self.hist.observe(wall * 1e-9)
+            else:
+                self.hist.observe(wall * 1e-9, self.label)
+            _OFFCPU.inc(self.name, by=off * 1e-9)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+
+def phase(name: str, hist=None, label: Optional[str] = None, *,
+          parent=_AMBIENT, traced: bool = True, since=None, detail: str = "",
+          **attrs):
+    """Context manager round one timed phase; use it as a ``with`` block at
+    the site (a wrapper function would put one more frame on the wave
+    loop's stack). On exit, from one pair of clock readings:
+
+    1. always, when ``hist`` is given: ``hist.observe(wall[, label])`` and
+       ``wall - thread CPU`` onto ``scheduler_wave_offcpu_seconds_total
+       {span=name}``;
+    2. always: a ``jax.profiler.TraceAnnotation`` named ``ktpu/<name>``
+       (``ktpu/<name>.<detail>`` with ``detail``) — inert without a
+       profiler session, absent in a process that never loaded JAX;
+    3. with kube-trace on and ``traced``: the span ``name`` under
+       ``parent`` — an explicit context, None for a new root, or by default
+       the thread's ambient span and NO span where there is none (shared
+       code also runs off any traced path). The span is ambient inside the
+       block, as ``span()``'s is.
+
+    ``since`` (from ``clocks()``) moves the start of 1 and 3 back."""
+    ann = _annotation or _find_annotation()
+    if ann is not None:
+        ann = ann(ANNOTATION_PREFIX + name + "." + detail if detail
+                  else ANNOTATION_PREFIX + name)
+    sp = None
+    if _on and traced:
+        if parent is _AMBIENT:
+            parent = current()
+            if parent is not None:
+                sp = _Span(name, parent, attrs)
+        else:
+            sp = _Span(name, parent, attrs)
+    return _Phase(name, hist, label, sp, ann, since)
 
 
 def record(name: str, start_ns: int, end_ns: int, parent=None,
@@ -328,6 +477,86 @@ def _emit(name, ctx, psid, t0, end, attrs) -> None:
         "thr": threading.current_thread().name,
         "attrs": attrs,
     })
+
+
+# -- interpreter time by thread role -----------------------------------------
+# A thread marks its role once, where the role begins; nothing runs on any
+# hot path after that. The collector (when a registry renders) reads every
+# marked thread's CPU clock. CPU seconds are not lock-held seconds — C code
+# runs without the interpreter lock — but over a window they say who used
+# the one interpreter while another thread waited.
+
+ROLES = ("wave_loop", "http", "watch_send", "reflector")
+
+_roles_lock = threading.Lock()
+# thread ident -> (role, the thread's CPU clock id, cpu_ns at the mark)
+_roles: Dict[int, tuple] = {}
+# role -> cpu_ns of threads that ended or re-marked
+_banked: Dict[str, int] = {}
+_T_IMPORT = time.monotonic()
+
+
+def role(name: Optional[str]) -> None:
+    """The calling thread runs as ``name`` from here on (None: as nothing).
+    Its CPU so far is banked to the role it had."""
+    now = time.thread_time_ns()
+    ident = threading.get_ident()
+    with _roles_lock:
+        prev = _roles.pop(ident, None)
+        if prev is not None:
+            _banked[prev[0]] = _banked.get(prev[0], 0) + now - prev[2]
+        if name is not None:
+            # the clock id is taken by the thread itself, while it surely
+            # lives: reading it for a thread that ended fails cleanly
+            _roles[ident] = (name, time.pthread_getcpuclockid(ident), now)
+
+
+def role_end() -> None:
+    """The calling thread's role ends (its ``finally``): bank its CPU."""
+    role(None)
+
+
+def role_cpu_seconds() -> Dict[str, float]:
+    """CPU seconds by role so far — banked plus every live marked thread's
+    clock — with ``other``: the process's CPU less all of them."""
+    live = {t.ident for t in threading.enumerate()}
+    with _roles_lock:
+        total = dict(_banked)
+        for ident, (name, clock_id, c0) in list(_roles.items()):
+            try:
+                if ident not in live:
+                    raise OSError    # its clock id may be another's by now
+                total[name] = total.get(name, 0) + \
+                    time.clock_gettime_ns(clock_id) - c0
+            except OSError:
+                # ended without role_end(): its last stretch is lost
+                del _roles[ident]
+        process = time.process_time_ns()
+    out = dict.fromkeys(ROLES, 0.0)
+    out.update((name, ns * 1e-9) for name, ns in total.items())
+    out["other"] = max(0.0, process * 1e-9 - sum(out.values()))
+    return out
+
+
+_ROLE_CPU = metrics.default_registry().counter(
+    "process_role_cpu_seconds_total",
+    "CPU seconds of this process by the role its threads marked "
+    "(other: process CPU less the marked threads)", ("role",))
+_WALL = metrics.default_registry().counter(
+    "process_wall_seconds_total",
+    "Wall seconds since this process loaded its tracing module")
+_collect_lock = threading.Lock()
+
+
+def _collect_roles() -> None:
+    """Render-time collector: bring the two counters up to now."""
+    with _collect_lock:
+        for name, seconds in role_cpu_seconds().items():
+            _ROLE_CPU.inc(name, by=max(0.0, seconds - _ROLE_CPU.value(name)))
+        _WALL.inc(by=time.monotonic() - _T_IMPORT - _WALL.value())
+
+
+metrics.default_registry().add_collector(_collect_roles)
 
 
 # -- collection -------------------------------------------------------------

@@ -2,7 +2,11 @@
 buffer's never-block/evict-oldest contract, trace-context propagation
 over the delta wire (v3) and over HTTP (X-KTPU-Trace, live two-process),
 Chrome-trace export validity, the <1% disabled-path overhead guard, and
-the Histogram.quantile semantics the latency record section relies on.
+the Histogram.quantile semantics the latency record section relies on;
+then ``tracing.phase`` — one instrumentation point, three sinks (always-on
+histogram + off-CPU counter, a ``ktpu/`` annotation on the profiler's
+clock, the kube-trace span) — the wave loop's sites that go through it,
+the FIFO's queue wait and the thread-role CPU counters.
 
 The contract under test (docs/design/observability.md): tracing OFF is
 free and the default; tracing ON never blocks a hot path (the ring
@@ -138,16 +142,6 @@ class TestSpans:
         assert done.wait(5)
         (sp,) = tracing.drain()["spans"]
         assert sp["tid"] == ctx[0] and sp["psid"] == ctx[1]
-
-    def test_start_finish_handle_does_not_install_ambient(self):
-        fresh()
-        h = tracing.start("wave", pods=3)
-        assert tracing.current() is None  # owner may finish elsewhere
-        h.set(bound=3)
-        h.finish(committed=True)          # finish-time attrs recorded too
-        (sp,) = tracing.drain()["spans"]
-        assert sp["name"] == "wave"
-        assert sp["attrs"] == {"pods": 3, "bound": 3, "committed": True}
 
     def test_record_retroactive_span(self):
         fresh()
@@ -451,6 +445,362 @@ class TestChromeExport:
         assert sd["pid"] == 999
 
 
+# -- tracing.phase: one helper, three sinks ----------------------------------
+
+def _offcpu(name):
+    return tracing._OFFCPU.value(name)
+
+
+def _spin(seconds):
+    end = time.thread_time() + seconds
+    while time.thread_time() < end:
+        pass
+
+
+class TestPhase:
+    def test_feeds_sum_count_and_offcpu_from_one_reading(self):
+        h = metrics.Histogram("h", "t", ("part",), buckets=(0.1, 1.0))
+        off0 = _offcpu("t.phase.a")
+        for _ in range(3):
+            with tracing.phase("t.phase.a", h, "a") as ph:
+                time.sleep(0.002)
+        assert h.count("a") == 3
+        assert h.sum("a") >= 3 * 0.002
+        # the block's own reading is what the histogram got last
+        assert ph.wall_s >= 0.002 and h.sum("a") >= ph.wall_s
+        off = _offcpu("t.phase.a") - off0
+        assert 0.0 <= off <= h.sum("a")
+        # no histogram, no off-CPU series (annotation-only sites)
+        with tracing.phase("t.phase.bare"):
+            pass
+        assert ("t.phase.bare",) not in tracing._OFFCPU.by_label()
+
+    @pytest.mark.parametrize("body,lo,hi", [
+        ("sleep", 0.8, 1.0),     # held open without running: all off-CPU
+        ("spin", 0.0, 0.25),     # ran the whole time: next to none
+    ])
+    def test_offcpu_is_wall_less_thread_cpu(self, body, lo, hi):
+        h = metrics.Histogram("h", "t", buckets=(1.0,))
+        name = "t.phase." + body
+        shares = []
+        for _ in range(3):       # best of three: a loaded box preempts a spin
+            off0, sum0 = _offcpu(name), h.sum()
+            with tracing.phase(name, h):
+                time.sleep(0.05) if body == "sleep" else _spin(0.05)
+            shares.append((_offcpu(name) - off0) / (h.sum() - sum0))
+        assert all(0.0 <= s <= 1.0 for s in shares)
+        best = max(shares) if body == "sleep" else min(shares)
+        assert lo <= best <= hi, shares
+
+    def test_records_the_kube_trace_span_span_would(self):
+        """Same name, same parent, same ring as tracing.span at the
+        site; ambient inside the block (RemoteSolver ships it)."""
+        fresh()
+        ctx = tracing.new_ctx()
+        with tracing.phase("wave.solve", parent=ctx, pods=4) as ph:
+            assert tracing.current() == ph.ctx
+            with tracing.phase("wave.solve.ship"):     # ambient child
+                pass
+            ph.set(extra=1)
+        with tracing.phase("wave.encode", parent=None):  # new root
+            pass
+        ship, solve_sp, enc = tracing.drain()["spans"]
+        assert (solve_sp["name"], solve_sp["tid"], solve_sp["psid"]) == \
+            ("wave.solve", ctx[0], ctx[1])
+        assert solve_sp["attrs"] == {"pods": 4, "extra": 1}
+        assert (ship["name"], ship["tid"], ship["psid"]) == \
+            ("wave.solve.ship", ctx[0], solve_sp["sid"])
+        assert enc["psid"] == "" and enc["tid"] != ctx[0]
+        assert tracing.current() is None
+
+    def test_ambient_default_and_untraced_record_nothing_alone(self):
+        """Shared code (batch_solver.solve off the wave loop, an HTTP
+        request with no header) keeps time but opens no root trace."""
+        fresh()
+        h = metrics.Histogram("h", "t", buckets=(1.0,))
+        with tracing.phase("wave.solve.route", h):
+            pass
+        with tracing.phase("http.get", parent=None, traced=False):
+            pass
+        assert tracing.drain()["spans"] == []
+        assert h.count() == 1
+        tracing.disable()
+        with tracing.phase("wave.solve", h, parent=None) as ph:
+            assert ph.ctx is None and tracing.current() is None
+        assert h.count() == 2
+
+    def test_cancel_and_since_carry_a_wait_over_empty_ticks(self):
+        fresh()
+        h = metrics.Histogram("h", "t", buckets=(1.0,))
+        since = tracing.clocks()
+        with pytest.raises(TimeoutError):
+            with tracing.phase("wave.drain.wait", h, parent=None,
+                               since=since) as ph:
+                time.sleep(0.01)
+                ph.cancel()
+                raise TimeoutError
+        assert h.count() == 0 and tracing.drain()["spans"] == []
+        with tracing.phase("wave.drain.wait", h, parent=None, since=since):
+            pass
+        (sp,) = tracing.drain()["spans"]
+        assert h.count() == 1 and h.sum() >= 0.01
+        assert sp["t0"] == since[0] and sp["dur"] >= 10_000_000
+
+    def test_cpu_clock_is_read_once_for_adjacent_phases(self, monkeypatch):
+        """The thread's CPU clock is a system call (a slow one on the
+        benchmark's host): a reading younger than 20 us is carried
+        forward, an older one taken anew, and carried ones never chain."""
+        reads = []
+        real = time.thread_time_ns
+        monkeypatch.setattr(time, "thread_time_ns",
+                            lambda: reads.append(1) or real())
+        tracing._tls.cpu = None
+        now = time.monotonic_ns()
+        a = tracing._thread_cpu_ns(now)
+        b = tracing._thread_cpu_ns(now + 5_000)
+        c = tracing._thread_cpu_ns(now + 19_000)
+        d = tracing._thread_cpu_ns(now + 25_000)
+        assert len(reads) == 2
+        assert (b, c) == (a + 5_000, a + 19_000) and d >= a
+
+    def test_imports_and_runs_without_jax(self):
+        """An apiserver of the multi-process deployment never loads JAX:
+        the helper must not, and then writes no annotation."""
+        code = ("import sys\n"
+                "from kubernetes_tpu.util import tracing, metrics\n"
+                "h = metrics.Histogram('h', 't', buckets=(1.0,))\n"
+                "with tracing.phase('wave.solve', h, parent=None):\n"
+                "    pass\n"
+                "tracing.role('http'); tracing.role_end()\n"
+                "assert h.count() == 1\n"
+                "assert tracing._annotation is None\n"
+                "assert not [m for m in sys.modules if m == 'jax' "
+                "or m.startswith('jax.')], 'jax loaded'\n")
+        proc = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                              capture_output=True, text=True, timeout=60)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+
+    def test_annotation_lies_on_the_profilers_clock(self, tmp_path):
+        """With a jax.profiler session open, the phase is a ktpu/<name>
+        event of the host plane, inside an enclosing TraceAnnotation —
+        one clock with everything else the profiler records. Its own
+        process and time limit: a session is process-wide state."""
+        code = (
+            "import glob, os, sys, time\n"
+            "import jax\n"
+            "from jax.profiler import ProfileData, TraceAnnotation\n"
+            "from kubernetes_tpu.util import tracing\n"
+            "opts = jax.profiler.ProfileOptions()\n"
+            "opts.python_tracer_level = 0\n"
+            "jax.profiler.start_trace(sys.argv[1], profiler_options=opts)\n"
+            "with TraceAnnotation('bench/solve'):\n"
+            "    time.sleep(0.002)\n"
+            "    with tracing.phase('wave.solve.ship'):\n"
+            "        time.sleep(0.002)\n"
+            "    with tracing.phase('http.post', detail='pods',\n"
+            "                       traced=False):\n"
+            "        pass\n"
+            "    time.sleep(0.002)\n"
+            "jax.profiler.stop_trace()\n"
+            "path = glob.glob(os.path.join(sys.argv[1], '**',\n"
+            "                 '*.xplane.pb'), recursive=True)[0]\n"
+            "host = [p for p in ProfileData.from_file(path).planes\n"
+            "        if p.name == '/host:CPU'][0]\n"
+            "ev = {e.name: (e.start_ns, e.start_ns + e.duration_ns)\n"
+            "      for line in host.lines for e in line.events\n"
+            "      if e.name.startswith(('bench/', 'ktpu/'))}\n"
+            "assert set(ev) == {'bench/solve', 'ktpu/wave.solve.ship',\n"
+            "                   'ktpu/http.post.pods'}, sorted(ev)\n"
+            "o0, o1 = ev['bench/solve']\n"
+            "s0, s1 = ev['ktpu/wave.solve.ship']\n"
+            "assert o0 < s0 and s1 < o1 and s1 - s0 >= 2_000_000, ev\n")
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        proc = subprocess.run([sys.executable, "-c", code, str(tmp_path)],
+                              cwd=REPO, env=env, capture_output=True,
+                              text=True, timeout=120)
+        assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+# -- the wave loop's sites ----------------------------------------------------
+
+def _bare_scheduler(next_pod, queue=None, **kw):
+    """A BatchScheduler over stand-ins: only what _drain_wave and
+    _solve_snap touch."""
+    import types
+
+    from kubernetes_tpu.scheduler.tpu_batch import BatchScheduler
+    config = types.SimpleNamespace(next_pod=next_pod, provider=None,
+                                   policy=None, mesh="off")
+    factory = types.SimpleNamespace(pod_queue=queue)
+    return BatchScheduler(config, factory, client=None, **kw)
+
+
+def _part(name):
+    from kubernetes_tpu.models.batch_solver import wave_parts
+    return wave_parts().count(name), wave_parts().sum(name)
+
+
+class TestWaveSites:
+    def test_cpu_wave_observes_each_solve_part_once(self, monkeypatch):
+        """One wave through _solve_snap on the CPU (the kernel through
+        the interpreter): hostprep, route, ship, launch, readback and
+        post observed once each, and together inside the solve's own
+        time — with the wave's kube-trace spans hung under wave.solve."""
+        from kubernetes_tpu.scheduler import tpu_batch
+        monkeypatch.setenv("KTPU_PALLAS", "interpret")
+        monkeypatch.setenv("KTPU_PREWARM", "off")
+        sched = _bare_scheduler(next_pod=None)
+        snap = small_snapshot("parts")
+        parts = ["solve.hostprep", "solve.route", "solve.ship",
+                 "solve.launch", "solve.readback", "solve.post"]
+        solve_h = tpu_batch._wave_metrics().solve
+        before = {p: _part(p) for p in parts}
+        n0, s0 = solve_h.count(), solve_h.sum()
+        names0 = _part("names")[0]
+        off0 = _offcpu("wave.solve")
+        fresh()
+        ctx = tracing.new_ctx()
+        decisions = sched._solve_snap(snap, 9, tctx=ctx)
+        assert len(decisions.hosts) == 9 and all(decisions.hosts)
+        took = solve_h.sum() - s0
+        assert solve_h.count() - n0 == 1
+        assert all(_part(p)[0] - before[p][0] == 1 for p in parts)
+        total = sum(_part(p)[1] - before[p][1] for p in parts)
+        assert 0.0 < total <= took
+        assert 0.0 <= _offcpu("wave.solve") - off0 <= took
+        assert _part("names")[0] - names0 == 1
+        spans = {s["name"]: s for s in tracing.drain()["spans"]}
+        assert {"wave." + p for p in parts} <= set(spans)
+        assert all(spans["wave." + p]["psid"] == spans["wave.solve"]["sid"]
+                   for p in parts)
+        assert spans["wave.names"]["psid"] == ctx[1]
+
+    def test_drain_counts_why_each_wave_was_cut(self):
+        from kubernetes_tpu.client.cache import FIFO
+        from kubernetes_tpu.scheduler import tpu_batch
+        wm = tpu_batch._wave_metrics()
+        queue = FIFO()
+
+        def slow_pop(timeout=None):      # pods keep coming, slowly
+            time.sleep(0.004)
+            return queue.pop(timeout=timeout)
+
+        def cuts():
+            return {r: wm.cut.value(r) for r in ("full", "linger", "empty")}
+
+        for i in range(40):
+            queue.add(mk_pod(f"cut-{i}"))
+        left0, c0 = wm.queue_left.value(), cuts()
+        sched = _bare_scheduler(queue.pop, queue, wave_size=4,
+                                wave_linger_s=0.5)
+        assert len(sched._drain_wave(0.2)) == 4          # full: 36 left
+        sched.config.next_pod = slow_pop
+        sched.wave_size, sched.wave_linger_s = 1024, 0.02
+        took = len(sched._drain_wave(0.2))               # linger
+        assert 1 <= took < 36
+        sched.config.next_pod = queue.pop
+        sched.wave_linger_s = 0.3
+        assert len(sched._drain_wave(0.2)) == 36 - took  # empty: dry queue
+        got = {r: v - c0[r] for r, v in cuts().items()}
+        assert got == {"full": 1, "linger": 1, "empty": 1}
+        assert wm.queue_left.value() - left0 == 36 + (36 - took) + 0
+
+    def test_drain_wait_spans_the_empty_ticks_before_the_pod(self):
+        from kubernetes_tpu.client.cache import FIFO
+        queue = FIFO()
+        sched = _bare_scheduler(queue.pop, queue, wave_linger_s=0.0)
+        n0, s0 = _part("drain.wait")
+        fresh()
+        for _ in range(2):               # two empty ticks: no wave, no span
+            with pytest.raises(TimeoutError):
+                sched._drain_wave(0.02)
+        assert _part("drain.wait")[0] == n0
+        assert tracing.drain()["spans"] == []
+        queue.add(mk_pod("late"))
+        assert len(sched._drain_wave(0.02)) == 1
+        n1, s1 = _part("drain.wait")
+        assert n1 - n0 == 1 and s1 - s0 >= 0.04   # the whole wait, once
+        tctx = sched._wave_ctx([object()])
+        spans = tracing.drain()["spans"]
+        assert [s["name"] for s in spans] == ["wave.drain.wait",
+                                              "wave.drain.collect"]
+        assert all((s["tid"], s["psid"]) == tctx for s in spans)
+        assert spans[0]["dur"] >= 40_000_000
+
+    def test_fifo_wait_is_observed_once_a_pod_from_its_first_add(self):
+        from kubernetes_tpu.client.cache import FIFO
+        h = metrics.Histogram("w", "t", buckets=(0.01, 1.0))
+        queue = FIFO(wait_hist=h)
+        a, b = mk_pod("qa"), mk_pod("qb")
+        queue.add(a)
+        time.sleep(0.03)
+        queue.add(a)                     # coalesced: keeps the first stamp
+        queue.add(b)
+        assert queue.pop(0.1) is a
+        assert h.count() == 1 and h.sum() >= 0.03
+        queue.delete(b)                  # never popped: never observed
+        with pytest.raises(TimeoutError):
+            queue.pop(0.01)
+        queue.replace([b])               # a relist stamps what is new
+        assert queue.pop(0.1) is b
+        assert h.count() == 2 and h.sum() < 0.03 + 0.02
+        assert FIFO()._wait_hist is None  # other queues keep no stamps
+
+
+# -- interpreter time by thread role ------------------------------------------
+
+class TestRoles:
+    def _run(self, target):
+        t = threading.Thread(target=target)
+        t.start()
+        t.join(10)
+        assert not t.is_alive()
+
+    def test_spinning_thread_shows_under_its_role_and_remark_banks(self):
+        seen = {}
+
+        def worker():
+            tracing.role("t_role_a")
+            _spin(0.05)
+            seen["live"] = tracing.role_cpu_seconds()
+            tracing.role("t_role_b")     # banks the 50 ms to t_role_a
+            seen["after"] = tracing.role_cpu_seconds()
+            _spin(0.02)
+            tracing.role_end()
+
+        self._run(worker)
+        assert seen["live"]["t_role_a"] >= 0.04
+        assert seen["after"]["t_role_a"] >= 0.04
+        assert seen["after"]["t_role_b"] < 0.01
+        done = tracing.role_cpu_seconds()
+        assert 0.04 <= done["t_role_a"] < 0.07    # no longer growing
+        assert done["t_role_b"] >= 0.015
+        assert done["other"] >= 0.0
+        assert set(tracing.ROLES) <= set(done)
+
+    def test_thread_that_ended_unmarked_is_dropped_not_fatal(self):
+        self._run(lambda: tracing.role("t_role_gone"))
+        cpu = tracing.role_cpu_seconds()   # its clock is gone: no raise
+        assert cpu.get("t_role_gone", 0.0) < 0.01
+        assert all(r[0] != "t_role_gone" for r in tracing._roles.values())
+
+    def test_registry_renders_roles_and_wall_through_the_collector(self):
+        self._run(lambda: (tracing.role("t_role_c"), _spin(0.02),
+                           tracing.role_end()))
+        text = metrics.default_registry().render_text()
+        assert 'process_role_cpu_seconds_total{role="t_role_c"} 0.0' in text
+        assert 'process_role_cpu_seconds_total{role="other"}' in text
+        wall = [ln for ln in text.splitlines()
+                if ln.startswith("process_wall_seconds_total ")]
+        assert len(wall) == 1 and float(wall[0].split()[1]) > 0.0
+        calls = []
+        reg = metrics.Registry()
+        reg.add_collector(lambda: calls.append(1))
+        reg.render_text()
+        reg.sample()
+        assert len(calls) == 2
+
+
 # -- overhead guard ----------------------------------------------------------
 
 class TestOverheadGuard:
@@ -499,6 +849,64 @@ class TestOverheadGuard:
         assert per_wave_s < 0.01 * stage_s, (
             f"disabled tracing {per_wave_s * 1e6:.2f}us/wave vs stage "
             f"{stage_s * 1e3:.2f}ms — over the 1% budget")
+
+    def test_always_on_phases_under_1pct_of_a_wave(self):
+        """What tracing.phase costs with kube-trace OFF and no profiler
+        session — the state of every production run — costed like the
+        disabled path above, min-of-N on both sides. A phase is two clock
+        pairs, a histogram observe, a counter add and an inert
+        annotation, so it cannot be free like the no-op span: ONE call
+        must stay under 1% of the cheapest real encode, and one wave's
+        worth of them (16: the table in docs/design/observability.md)
+        under 1% of the cheapest wave cycle the loop runs below
+        capacity — the default 20 ms linger plus that encode (at
+        capacity a wave is ~100 pods and its cycle ten times that)."""
+        import inspect
+
+        from kubernetes_tpu.scheduler.tpu_batch import BatchScheduler
+        linger_s = inspect.signature(
+            BatchScheduler.__init__).parameters["wave_linger_s"].default
+        tracing.disable()
+        nodes = [mk_node(f"ov-n{i}") for i in range(128)]
+        pending = [mk_pod(f"ov-p{j}") for j in range(256)]
+        encode_snapshot(nodes, [], pending, [])  # warm the path
+
+        def one_encode():
+            t0 = time.perf_counter()
+            encode_snapshot(nodes, [], pending, [])
+            return time.perf_counter() - t0
+
+        stage_s = min(one_encode() for _ in range(5))
+        # histograms of the real ones' shapes, outside the registry
+        buckets = (0.001, 0.0025, 0.005, 0.01, 0.02, 0.05, 0.1, 0.25, 0.5,
+                   1.0, 2.5)
+        part = metrics.Histogram("p", "t", ("part",), buckets=buckets)
+        stage = metrics.Histogram("s", "t", buckets=buckets)
+        parts = ["drain.wait", "drain.collect", "prepare", "solve.hostprep",
+                 "solve.route", "solve.ship", "solve.launch",
+                 "solve.readback", "solve.post", "names", "commit.build",
+                 "commit.bind", "commit.assume"]
+
+        def helper_waves(n=2_000):
+            t0 = time.perf_counter()
+            for _ in range(n):
+                for label in parts:
+                    with tracing.phase("t.ovh." + label, part, label):
+                        pass
+                for name in ("t.ovh.encode", "t.ovh.solve", "t.ovh.commit"):
+                    with tracing.phase(name, stage, parent=None):
+                        pass
+            return (time.perf_counter() - t0) / n
+
+        per_wave_s = min(helper_waves() for _ in range(5))
+        per_call_s = per_wave_s / (len(parts) + 3)
+        assert stage.count() == 5 * 2_000 * 3
+        assert per_call_s < 0.01 * stage_s, (
+            f"one always-on phase {per_call_s * 1e6:.2f}us vs encode "
+            f"{stage_s * 1e3:.2f}ms — over the 1% budget")
+        assert per_wave_s < 0.01 * (linger_s + stage_s), (
+            f"always-on phases {per_wave_s * 1e6:.1f}us/wave vs a "
+            f"{(linger_s + stage_s) * 1e3:.1f}ms wave cycle — over 1%")
 
 
 # -- Histogram.quantile semantics (the latency record contract) --------------
