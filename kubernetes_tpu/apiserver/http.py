@@ -44,6 +44,7 @@ from kubernetes_tpu.api import types as api
 from kubernetes_tpu.apiserver import fairshed as fairshed_mod
 from kubernetes_tpu.auth import AuthRequest
 from kubernetes_tpu.util import chaos
+from kubernetes_tpu.util import gcpolicy
 from kubernetes_tpu.util import metrics as metrics_pkg
 from kubernetes_tpu.util import tracing
 
@@ -1207,6 +1208,8 @@ class APIServer:
         return f"http://{host}:{port}"
 
     def start(self) -> "APIServer":
+        # the process that serves the API holds the cluster's objects
+        gcpolicy.ensure()
         self._thread = threading.Thread(target=self._httpd.serve_forever,
                                         kwargs={"poll_interval": 0.05},
                                         daemon=True, name="apiserver-http")

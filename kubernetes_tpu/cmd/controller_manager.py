@@ -61,6 +61,8 @@ def build_manager(opts):
 def controller_manager_server(argv: List[str],
                               ready: Optional[threading.Event] = None,
                               stop: Optional[threading.Event] = None) -> int:
+    from kubernetes_tpu.util import gcpolicy
+    gcpolicy.ensure()
     try:
         opts = build_parser().parse_args(argv)
         manager = build_manager(opts)

@@ -40,6 +40,8 @@ def build_proxy(opts):
 def proxy_server(argv: List[str],
                  ready: Optional[threading.Event] = None,
                  stop: Optional[threading.Event] = None) -> int:
+    from kubernetes_tpu.util import gcpolicy
+    gcpolicy.ensure()
     try:
         opts = build_parser().parse_args(argv)
     except argparse.ArgumentError as e:

@@ -67,6 +67,8 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    from kubernetes_tpu.util import gcpolicy
+    gcpolicy.ensure()
     try:
         opts = build_parser().parse_args(argv)
     except argparse.ArgumentError as e:

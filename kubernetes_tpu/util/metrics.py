@@ -145,6 +145,20 @@ class Histogram(_Metric):
             s[1] += 1
             s[2] += value
 
+    def load(self, counts: Sequence[int], total: float,
+             *label_values: str) -> None:
+        """Set a series from accumulators a render-time collector keeps
+        where no lock may be taken: ``counts`` as observe() keeps them
+        (per bucket, one more slot past the last bound), ``total`` the sum
+        of the observed values."""
+        counts = list(counts)
+        if len(counts) != len(self.buckets) + 1:
+            raise ValueError(f"{self.name}: {len(counts)} counts for "
+                             f"{len(self.buckets)} buckets")
+        with self._lock:
+            self._series[tuple(map(str, label_values))] = \
+                [counts, sum(counts), total]
+
     def _cumulative(self):
         """[(label values, cumulative bucket counts, count, sum)], a
         consistent copy."""
