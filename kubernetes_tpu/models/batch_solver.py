@@ -852,14 +852,25 @@ def solve_device(inp: SolverInputs, pol: Optional[BatchPolicy],
 
 
 def wave_programs() -> metrics.Counter:
-    """Waves dispatched by solve_device, by the program that solved them
-    (``pallas`` kernel or XLA ``scan``) and the platform their inputs
-    lived on — a TPU process's host-routed waves count under ``cpu``."""
+    """Waves by the program that solved them — ``pallas`` kernel or XLA
+    ``scan`` (counted by solve_device), ``scan-sharded`` (the GSPMD scan
+    of solve's mesh arm) — and the platform their inputs lived on: a TPU
+    process's host-routed waves count under ``cpu``."""
     return metrics.default_registry().counter(
         "solver_wave_program_total",
-        "Waves dispatched by solve_device, by compiled program and the "
-        "platform of the device that held their inputs",
+        "Waves dispatched by solve_device or solve's mesh arm, by compiled "
+        "program and the platform of the device(s) that held their inputs",
         ("program", "platform"))
+
+
+def mesh_placed_bytes() -> metrics.Counter:
+    """Bytes ``solve``'s mesh arm placed onto the mesh (``device_put`` of
+    the padded resident and wave planes, a replicated plane counted once):
+    all of them on every wave, nothing being resident in-process."""
+    return metrics.default_registry().counter(
+        "solver_mesh_placed_bytes_total",
+        "Bytes of solver planes placed onto the device mesh by in-process "
+        "sharded waves")
 
 
 _WAVE_PART_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
@@ -1098,6 +1109,20 @@ def _mesh_min_nodes() -> int:
     return DEFAULT_MESH_MIN_NODES
 
 
+def _takes_mesh(host: SolverInputs, mesh, pol, gangs: bool,
+                peer_bound: int) -> bool:
+    """Whether a wave solves over ``mesh``: only at or above the node
+    floor, and only outside the kernel's domain — a cluster that fits one
+    core's VMEM is faster on one device, since sharding the node axis
+    puts a cross-shard argmax inside every pod step (docs/design/
+    solver.md)."""
+    if mesh is None or int(host.cap.shape[0]) < _mesh_min_nodes():
+        return False
+    from kubernetes_tpu.ops import pallas_solver
+    return not pallas_solver.eligible(host, pol or BatchPolicy(), gangs,
+                                      peer_bound)
+
+
 def solve(snap: ClusterSnapshot,
           host: Optional[SolverInputs] = None,
           mesh=None) -> Tuple[np.ndarray, np.ndarray]:
@@ -1110,13 +1135,16 @@ def solve(snap: ClusterSnapshot,
     (the RemoteSolver fallback path, which encoded before learning the
     daemon couldn't take the wave).
 
-    ``mesh`` (a parallel.mesh Mesh, kube-scheduler --mesh) routes waves at
-    or above the mesh node floor through solve_sharded's measured
-    kernel-vs-mesh dispatch instead of the router — the in-process twin
-    of kube-solverd's MeshExecutor, minus device residency (workers that
-    want resident planes use the daemon). Decisions are bit-identical
-    either way (parallel/mesh.py contract); the gang post-pass is applied
-    here exactly as on the router path."""
+    ``mesh`` (a parallel.mesh Mesh, kube-scheduler --mesh): a wave at or
+    above the mesh node floor that is outside the Pallas kernel's domain
+    takes the GSPMD scan over the mesh (``_takes_mesh``) — the in-process
+    twin of kube-solverd's MeshExecutor, minus device residency: every
+    plane is placed anew each wave (``solver_mesh_placed_bytes_total``;
+    workers that want resident planes use the daemon). A kernel-eligible
+    wave takes the one-device arm whatever the mesh. Both arms keep the
+    same six parts; decisions are bit-identical either way
+    (parallel/mesh.py contract) and the gang post-pass is applied here
+    exactly as on the router path."""
     # the phases hang on the caller's ambient span (the wave's wave.solve);
     # off the wave loop (solverd) there is none and they only keep time
     part = wave_parts()
@@ -1127,16 +1155,29 @@ def solve(snap: ClusterSnapshot,
     has_gangs = snap.has_gangs
     with tracing.phase("wave.solve.route", part, "solve.route"):
         peer_bound = peer_bound_of(snap)
-        sharded = mesh is not None and \
-            int(host.cap.shape[0]) >= _mesh_min_nodes()
+        sharded = _takes_mesh(host, mesh, snap.policy, has_gangs, peer_bound)
         if not sharded:
             plan = default_router.plan_for(host, snap.policy, has_gangs,
                                            peer_bound)
     if sharded:
-        from kubernetes_tpu.parallel.mesh import solve_sharded
-        chosen, scores = solve_sharded(host, mesh, pol=snap.policy,
-                                       gangs=has_gangs,
-                                       peer_bound=peer_bound)
+        from kubernetes_tpu.parallel import mesh as pmesh
+        with tracing.phase("wave.solve.ship", part, "solve.ship"):
+            resident, wave, nbytes = pmesh.place_on_mesh(host, mesh)
+            mesh_placed_bytes().inc(by=nbytes)
+        with tracing.phase("wave.solve.launch", part, "solve.launch"):
+            wave_programs().inc("scan-sharded",
+                                mesh.devices.flat[0].platform)
+            # donate=False: device_put of an already-placed array
+            # aliases it, and the caller owns ``host``
+            chosen, scores = pmesh.sharded_program(
+                mesh, snap.policy or BatchPolicy(), has_gangs,
+                donate=False)(resident, wave)
+        with tracing.phase("wave.solve.readback", part, "solve.readback"):
+            # replicated outputs: the first copy waits for the program,
+            # the second is there by then
+            chosen, scores = np.asarray(chosen), np.asarray(scores)
+        # padded nodes are infeasible: no index points past the real ones
+        assert chosen.max(initial=-1) < int(host.cap.shape[0])
     else:
         with tracing.phase("wave.solve.ship", part, "solve.ship"):
             inp = ship_inputs(host, plan.device)
@@ -1153,7 +1194,7 @@ def solve(snap: ClusterSnapshot,
         # the wave's device inputs are let go here, inside the last part,
         # not in this frame's teardown after it: freeing ~30 device arrays
         # gives the interpreter away once each
-        inp = None
+        inp = resident = wave = None
         if has_gangs:
             chosen = gang.apply_all_or_nothing(snap.pod_rid, chosen)
             # keep the chosen/score pairing: rolled-back members'
@@ -1174,11 +1215,10 @@ def warm_compile(host: SolverInputs, pol, gangs: bool,
     fence that forces the compile to really happen. Runs on the prewarm
     thread — never on the wave loop."""
     ensure_x64()
-    if mesh is not None and int(host.cap.shape[0]) >= _mesh_min_nodes():
+    if _takes_mesh(host, mesh, pol, gangs, peer_bound):
         from kubernetes_tpu.parallel.mesh import solve_sharded
-        chosen, scores = solve_sharded(host, mesh, pol=pol, gangs=gangs,
-                                       peer_bound=peer_bound)
-        np.asarray(chosen), np.asarray(scores)
+        solve_sharded(host, mesh, pol=pol, gangs=gangs,
+                      peer_bound=peer_bound, prefer_kernel=False)
         return
     plan = default_router.plan_for(host, pol, gangs, peer_bound)
     inp = ship_inputs(host, plan.device)

@@ -31,6 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from kubernetes_tpu.models.batch_solver import SolverInputs, solve_jit
 
 __all__ = ["make_mesh", "maybe_mesh", "pad_inputs_for_mesh", "solve_sharded",
+           "place_on_mesh",
            "shard_memory_report", "sharded_program", "input_shardings",
            "RESIDENT_FIELDS", "WAVE_FIELDS", "DEFAULT_MESH_MIN_NODES"]
 
@@ -309,13 +310,7 @@ def solve_sharded(inp: SolverInputs, mesh: Optional[Mesh] = None,
             return np.asarray(chosen), np.asarray(scores)
 
     mesh = mesh or make_mesh()
-    padded, n = pad_inputs_for_mesh(inp, mesh)
-    shardings = input_shardings(mesh)
-    resident = tuple(jax.device_put(getattr(padded, f),
-                                    getattr(shardings, f))
-                     for f in RESIDENT_FIELDS)
-    wave = tuple(jax.device_put(getattr(padded, f), getattr(shardings, f))
-                 for f in WAVE_FIELDS)
+    resident, wave, _nbytes = place_on_mesh(inp, mesh)
     # donate=False: the caller owns inp, and device_put of an
     # already-placed array aliases it — donation would delete the
     # caller's buffers. The daemon's mesh executor owns its transfers
@@ -325,8 +320,25 @@ def solve_sharded(inp: SolverInputs, mesh: Optional[Mesh] = None,
     chosen = np.asarray(chosen)
     scores = np.asarray(scores)
     # padded nodes are infeasible, so indices never point past n; no remap
-    assert chosen.max(initial=-1) < n
+    assert chosen.max(initial=-1) < int(inp.cap.shape[0])
     return chosen, scores
+
+
+def place_on_mesh(inp: SolverInputs, mesh: Mesh) -> Tuple[tuple, tuple, int]:
+    """One wave's planes onto the mesh, in ``sharded_program``'s argument
+    order: pad the node axis to the mesh, ``device_put`` every plane under
+    its sharding. -> (resident tuple, wave tuple, bytes placed — the
+    padded planes' sizes, a replicated plane counted once). Every plane is
+    placed anew on every call: nothing here is resident across waves (the
+    daemon's MeshExecutor keeps its own)."""
+    padded, _n = pad_inputs_for_mesh(inp, mesh)
+    shardings = input_shardings(mesh)
+    resident = tuple(jax.device_put(getattr(padded, f),
+                                    getattr(shardings, f))
+                     for f in RESIDENT_FIELDS)
+    wave = tuple(jax.device_put(getattr(padded, f), getattr(shardings, f))
+                 for f in WAVE_FIELDS)
+    return resident, wave, sum(int(a.nbytes) for a in padded)
 
 
 @functools.lru_cache(maxsize=64)
@@ -348,6 +360,8 @@ def sharded_program(mesh: Mesh, pol, gangs: bool, donate: bool = True):
     wave_sh = tuple(getattr(shardings, f) for f in WAVE_FIELDS)
     rep = NamedSharding(mesh, P())
 
+    # the function's name is the program's in a device trace (``jit_run``):
+    # benchmarks/metrics/sharded_scan_ms.json finds it by that
     def run(resident, wave):
         kw = dict(zip(RESIDENT_FIELDS, resident))
         kw.update(zip(WAVE_FIELDS, wave))
