@@ -172,14 +172,15 @@ class SolverInputs(NamedTuple):
 
 
 def _pack_bits(a: np.ndarray) -> np.ndarray:
-    """[R, K] bool -> [R, W] uint32 bitmask words (little-endian bits)."""
+    """[R, K] bool -> [R, W] uint32 bitmask words (little-endian bits:
+    bit j of word w is column 32 w + j). One ``packbits`` into bytes, the
+    bytes of a word in memory order: at a wave's sizes a numpy call costs
+    the wave loop a hand-off of the interpreter, not arithmetic."""
     rows, K = a.shape
     W = max(1, (K + 31) // 32)
-    padded = np.zeros((rows, W * 32), dtype=bool)
-    padded[:, :K] = a
-    weights = (np.uint64(1) << np.arange(32, dtype=np.uint64))
-    words = (padded.reshape(rows, W, 32) * weights).sum(axis=2)
-    return words.astype(np.uint32)
+    words = np.zeros((rows, W * 4), np.uint8)
+    words[:, :(K + 7) // 8] = np.packbits(a, axis=1, bitorder="little")
+    return words.view("<u4")
 
 
 def _resource_scales(snap: ClusterSnapshot) -> np.ndarray:
@@ -221,21 +222,30 @@ def snapshot_to_inputs(snap: ClusterSnapshot,
 
 def snapshot_to_host_inputs(snap: ClusterSnapshot) -> SolverInputs:
     """The host-side (numpy) half of snapshot_to_inputs: scaling, dtype
-    narrowing, bit-packing — everything up to the device transfer."""
+    narrowing, bit-packing — everything up to the device transfer. Every
+    plane anew from the snapshot: the cold path. The wave loop goes through
+    models/resident.ResidentPlanes.host_inputs, which keeps the node planes
+    between waves and falls back to this whenever it cannot patch them."""
+    return host_inputs_scaled(snap)[0]
+
+
+def host_inputs_scaled(snap: ClusterSnapshot
+                       ) -> Tuple[SolverInputs, np.ndarray]:
+    """-> (snapshot_to_host_inputs(snap), the [R] resource scales it
+    divided by)."""
     ensure_x64()
-    g = _resource_scales(snap)[None, :]                    # [1, R]
+    scales = _resource_scales(snap)
+    g = scales[None, :]                                    # [1, R]
     cap = snap.cap // g
     fit_used = snap.fit_used // g
     score_used = snap.score_used // g
     req = snap.req // g
-    N0 = snap.n_nodes
+    N = snap.n_nodes
     R0 = snap.cap.shape[1]
     evict_cap = (snap.evict_cap if snap.evict_cap is not None
-                 else np.zeros((N0, 0, R0), np.int64)) // g[None, :, :]
+                 else np.zeros((N, 0, R0), np.int64)) // g[None, :, :]
     evict_cnt = (snap.evict_cnt if snap.evict_cnt is not None
-                 else np.zeros((N0, 0), np.int32))
-    band_prio = (snap.band_prio if snap.band_prio is not None
-                 else np.zeros(0, np.int32))
+                 else np.zeros((N, 0), np.int32))
 
     # int32 is safe when no running sum can reach 2^31/10: the largest
     # initial value plus the whole batch's requests bounds every accumulator
@@ -244,36 +254,13 @@ def snapshot_to_host_inputs(snap: ClusterSnapshot) -> SolverInputs:
                         cap + req_total, evict_cap)
     rdt = np.int32 if use_i32 else np.int64
 
-    N = snap.n_nodes
-    P = snap.req.shape[0]  # includes pod-axis padding (n_pods is the real count)
     G = snap.group_counts.shape[0]
     score_static = (snap.score_static if snap.score_static is not None
                     else np.zeros(N, np.int32))
     node_aff_vals = (snap.node_aff_vals if snap.node_aff_vals is not None
                      else np.zeros((N, 0), np.int32))
-    pod_aff_static = (snap.pod_aff_static if snap.pod_aff_static is not None
-                      else np.zeros((P, 0), np.int32))
-    anchor_vals0 = (snap.anchor_vals0 if snap.anchor_vals0 is not None
-                    else np.zeros((G, 0), np.int32))
-    has_anchor0 = (snap.has_anchor0 if snap.has_anchor0 is not None
-                   else np.zeros(G, bool))
     node_zone = (snap.node_zone if snap.node_zone is not None
                  else np.zeros((0, N), np.int32))
-    A = node_zone.shape[0]
-    V = max(1, int(node_zone.max(initial=-1)) + 1)
-    zone_counts0 = snap.zone_counts0
-    if zone_counts0 is None:
-        # per-group per-zone initial peer totals over labeled nodes —
-        # derived here for the full encoder; the incremental encoder keeps
-        # these resident and hands them down (O(changed) maintenance)
-        zone_counts0 = derive_zone_counts(node_zone, snap.group_counts, V)
-    elif _DEBUG_VERIFY_ZONES:
-        want = derive_zone_counts(node_zone, snap.group_counts, V)
-        assert zone_counts0.shape == want.shape and \
-            np.array_equal(zone_counts0, want), (
-                "resident zone_counts0 diverged from the group_counts/"
-                "node_zone planes — the incremental encoder's O(changed) "
-                "zone maintenance is out of sync")
 
     host = SolverInputs(
         cap=cap.astype(rdt),
@@ -285,7 +272,54 @@ def snapshot_to_host_inputs(snap: ClusterSnapshot) -> SolverInputs:
         node_sel=np.ascontiguousarray(snap.node_sel),
         node_pds=_pack_bits(snap.node_pds),
         node_extra_ok=np.asarray(snap.node_extra_ok, bool),
-        req=req.astype(rdt),
+        group_counts=np.ascontiguousarray(snap.group_counts),
+        score_static=score_static.astype(np.int32),
+        node_aff_vals=node_aff_vals.astype(np.int32),
+        zone_idx=node_zone.astype(np.int32),
+        evict_cap=np.ascontiguousarray(evict_cap.astype(rdt)),
+        evict_cnt=np.ascontiguousarray(evict_cnt, np.int32),
+        **host_small_planes(snap, node_zone),
+        **host_wave_planes(snap, req.astype(rdt), G),
+    )
+    return host, scales
+
+
+def host_small_planes(snap: ClusterSnapshot, node_zone: np.ndarray) -> dict:
+    """The two resident planes with no node axis (``zone_counts0``,
+    ``band_prio``): small, and built whole every wave on either path."""
+    zone_counts0 = snap.zone_counts0
+    if zone_counts0 is None or _DEBUG_VERIFY_ZONES:
+        V = max(1, int(node_zone.max(initial=-1)) + 1)
+        want = derive_zone_counts(node_zone, snap.group_counts, V)
+    if zone_counts0 is None:
+        # per-group per-zone initial peer totals over labeled nodes —
+        # derived here for the full encoder; the incremental encoder keeps
+        # these resident and hands them down (O(changed) maintenance)
+        zone_counts0 = want
+    elif _DEBUG_VERIFY_ZONES:
+        assert zone_counts0.shape == want.shape and \
+            np.array_equal(zone_counts0, want), (
+                "resident zone_counts0 diverged from the group_counts/"
+                "node_zone planes — the incremental encoder's O(changed) "
+                "zone maintenance is out of sync")
+    band_prio = (snap.band_prio if snap.band_prio is not None
+                 else np.zeros(0, np.int32))
+    return {"zone_counts0": np.ascontiguousarray(zone_counts0, np.int32),
+            "band_prio": np.ascontiguousarray(band_prio, np.int32)}
+
+
+def host_wave_planes(snap: ClusterSnapshot, req: np.ndarray, G: int) -> dict:
+    """The pod-axis planes (parallel/mesh.WAVE_FIELDS), new every wave;
+    ``req`` comes scaled and narrowed from the caller."""
+    P = snap.req.shape[0]  # includes pod-axis padding (n_pods is the real count)
+    pod_aff_static = (snap.pod_aff_static if snap.pod_aff_static is not None
+                      else np.zeros((P, 0), np.int32))
+    anchor_vals0 = (snap.anchor_vals0 if snap.anchor_vals0 is not None
+                    else np.zeros((G, 0), np.int32))
+    has_anchor0 = (snap.has_anchor0 if snap.has_anchor0 is not None
+                   else np.zeros(G, bool))
+    return dict(
+        req=req,
         pod_ports=_pack_bits(snap.pod_ports),
         pod_sel=np.ascontiguousarray(snap.pod_sel),
         pod_pds=_pack_bits(snap.pod_pds),
@@ -294,32 +328,25 @@ def snapshot_to_host_inputs(snap: ClusterSnapshot) -> SolverInputs:
         tie_lo=np.ascontiguousarray(snap.tie_lo),
         pod_gid=np.ascontiguousarray(snap.pod_gid),
         pod_group_member=np.ascontiguousarray(snap.pod_group_member),
-        group_counts=np.ascontiguousarray(snap.group_counts),
         gang_start=np.asarray(snap.pod_run_start
                               if snap.pod_run_start is not None
                               else np.ones(P, bool), bool),
-        score_static=score_static.astype(np.int32),
-        node_aff_vals=node_aff_vals.astype(np.int32),
         pod_aff_static=pod_aff_static.astype(np.int32),
         anchor_vals0=anchor_vals0.astype(np.int32),
         has_anchor0=np.asarray(has_anchor0, bool),
-        zone_idx=node_zone.astype(np.int32),
-        zone_counts0=np.ascontiguousarray(zone_counts0, np.int32),
         pod_prio=np.ascontiguousarray(
             snap.pod_prio if snap.pod_prio is not None
             else np.zeros(P, np.int32), np.int32),
         pod_can_preempt=np.asarray(
             snap.pod_can_preempt if snap.pod_can_preempt is not None
             else np.ones(P, bool), bool),
-        band_prio=np.ascontiguousarray(band_prio, np.int32),
-        evict_cap=np.ascontiguousarray(evict_cap.astype(rdt)),
-        evict_cnt=np.ascontiguousarray(evict_cnt, np.int32),
     )
-    return host
 
 
 def ship_inputs(host: SolverInputs, device=None) -> SolverInputs:
-    """Place host (numpy) SolverInputs onto a device. ``device=None``:
+    """Place host (numpy) SolverInputs onto a device, every plane anew: the
+    cold path (the wave loop ships through models/resident.py, which keeps
+    the node planes on the device and sends what changed). ``device=None``:
     the default device, via the packed single-shipment transfer when
     enabled. An explicit device (the router's host-CPU route) uses plain
     device_put — packing exists to amortize a fixed per-transfer cost,
@@ -334,12 +361,17 @@ def ship_inputs(host: SolverInputs, device=None) -> SolverInputs:
 # -- packed transfer ---------------------------------------------------------
 # Where every host->device transfer pays a fixed cost, shipping
 # SolverInputs' ~32 arrays separately makes small waves transfer-latency-
-# bound. Instead the whole tree is packed into ONE uint8 buffer host-side
+# bound. Instead the arrays are packed into ONE uint8 buffer host-side
 # (memcpy-speed), shipped as a single transfer, and re-materialized on
-# device by a jitted unpack program (static offsets per shape bucket; XLA
-# bitcasts — backend-independent semantics). The unpack program is a
-# compile of its own per shape bucket, and on a v5e a slow one (PERF.md).
-# KTPU_PACK_TRANSFER: auto (default: on for non-CPU backends) | on | off.
+# device by a jitted program (static offsets per shape bucket; XLA
+# bitcasts — backend-independent semantics). Two programs unpack such a
+# buffer: models/resident.py's apply program, which the wave loop runs (the
+# pod planes and the changed rows of the node planes, patched into the
+# planes the device kept), and ``_unpack_device`` below for the callers
+# that keep nothing (the whole tree: a compile of its own per shape bucket,
+# and on a v5e a slow one, PERF.md).
+# KTPU_PACK_TRANSFER, for ``ship_inputs`` alone: auto (default: on for
+# non-CPU backends) | on | off.
 
 _PACK_ALIGN = 8
 
@@ -356,27 +388,38 @@ def _pack_transfer_enabled() -> bool:
     return jax.default_backend() != "cpu"
 
 
-def _pack_spec(host: "SolverInputs"):
+def _pack_spec(arrays):
     """-> (hashable spec, total bytes). Offsets are _PACK_ALIGN-aligned."""
     spec = []
     off = 0
-    for a in host:
+    for a in arrays:
         off = (off + _PACK_ALIGN - 1) // _PACK_ALIGN * _PACK_ALIGN
         spec.append((str(a.dtype), tuple(a.shape), off, int(a.nbytes)))
         off += a.nbytes
     return tuple(spec), off
 
 
+def pack_arrays(arrays) -> Tuple[np.ndarray, tuple]:
+    """-> (one uint8 buffer holding every array, the spec that unpacks it).
+    One concatenate of byte views and alignment gaps, not a copy an array:
+    each numpy call of this size hands the interpreter away."""
+    spec, total = _pack_spec(arrays)
+    pieces, end = [], 0
+    for a, (_, _, off, nb) in zip(arrays, spec):
+        if off > end:
+            pieces.append(np.zeros(off - end, np.uint8))
+        pieces.append(np.ascontiguousarray(a).reshape(-1).view(np.uint8))
+        end = off + nb
+    return np.concatenate(pieces), spec
+
+
 def pack_and_ship(host: "SolverInputs") -> "SolverInputs":
-    spec, total = _pack_spec(host)
-    buf = np.zeros(total, np.uint8)
-    for a, (_, _, off, nb) in zip(host, spec):
-        buf[off:off + nb] = np.ascontiguousarray(a).view(np.uint8).reshape(-1)
+    buf, spec = pack_arrays(host)
     return SolverInputs(*_unpack_device(jnp.asarray(buf), spec))
 
 
-@functools.partial(jax.jit, static_argnames=("spec",))
-def _unpack_device(buf: jnp.ndarray, spec) -> tuple:
+def unpack_arrays(buf: jnp.ndarray, spec) -> tuple:
+    """Traced: the arrays of ``pack_arrays`` out of the device buffer."""
     out = []
     for dtype_str, shape, off, nb in spec:
         seg = jax.lax.slice(buf, (off,), (off + nb,))
@@ -391,6 +434,11 @@ def _unpack_device(buf: jnp.ndarray, spec) -> tuple:
             ).reshape(shape)
         out.append(arr)
     return tuple(out)
+
+
+@functools.partial(jax.jit, static_argnames=("spec",))
+def _unpack_device(buf: jnp.ndarray, spec) -> tuple:
+    return unpack_arrays(buf, spec)
 
 
 @functools.partial(jax.jit,
@@ -864,13 +912,14 @@ def wave_programs() -> metrics.Counter:
 
 
 def mesh_placed_bytes() -> metrics.Counter:
-    """Bytes ``solve``'s mesh arm placed onto the mesh (``device_put`` of
-    the padded resident and wave planes, a replicated plane counted once):
-    all of them on every wave, nothing being resident in-process."""
+    """Bytes that crossed from the host to the device(s) of ``solve``, on
+    either arm: a wave's packed buffer (the pod planes and the dirty rows)
+    where the node planes are resident, every plane (padded to the mesh, a
+    replicated plane counted once) where they are placed whole."""
     return metrics.default_registry().counter(
         "solver_mesh_placed_bytes_total",
-        "Bytes of solver planes placed onto the device mesh by in-process "
-        "sharded waves")
+        "Bytes of solver planes that crossed from the host to the device(s) "
+        "of in-process waves")
 
 
 _WAVE_PART_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
@@ -1125,53 +1174,63 @@ def _takes_mesh(host: SolverInputs, mesh, pol, gangs: bool,
 
 def solve(snap: ClusterSnapshot,
           host: Optional[SolverInputs] = None,
-          mesh=None) -> Tuple[np.ndarray, np.ndarray]:
+          mesh=None, resident=None) -> Tuple[np.ndarray, np.ndarray]:
     """Host entry: encode -> device -> solve -> host decisions (including
     the all-or-nothing gang post-pass when the wave has PodGroups).
     Waves route through the measured host-vs-device dispatch (WaveRouter):
     a small wave may be dispatch-bound on the device and run faster on
     the host CPU backend. ``host`` short-circuits the host-side
-    encode when the caller already holds snapshot_to_host_inputs(snap)
-    (the RemoteSolver fallback path, which encoded before learning the
-    daemon couldn't take the wave).
+    encode when the caller already holds the wave's host inputs (the wave
+    loop, which reads the wave's bucket from them for the prewarm).
+
+    ``resident`` (a models/resident.ResidentPlanes, one a scheduler): the
+    node planes outlive the wave, on the host and on the device(s), and a
+    wave patches them with the rows the encoder touched and ships only the
+    pod planes and those rows; it rebuilds and places whole whenever the
+    snapshot does not allow that (counted, with the reason, in
+    ``solver_resident_waves_total``). Without it — RemoteSolver's
+    fallback, chip_smoke.py, tests — every plane is built and placed anew:
+    the cold path, which is also what a rebuild runs.
 
     ``mesh`` (a parallel.mesh Mesh, kube-scheduler --mesh): a wave at or
     above the mesh node floor that is outside the Pallas kernel's domain
     takes the GSPMD scan over the mesh (``_takes_mesh``) — the in-process
-    twin of kube-solverd's MeshExecutor, minus device residency: every
-    plane is placed anew each wave (``solver_mesh_placed_bytes_total``;
-    workers that want resident planes use the daemon). A kernel-eligible
-    wave takes the one-device arm whatever the mesh. Both arms keep the
-    same six parts; decisions are bit-identical either way
-    (parallel/mesh.py contract) and the gang post-pass is applied here
-    exactly as on the router path."""
+    twin of kube-solverd's MeshExecutor, resident planes included: they
+    stay sharded under ``input_shardings`` and are patched in place. A
+    kernel-eligible wave takes the one-device arm whatever the mesh. Both
+    arms keep the same six parts and count the bytes that crossed to the
+    device(s) (``solver_mesh_placed_bytes_total``); decisions are
+    bit-identical either way (parallel/mesh.py contract) and the gang
+    post-pass is applied here exactly as on the router path."""
     # the phases hang on the caller's ambient span (the wave's wave.solve);
     # off the wave loop (solverd) there is none and they only keep time
     part = wave_parts()
-    inp = None
     if host is None:
         with tracing.phase("wave.solve.hostprep", part, "solve.hostprep"):
-            host = snapshot_to_host_inputs(snap)
+            host = (resident.host_inputs(snap) if resident is not None
+                    else snapshot_to_host_inputs(snap))
     has_gangs = snap.has_gangs
     with tracing.phase("wave.solve.route", part, "solve.route"):
         peer_bound = peer_bound_of(snap)
         sharded = _takes_mesh(host, mesh, snap.policy, has_gangs, peer_bound)
+        device = None
         if not sharded:
-            plan = default_router.plan_for(host, snap.policy, has_gangs,
-                                           peer_bound)
+            device = default_router.plan_for(host, snap.policy, has_gangs,
+                                             peer_bound).device
+    with tracing.phase("wave.solve.ship", part, "solve.ship"):
+        inp, nbytes = _ship_wave(host, mesh if sharded else None, device,
+                                 resident)
+        mesh_placed_bytes().inc(by=nbytes)
     if sharded:
         from kubernetes_tpu.parallel import mesh as pmesh
-        with tracing.phase("wave.solve.ship", part, "solve.ship"):
-            resident, wave, nbytes = pmesh.place_on_mesh(host, mesh)
-            mesh_placed_bytes().inc(by=nbytes)
         with tracing.phase("wave.solve.launch", part, "solve.launch"):
             wave_programs().inc("scan-sharded",
                                 mesh.devices.flat[0].platform)
-            # donate=False: device_put of an already-placed array
-            # aliases it, and the caller owns ``host``
+            # donate=False: the resident planes outlive the wave, and the
+            # pod planes may alias host memory (parallel/mesh.py)
             chosen, scores = pmesh.sharded_program(
                 mesh, snap.policy or BatchPolicy(), has_gangs,
-                donate=False)(resident, wave)
+                donate=False)(*pmesh.split_inputs(inp))
         with tracing.phase("wave.solve.readback", part, "solve.readback"):
             # replicated outputs: the first copy waits for the program,
             # the second is there by then
@@ -1179,22 +1238,20 @@ def solve(snap: ClusterSnapshot,
         # padded nodes are infeasible: no index points past the real ones
         assert chosen.max(initial=-1) < int(host.cap.shape[0])
     else:
-        with tracing.phase("wave.solve.ship", part, "solve.ship"):
-            inp = ship_inputs(host, plan.device)
         with tracing.phase("wave.solve.launch", part, "solve.launch"):
             chosen, scores = solve_device(
                 inp, snap.policy, has_gangs, peer_bound,
-                force_scan=plan.device is not None)
+                force_scan=device is not None)
         with tracing.phase("wave.solve.readback", part, "solve.readback"):
             # ONE device->host readback, not two: at churn rates a second
             # sync per wave starves the feeder and watch pumps
             both = np.asarray(jnp.stack([chosen, scores]))
         chosen, scores = both[0], both[1]
     with tracing.phase("wave.solve.post", part, "solve.post"):
-        # the wave's device inputs are let go here, inside the last part,
-        # not in this frame's teardown after it: freeing ~30 device arrays
-        # gives the interpreter away once each
-        inp = resident = wave = None
+        # the wave's own device arrays (the pod planes) are let go here,
+        # inside the last part, not in this frame's teardown after it:
+        # freeing a device array gives the interpreter away once
+        inp = None
         if has_gangs:
             chosen = gang.apply_all_or_nothing(snap.pod_rid, chosen)
             # keep the chosen/score pairing: rolled-back members'
@@ -1203,25 +1260,52 @@ def solve(snap: ClusterSnapshot,
     return chosen, scores
 
 
+def _ship_wave(host: SolverInputs, mesh, device, resident
+               ) -> Tuple[SolverInputs, int]:
+    """One wave's planes onto where it solves -> (device SolverInputs,
+    bytes that crossed). ``mesh``: the sharded arm (the planes padded to
+    it); ``device``: the router's host route; neither: the default device.
+    ``resident`` ships what changed, where it holds ``host``'s node planes
+    and the wave goes to the device(s); everything else is the cold path."""
+    if resident is not None:
+        if device is not None:
+            resident.bypass(host)
+        else:
+            shipped = resident.ship(host, mesh)
+            if shipped is not None:
+                return shipped
+    if mesh is not None:
+        from kubernetes_tpu.parallel import mesh as pmesh
+        return pmesh.place_on_mesh(host, mesh)
+    return ship_inputs(host, device), sum(int(a.nbytes) for a in host)
+
+
 def warm_compile(host: SolverInputs, pol, gangs: bool,
                  peer_bound: int = 0, mesh=None) -> None:
     """kube-slipstream prewarm entry: run (and discard) one wave of this
     exact shape through the same dispatch ``solve`` uses, so the compiled
-    executable — router calibration included, since calibration IS the
-    first compile of both paths — is resident in the jit cache (and the
-    util/warmstart.py persistent cache) before a live wave needs it.
-    The results are read back to host because a dispatch whose outputs
-    are never consumed may be elided wholesale; the readback is the
-    fence that forces the compile to really happen. Runs on the prewarm
-    thread — never on the wave loop."""
+    executables — router calibration included, since calibration IS the
+    first compile of both paths, and the resident planes' apply program at
+    every bucket of its row ladder — are resident in the jit cache (and
+    the util/warmstart.py persistent cache) before a live wave needs them.
+    ``host`` is the caller's own exemplar and is placed on planes of its
+    own (ResidentPlanes.warm): a scheduler's live planes are never seen,
+    let alone donated, from here. The results are read back to host
+    because a dispatch whose outputs are never consumed may be elided
+    wholesale; the readback is the fence that forces the compile to really
+    happen. Runs on the prewarm thread — never on the wave loop."""
+    from kubernetes_tpu.models.resident import ResidentPlanes
     ensure_x64()
     if _takes_mesh(host, mesh, pol, gangs, peer_bound):
-        from kubernetes_tpu.parallel.mesh import solve_sharded
-        solve_sharded(host, mesh, pol=pol, gangs=gangs,
-                      peer_bound=peer_bound, prefer_kernel=False)
+        from kubernetes_tpu.parallel import mesh as pmesh
+        chosen, scores = pmesh.sharded_program(
+            mesh, pol or BatchPolicy(), gangs, donate=False)(
+                *pmesh.split_inputs(ResidentPlanes.warm(host, mesh)))
+        np.asarray(chosen), np.asarray(scores)
         return
     plan = default_router.plan_for(host, pol, gangs, peer_bound)
-    inp = ship_inputs(host, plan.device)
+    inp = (ResidentPlanes.warm(host) if plan.device is None
+           else ship_inputs(host, plan.device))
     chosen, scores = solve_device(inp, pol, gangs, peer_bound,
                                   force_scan=plan.device is not None)
     np.asarray(jnp.stack([chosen, scores]))

@@ -44,6 +44,8 @@ tests/test_incremental.py.
 
 from __future__ import annotations
 
+import functools
+import itertools
 import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -68,6 +70,14 @@ __all__ = ["IncrementalEncoder"]
 # incrementally-maintained ones (models/preempt.derive_evict_planes is
 # the authoritative from-scratch twin)
 _DEBUG_VERIFY_EVICT = os.environ.get("KTPU_DEBUG", "") not in ("", "0")
+
+# Residency epochs (models/resident.py): one counter for the process, so no
+# two encoders, and no encoder before and after a restore(), ever hand out
+# the same epoch.
+_EPOCHS = itertools.count(1)
+# the touched-row log keeps at most this many entries; a consumer that asks
+# for rows it no longer holds is told so (None) and re-places its planes
+_TOUCH_LOG_MAX = 1 << 16
 
 
 class _PodRec:
@@ -144,6 +154,40 @@ class IncrementalEncoder:
         self.op_counts: Dict[str, int] = {
             "zone_writes": 0, "group_writes": 0, "node_rebuilds": 0,
             "evict_writes": 0}
+        # residency (models/resident.py): the node rows _add_pod and
+        # _remove_pod wrote, in order, under a sequence number; the epoch
+        # changes whenever the rows alone no longer say what changed
+        self._touch_log: List[int] = []
+        self._touch_base = 0
+        self._new_epoch("first")
+
+    # -- residency: what changed since a snapshot -----------------------------
+    def _new_epoch(self, why: str) -> None:
+        """A consumer that keeps the node planes of an earlier snapshot may
+        patch them with the touched rows only inside one epoch; everything
+        else a wave can do to them ends it (``why`` says what)."""
+        self._epoch = next(_EPOCHS)
+        self._epoch_why = why
+        self._touch_base += len(self._touch_log)
+        self._touch_log.clear()
+
+    def _touch(self, i: int) -> None:
+        log = self._touch_log
+        log.append(i)
+        if len(log) > _TOUCH_LOG_MAX:
+            half = len(log) // 2
+            del log[:half]
+            self._touch_base += half
+
+    def _touched(self, since: int, *, epoch: int, upto: int
+                 ) -> Optional[List[int]]:
+        """Node rows written in [since, upto) of ``epoch``, duplicates and
+        all; None when the log cannot say (another epoch by now, rows
+        trimmed away, or a snapshot older than the one last applied)."""
+        lo, hi = since - self._touch_base, upto - self._touch_base
+        if epoch != self._epoch or lo < 0 or hi < lo:
+            return None
+        return self._touch_log[lo:hi]
 
     # -- node side ----------------------------------------------------------
     @staticmethod
@@ -171,7 +215,8 @@ class IncrementalEncoder:
 
     def _rebuild_nodes(self, nodes: Sequence[api.Node],
                        existing: Sequence[api.Pod],
-                       services: Sequence[api.Service]) -> None:
+                       services: Sequence[api.Service],
+                       why: str = "nodes") -> None:
         """Node set/order/labels/capacity changed: rebuild every resident
         plane (node order defines the tie-break axis, so there is no safe
         partial update on reorder). Sticky vocabularies survive."""
@@ -255,6 +300,7 @@ class IncrementalEncoder:
         self._set_services(services)
         for p in existing:
             self._add_pod(p)
+        self._new_epoch(why)
 
     # -- services -----------------------------------------------------------
     @staticmethod
@@ -309,6 +355,7 @@ class IncrementalEncoder:
         the full encoder's member_exist matrix does (an existing peer is a
         peer of any service that selects it, not just its own first)."""
         row = self._grp_rows[key] = len(self._grp_rows)
+        self._new_epoch("column")   # backfilled from every cached pod
         if row >= self._grp_cnt.shape[0]:
             grown = np.zeros((_pow2_pad(row + 1), self._N + 1), np.int32)
             grown[:self._grp_cnt.shape[0]] = self._grp_cnt
@@ -352,6 +399,7 @@ class IncrementalEncoder:
         r = self._rix.get(name)
         if r is None:
             r = self._rix[name] = len(self._resource_names)
+            self._new_epoch("column")
             self._resource_names.append(name)
             self._cap = np.pad(self._cap, ((0, 0), (0, 1)))
             self._advertised = np.pad(self._advertised, ((0, 0), (0, 1)))
@@ -368,6 +416,7 @@ class IncrementalEncoder:
             self._band_min = prio
         cap = self._bands.cap
         if self._evict_cnt.shape[1] < cap:
+            self._new_epoch("column")
             self._evict_cap = np.pad(
                 self._evict_cap,
                 ((0, 0), (0, cap - self._evict_cap.shape[1]), (0, 0)))
@@ -376,11 +425,15 @@ class IncrementalEncoder:
 
     def _port_col(self, port: int) -> int:
         col = self._ports.intern(port)
+        if self._port_cnt.shape[1] < self._ports.cap:
+            self._new_epoch("column")
         self._port_cnt = self._grow_cols(self._port_cnt, self._ports.cap)
         return col
 
     def _pd_col(self, pd: str) -> int:
         col = self._pds.intern(pd)
+        if self._pd_cnt.shape[1] < self._pds.cap:
+            self._new_epoch("column")
         self._pd_cnt = self._grow_cols(self._pd_cnt, self._pds.cap)
         return col
 
@@ -390,6 +443,7 @@ class IncrementalEncoder:
         self._node_sel = self._grow_cols(self._node_sel, self._sels.cap,
                                          fill=False)
         if not known:  # backfill the new column from resident node labels
+            self._new_epoch("column")
             k, v = kv
             for i, lbls in enumerate(self._node_labels):
                 if lbls.get(k) == v:
@@ -423,6 +477,7 @@ class IncrementalEncoder:
                       ns=pod.metadata.namespace)
         self._pods[uid] = rec
         if i < self._N:
+            self._touch(i)
             for r, amt in req:
                 self._score_used[i, r] += amt
             for col in ports:
@@ -447,6 +502,7 @@ class IncrementalEncoder:
         rec = self._pods.pop(uid)
         i = rec.host_idx
         if i < self._N:
+            self._touch(i)
             for r, amt in rec.req:
                 self._score_used[i, r] -= amt
             for col in rec.ports:
@@ -543,6 +599,7 @@ class IncrementalEncoder:
         self._pods = dict(ckpt["_pods"])
         self._node_pods = {i: dict(d)
                            for i, d in ckpt["_node_pods"].items()}
+        self._new_epoch("restore")
 
     def resident_fingerprint(self) -> tuple:
         """Order-stable digest of every resident plane + the pod registry.
@@ -620,8 +677,11 @@ class IncrementalEncoder:
                services: Sequence[api.Service] = (),
                pad_pods: bool = True) -> ClusterSnapshot:
         services = list(services)
-        if self._nodes_changed(nodes) or self._services_changed(services):
+        if self._nodes_changed(nodes):
             self._rebuild_nodes(nodes, existing_pods, services)
+        elif self._services_changed(services):
+            self._rebuild_nodes(nodes, existing_pods, services,
+                                why="services")
         else:
             cur = {}
             for p in existing_pods:
@@ -638,6 +698,9 @@ class IncrementalEncoder:
                                                           self._N):
                     self._remove_pod(u)   # host changed: re-account
                     self._add_pod(p)
+            # the greedy fit accumulators follow the order of this list
+            # where a node overflows: the touched rows do not say that
+            self._new_epoch("full_encode")
         return self._build(existing_pods, pending_pods, pad_pods)
 
     def encode_delta(self, nodes: Sequence[api.Node],
@@ -825,6 +888,7 @@ class IncrementalEncoder:
         if not self._preempt_emitted and len(self._bands) and P \
                 and int(pod_prio[:P].max()) > self._band_min:
             self._preempt_emitted = True
+            self._new_epoch("preempt_gate")
         if self._preempt_emitted:
             from kubernetes_tpu.models import preempt as _preempt
             Bc = self._bands.cap
@@ -858,6 +922,7 @@ class IncrementalEncoder:
             evict_cap = np.zeros((N, 0, R), np.int64)
             evict_cnt = np.zeros((N, 0), np.int32)
 
+        seq = self._touch_base + len(self._touch_log)
         return ClusterSnapshot(
             node_names=self._node_names,
             resource_names=list(self._resource_names),
@@ -884,4 +949,8 @@ class IncrementalEncoder:
             w_least_requested=self.policy.w_lr,
             w_spreading=self.policy.w_spread,
             w_equal=self.policy.w_equal,
+            resident_epoch=self._epoch, resident_why=self._epoch_why,
+            resident_seq=seq,
+            touched_since=functools.partial(self._touched,
+                                            epoch=self._epoch, upto=seq),
         )

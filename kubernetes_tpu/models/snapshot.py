@@ -41,7 +41,7 @@ from __future__ import annotations
 
 import os
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -196,6 +196,16 @@ class ClusterSnapshot:
     w_least_requested: int = 1
     w_spreading: int = 1
     w_equal: int = 0
+    # residency (models/resident.py), set by the incremental encoder only:
+    # the epoch inside which the node planes of two snapshots differ by the
+    # rows the encoder touched and nothing else (None: never patch), what
+    # began it, this snapshot's place in the touched-row log, and
+    # ``touched_since(seq)`` -> the rows written in [seq, resident_seq), or
+    # None where the log cannot say
+    resident_epoch: Optional[int] = None
+    resident_why: str = ""
+    resident_seq: int = 0
+    touched_since: Optional[Callable] = None
 
     @property
     def n_nodes(self) -> int:

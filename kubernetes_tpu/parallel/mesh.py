@@ -19,8 +19,10 @@ remain bit-identical to the unsharded / serial paths.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
+import warnings
 from typing import Optional, Tuple
 
 import jax
@@ -31,9 +33,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from kubernetes_tpu.models.batch_solver import SolverInputs, solve_jit
 
 __all__ = ["make_mesh", "maybe_mesh", "pad_inputs_for_mesh", "solve_sharded",
-           "place_on_mesh",
+           "place_on_mesh", "split_inputs",
            "shard_memory_report", "sharded_program", "input_shardings",
-           "RESIDENT_FIELDS", "WAVE_FIELDS", "DEFAULT_MESH_MIN_NODES"]
+           "RESIDENT_FIELDS", "WAVE_FIELDS", "DEFAULT_MESH_MIN_NODES",
+           "scatter_rows", "pad_rows_to", "may_alias_host",
+           "donation_warnings_scoped"]
 
 _DEBUG = os.environ.get("KTPU_DEBUG", "") not in ("", "0")
 
@@ -56,6 +60,71 @@ RESIDENT_FIELDS = (
 )
 WAVE_FIELDS = tuple(f for f in SolverInputs._fields
                     if f not in RESIDENT_FIELDS)
+
+
+# -- patching a resident plane on the device ---------------------------------
+# Shared by kube-solverd's MeshExecutor (solver/mesh_exec.py: one scatter a
+# changed plane) and the in-process resident planes (models/resident.py: one
+# apply program a wave). THE DONATION RULE: only a buffer that an XLA
+# program produced may be donated. One that ``jax.device_put`` made from a
+# host numpy array may ALIAS that array's memory on the CPU backend
+# (zero-copy when alignment allows); donating it frees memory numpy still
+# owns and corrupts the native heap (observed live as ``malloc(): unsorted
+# double linked list corrupted`` killing the daemon mid-churn). So the first
+# patch after a fresh placement does not donate; every later one does.
+
+def scatter_rows(base, rows, vals, axis: int = 0):
+    """``base`` with ``vals`` written at ``rows`` along ``axis`` (0, or 1
+    for a plane whose node axis is its second: ``vals`` is then [.., k])."""
+    if axis == 0:
+        return base.at[rows].set(vals)
+    return base.at[:, rows].set(vals)
+
+
+def may_alias_host(platform: str) -> bool:
+    """Whether a ``device_put`` onto ``platform`` can share the host
+    array's memory (the donation rule above): the CPU backend alone."""
+    return platform == "cpu"
+
+
+def pad_rows_to(rows: np.ndarray, vals: np.ndarray, want: int):
+    """A delta of k changed rows brought to ``want`` >= k by repeating the
+    last (row, value) pair — idempotent under scatter-set (same index,
+    same value) — so that the programs that apply it compile once a
+    bucket and not once a row count."""
+    extra = want - len(rows)
+    if extra <= 0 or len(rows) == 0:
+        return rows, vals
+    rows = np.concatenate([rows, np.repeat(rows[-1:], extra, axis=0)])
+    vals = np.concatenate([vals, np.repeat(vals[-1:], extra, axis=0)])
+    return rows, vals
+
+
+def pow2_rows(rows: np.ndarray, vals: np.ndarray):
+    """``pad_rows_to`` the next power of two: O(log k) programs a plane."""
+    return pad_rows_to(rows, vals, 1 << max(len(rows) - 1, 0).bit_length())
+
+
+@functools.lru_cache(maxsize=256)
+def scatter_fn(sharding, donate: bool = True):
+    """One plane's row scatter as a program of its own, keeping the plane's
+    sharding and (by default) donating the old buffer — the copy-on-write
+    delta apply, on device. ``donate`` follows the donation rule above."""
+    return jax.jit(scatter_rows, out_shardings=sharding,
+                   donate_argnums=(0,) if donate else ())
+
+
+@contextlib.contextmanager
+def donation_warnings_scoped():
+    """A program that donates planes it cannot alias to an output (the
+    sharded program's pod planes: the scan carry is [N]-shaped and sourced
+    from the NON-donated resident planes — by design) makes XLA report them
+    unusable once per compile. Expected there, but the warning stays live
+    for everyone else in the process."""
+    with warnings.catch_warnings():
+        warnings.filterwarnings(
+            "ignore", message="Some donated buffers were not usable")
+        yield
 
 
 def make_mesh(devices=None, pods_axis: int = 1) -> Mesh:
@@ -310,13 +379,13 @@ def solve_sharded(inp: SolverInputs, mesh: Optional[Mesh] = None,
             return np.asarray(chosen), np.asarray(scores)
 
     mesh = mesh or make_mesh()
-    resident, wave, _nbytes = place_on_mesh(inp, mesh)
+    placed, _nbytes = place_on_mesh(inp, mesh)
     # donate=False: the caller owns inp, and device_put of an
     # already-placed array aliases it — donation would delete the
     # caller's buffers. The daemon's mesh executor owns its transfers
     # and is the donating caller.
     fn = sharded_program(mesh, p, gangs, donate=False)
-    chosen, scores = fn(resident, wave)
+    chosen, scores = fn(*split_inputs(placed))
     chosen = np.asarray(chosen)
     scores = np.asarray(scores)
     # padded nodes are infeasible, so indices never point past n; no remap
@@ -324,21 +393,23 @@ def solve_sharded(inp: SolverInputs, mesh: Optional[Mesh] = None,
     return chosen, scores
 
 
-def place_on_mesh(inp: SolverInputs, mesh: Mesh) -> Tuple[tuple, tuple, int]:
-    """One wave's planes onto the mesh, in ``sharded_program``'s argument
-    order: pad the node axis to the mesh, ``device_put`` every plane under
-    its sharding. -> (resident tuple, wave tuple, bytes placed — the
-    padded planes' sizes, a replicated plane counted once). Every plane is
-    placed anew on every call: nothing here is resident across waves (the
-    daemon's MeshExecutor keeps its own)."""
+def place_on_mesh(inp: SolverInputs, mesh: Mesh) -> Tuple[SolverInputs, int]:
+    """One wave's planes onto the mesh, every one anew — the cold path:
+    pad the node axis to the mesh, ``device_put`` every plane under its
+    sharding. -> (the placed SolverInputs, bytes placed — the padded
+    planes' sizes, a replicated plane counted once). Who keeps planes
+    between waves places them here once and patches them after: the wave
+    loop through models/resident.py, the daemon through MeshExecutor."""
     padded, _n = pad_inputs_for_mesh(inp, mesh)
-    shardings = input_shardings(mesh)
-    resident = tuple(jax.device_put(getattr(padded, f),
-                                    getattr(shardings, f))
-                     for f in RESIDENT_FIELDS)
-    wave = tuple(jax.device_put(getattr(padded, f), getattr(shardings, f))
-                 for f in WAVE_FIELDS)
-    return resident, wave, sum(int(a.nbytes) for a in padded)
+    placed = SolverInputs(*(jax.device_put(a, sh) for a, sh in
+                            zip(padded, input_shardings(mesh))))
+    return placed, sum(int(a.nbytes) for a in padded)
+
+
+def split_inputs(inp: SolverInputs) -> Tuple[tuple, tuple]:
+    """-> (resident tuple, wave tuple): ``sharded_program``'s arguments."""
+    return (tuple(getattr(inp, f) for f in RESIDENT_FIELDS),
+            tuple(getattr(inp, f) for f in WAVE_FIELDS))
 
 
 @functools.lru_cache(maxsize=64)

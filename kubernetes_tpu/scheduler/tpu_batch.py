@@ -74,6 +74,7 @@ from kubernetes_tpu.models.batch_solver import (decisions_to_names,
                                                 wave_parts)
 from kubernetes_tpu.models.incremental import IncrementalEncoder
 from kubernetes_tpu.models.policy import BatchPolicy, batch_policy_from
+from kubernetes_tpu.models.resident import ResidentPlanes
 from kubernetes_tpu.models.snapshot import encode_snapshot
 from kubernetes_tpu.runtime.clone import deep_clone
 from kubernetes_tpu.scheduler.driver import ConfigFactory, SchedulerConfig
@@ -317,6 +318,10 @@ class BatchScheduler:
         # runs its own controller)
         self._prewarm = None
         self._prewarm_snap = None
+        # the node planes the in-process solve keeps between waves, on the
+        # host and on the device(s) (models/resident.py): this scheduler's
+        # own; the prewarm thread compiles on planes of its own
+        self._resident = ResidentPlanes()
         if self.solver is None and self._using_default_solve and \
                 self._encoder is not None and \
                 os.environ.get("KTPU_PREWARM", "auto") != "off":
@@ -514,16 +519,18 @@ class BatchScheduler:
                 # pads to the queued target bucket
                 with tracing.phase("wave.solve.hostprep", wm.part,
                                    "solve.hostprep"):
-                    host = snapshot_to_host_inputs(snap)
+                    host = self._resident.host_inputs(snap)
                     self._prewarm_snap = snap
                     actual = {"P": n_pending}
                     if self._encoder is not None:
                         actual.update(self._encoder.fill_dims())
                     from kubernetes_tpu.solver.service import _dims_of
                     self._prewarm.observe(actual, _dims_of(host))
-                chosen, scores = solve(snap, host=host, mesh=self._mesh)
+                chosen, scores = solve(snap, host=host, mesh=self._mesh,
+                                       resident=self._resident)
             else:
-                chosen, scores = solve(snap, mesh=self._mesh)
+                chosen, scores = solve(snap, mesh=self._mesh,
+                                       resident=self._resident)
         wm.note_stall(ph.wall_s)
         wm.pods.inc(by=n_pending)
         with tracing.phase("wave.names", wm.part, "names", parent=tctx):

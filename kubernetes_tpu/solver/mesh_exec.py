@@ -51,14 +51,11 @@ compared bitwise, counted in ``solverd_mesh_parity_*``.
 
 from __future__ import annotations
 
-import contextlib
-import functools
 import json
 import logging
 import os
 import threading
 import time
-import warnings
 from collections import OrderedDict
 from typing import Dict, Optional, Tuple
 
@@ -69,62 +66,6 @@ from kubernetes_tpu.util import metrics, tracing, warmstart
 __all__ = ["MeshExecutor"]
 
 _log = logging.getLogger("kubernetes_tpu.solver.mesh_exec")
-
-
-@contextlib.contextmanager
-def _donation_warnings_scoped():
-    """The sharded program donates the per-wave pod planes; most cannot
-    alias an output or carry buffer (the scan carry is [N]-shaped and
-    sourced from the NON-donated resident planes — by design), so XLA
-    reports them unusable once per compiled program. Expected here, but
-    the warning stays live for everyone else in the process."""
-    with warnings.catch_warnings():
-        warnings.filterwarnings(
-            "ignore", message="Some donated buffers were not usable")
-        yield
-
-
-@functools.lru_cache(maxsize=256)
-def _scatter_fn(sharding, donate: bool = True):
-    """Row scatter that keeps the plane's sharding and (by default)
-    donates the old buffer: the copy-on-write delta apply, on device.
-
-    Donation is safe ONLY for buffers XLA itself produced (a previous
-    scatter's output): the executor owns those exclusively and the
-    previous wave's solve has been read back before the next delta
-    arrives (the solve thread is single). A buffer that came from
-    ``jax.device_put`` of a host numpy array may ALIAS that array's
-    memory on the CPU backend (zero-copy when alignment allows) — the
-    delta cache keeps the host array alive for identity chaining, so
-    donating the aliased device buffer frees memory numpy still owns and
-    corrupts the native heap (observed live as ``malloc(): unsorted
-    double linked list corrupted`` killing the daemon mid-churn; the
-    in-process path in parallel/mesh.py documents the same hazard).
-    The first delta after a fresh establish therefore uses the
-    non-donating variant; every later delta donates."""
-    import jax
-
-    def f(base, rows, vals):
-        return base.at[rows].set(vals)
-
-    return jax.jit(f, out_shardings=sharding,
-                   donate_argnums=(0,) if donate else ())
-
-
-def _pow2_rows(rows: np.ndarray, vals: np.ndarray):
-    """Bucket a delta's changed-row count to the next power of two by
-    repeating the last (row, value) pair — idempotent under scatter-set
-    (same index, same value) — so _scatter_fn compiles O(log k) programs
-    per plane instead of one per distinct row count the churn happens to
-    produce."""
-    k = len(rows)
-    want = 1 << max(k - 1, 0).bit_length()
-    if k == 0 or want == k:
-        return rows, vals
-    extra = want - k
-    rows = np.concatenate([rows, np.repeat(rows[-1:], extra, axis=0)])
-    vals = np.concatenate([vals, np.repeat(vals[-1:], extra, axis=0)])
-    return rows, vals
 
 
 class MeshExecutor:
@@ -162,7 +103,7 @@ class MeshExecutor:
         # device buffer; xla_owned: True only when dev came out of an
         # XLA program (scatter output) — a device_put-established dev
         # may ALIAS src on the CPU backend and must NEVER be donated
-        # (see _scatter_fn)
+        # (parallel/mesh.py, the donation rule)
         self._resident: "OrderedDict[tuple, dict]" = OrderedDict()
         self._resident_bytes = 0
         # keys whose residency was LRU-evicted: their next wave's full
@@ -302,21 +243,11 @@ class MeshExecutor:
         seconds). Compile + first run are untimed (warm start covers
         them across restarts); the timed run is the steady per-wave
         cost the dispatch decision is about."""
-        import jax
         import jax.numpy as jnp
 
-        padded, _n = self._pm.pad_inputs_for_mesh(inp, mesh)
-        sh = self._pm.input_shardings(mesh)
         fn = self._pm.sharded_program(mesh, pol, gangs, donate=False)
-
-        def place():
-            res = tuple(jax.device_put(getattr(padded, f), getattr(sh, f))
-                        for f in self._pm.RESIDENT_FIELDS)
-            wav = tuple(jax.device_put(getattr(padded, f), getattr(sh, f))
-                        for f in self._pm.WAVE_FIELDS)
-            return res, wav
-
-        res, wav = place()
+        res, wav = self._pm.split_inputs(
+            self._pm.place_on_mesh(inp, mesh)[0])
         chosen, scores = fn(res, wav)
         both = np.asarray(jnp.stack([chosen, scores]))
         t0 = time.perf_counter()
@@ -379,14 +310,15 @@ class MeshExecutor:
                 _src, base_dev, base_xla_owned = rec
                 rows, vals = d[1], d[2]
                 vals = self._pad_vals(name, vals, pad)
-                rows, vals = _pow2_rows(np.ascontiguousarray(rows),
-                                        np.ascontiguousarray(vals))
+                rows, vals = pm.pow2_rows(np.ascontiguousarray(rows),
+                                          np.ascontiguousarray(vals))
                 # donate only XLA-owned bases: a device_put-established
-                # base may alias the cached host array (see _scatter_fn)
-                with _donation_warnings_scoped():
-                    dev = _scatter_fn(getattr(sh, name),
-                                      donate=base_xla_owned)(base_dev,
-                                                             rows, vals)
+                # base may alias the cached host array (parallel/mesh.py,
+                # the donation rule)
+                with pm.donation_warnings_scoped():
+                    dev = pm.scatter_fn(getattr(sh, name),
+                                        donate=base_xla_owned)(base_dev,
+                                                               rows, vals)
                 transfer += rows.nbytes + vals.nbytes
                 xla_owned = True
             else:
@@ -487,7 +419,7 @@ class MeshExecutor:
             both = np.asarray(jnp.stack([chosen, scores]))
         else:
             fn = pm.sharded_program(mesh, pol, gangs, donate=False)
-            with _donation_warnings_scoped():
+            with pm.donation_warnings_scoped():
                 chosen, scores = fn(tuple(resident_dev), tuple(wave_dev))
                 both = np.asarray(jnp.stack([chosen, scores]))
         if tctx is not None:

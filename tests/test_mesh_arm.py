@@ -100,7 +100,36 @@ def test_a_kernel_eligible_wave_under_the_mesh_counts_as_before(mesh):
     grown = {k for k, n in bs.wave_programs().by_label().items()
              if n - programs0.get(k, 0)}
     assert len(grown) == 1 and grown <= {("pallas", "cpu"), ("scan", "cpu")}
-    assert bs.mesh_placed_bytes().total() == placed0
+    # the bytes that crossed are counted on the one-device arm too: with
+    # nothing resident, every plane, unpadded
+    assert bs.mesh_placed_bytes().total() - placed0 == sum(
+        a.nbytes for a in bs.snapshot_to_host_inputs(snap))
+
+
+def test_placed_bytes_are_the_bytes_that_crossed(mesh, big_nodes):
+    """With resident planes the first wave places everything and the
+    second ships its pod planes and the rows the first one's binds
+    touched: O(rows + pod planes), not O(nodes)."""
+    from kubernetes_tpu.models.incremental import IncrementalEncoder
+    from kubernetes_tpu.models.resident import ResidentPlanes
+    enc, planes = IncrementalEncoder(), ResidentPlanes()
+    bound, crossed = [], []
+    for w in range(3):
+        pending = _pods(900 + w * WAVE, WAVE)
+        snap = enc.encode_delta(big_nodes, bound[-WAVE:], [], pending, []) \
+            if w else enc.encode(big_nodes, bound, pending, [])
+        placed0 = bs.mesh_placed_bytes().total()
+        chosen, _scores = bs.solve(snap, mesh=mesh, resident=planes)
+        crossed.append(bs.mesh_placed_bytes().total() - placed0)
+        for pod, host in zip(pending, bs.decisions_to_names(snap, chosen)):
+            pod.spec.host = pod.status.host = host
+        bound += pending
+    whole = sum(a.nbytes for a in bs.snapshot_to_host_inputs(snap))
+    assert crossed[0] > 0.9 * whole > N_NODES * 4
+    # the second wave placed whole once more (the first binds grew the
+    # band column: a new epoch); from the third on only what changed
+    assert crossed[1] > 0.9 * whole
+    assert crossed[2] < 64 * 1024 < whole / 20
 
 
 def test_warm_compile_leaves_nothing_to_compile_for_a_wave_of_its_bucket(

@@ -173,3 +173,55 @@ def test_unpack_program_compiles_at_served_bucket(one_chip,
     spec, total = bs._pack_spec(host)
     buf = jax.ShapeDtypeStruct((total,), jnp.uint8, sharding=one_chip)
     bs._unpack_device.lower(buf, spec=spec).compile()
+
+
+def _apply_lowering(host, mesh, sharding_of, rows_bucket):
+    """The resident planes' apply program (models/resident.py) of one pod
+    and row bucket, lowered for planes placed by ``sharding_of(field)``."""
+    import numpy as np
+    from kubernetes_tpu.models import resident as rs
+    from kubernetes_tpu.parallel import mesh as pm
+    n = host.cap.shape[0]
+    pad = 0 if mesh is None else pm._pad_width(n, mesh.shape["nodes"])
+    planes = {f: getattr(host, f) for f in pm.RESIDENT_FIELDS}
+    names = tuple(f for f in rs.PATCH_FIELDS if planes[f].size)
+    spec, total = bs._pack_spec(rs.wave_arrays(
+        host, names, np.zeros(1, np.int64), rows_bucket))
+    patch = tuple(jax.ShapeDtypeStruct(
+        pm.pad_plane(f, planes[f], pad).shape, planes[f].dtype,
+        sharding=sharding_of(f)) for f in names)
+    buf = jax.ShapeDtypeStruct((total,), jnp.uint8,
+                               sharding=sharding_of(None))
+    return rs._apply_program(spec, names, n, mesh, True).lower(patch, buf)
+
+
+@pytest.mark.parametrize("n_pods", [128, 1_024])
+def test_apply_program_compiles_at_served_bucket(one_chip,
+                                                 no_persistent_cache, n_pods):
+    """What the wave loop runs in ``_unpack_device``'s place: the pod
+    planes and the dirty rows, patched into the planes the chip kept."""
+    from kubernetes_tpu.models import resident as rs
+    _snap, host = _wave(5_000, n_pods)
+    for rows_bucket in rs.ROW_LADDER:
+        _apply_lowering(host, None, lambda f: one_chip,
+                        rows_bucket).compile()
+
+
+def test_sharded_apply_program_compiles_without_a_collective(
+        topo, no_persistent_cache):
+    """On the 1x4 mesh the planes are patched where they lie: rows and
+    pod planes arrive replicated, each chip writes the rows it owns."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    from kubernetes_tpu.models import resident as rs
+    from kubernetes_tpu.parallel import mesh as pm
+    _snap, host = _wave(40_960, 256)
+    mesh = pm.make_mesh(topo.devices, pods_axis=1)
+    sh = pm.input_shardings(mesh)
+    rep = NamedSharding(mesh, PartitionSpec())
+    text = _apply_lowering(
+        host, mesh, lambda f: rep if f is None else getattr(sh, f),
+        rs.ROW_LADDER[-1]).compile().as_text()
+    for collective in ("all-gather", "all-reduce", "collective-permute",
+                       "all-to-all"):
+        assert collective not in text, collective
+
