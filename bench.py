@@ -20,16 +20,6 @@ its own equivalence gate:
   gang            1k PodGroups x 8 pods all-or-nothing on 2k nodes
   churn           pods offered at 1k/s through the REAL BatchScheduler +
                   apiserver + reflectors (incremental encoder path)
-  pipeline        (--pipeline only) a pre-created backlog drained through
-                  the REAL BatchScheduler twice — causal loop vs the
-                  speculative double-buffered loop — committed placements
-                  bit-identical, first wave oracle-checked
-
-With ``--pipeline`` the solver configs also claim the double-buffered
-wave rate as ``value`` (the shipped driver now runs that loop —
-scheduler/tpu_batch.py pipelined mode), with the causal rate and the
-speedup alongside; the churn config runs its scheduler with
-``pipeline=True``.
 
 Honest timing: a wave costs encode + host->device transfer + solve +
 decision readback; every timed run performs all four inside the clock and
@@ -47,7 +37,7 @@ The cumulative record is printed after every config, so the last JSON
 line on stdout is always the newest truth.
 
 Usage: python bench.py [--smoke] [--pods P] [--nodes N] [--configs a,b,..]
-                       [--profile DIR] [--pipeline] [--detail-out FILE]
+                       [--profile DIR] [--detail-out FILE]
 """
 
 from __future__ import annotations
@@ -66,8 +56,9 @@ def log(*a):
 # reference target: 99% of decisions < 1s at 100 nodes / 3000 pods
 # (docs/roadmap.md:61) normalizes to 10k pods/s — see module docstring
 BASELINE_PODS_PER_S = 10_000.0
-TIMING_DESC = ("steady-state wave: encode + pipelined host->device + solve "
-               "+ readback (median full-pipeline run; see timed_wave)")
+TIMING_DESC = ("steady-state wave: encode + host->device + solve + readback, "
+               "no sync between transfer and solve (median run; see "
+               "timed_wave)")
 
 
 # --------------------------------------------------------------------------
@@ -84,26 +75,19 @@ _COMPACT_CFG_KEYS = (
     ("p99", ("wave_s_p99", "p99")),
     ("path", ("path",)),
     ("gate", ("gate",)),
-    ("speedup", ("pipeline_speedup", "speedup")),
-    ("causal", ("causal_pods_per_s", "causal_pods_per_sec", "causal")),
-    ("hits", ("speculation_hits", "hits")),
-    ("inval", ("speculation_invalidations", "inval")),
-    ("div", ("divergent_decisions", "div")),
 )
 
 
 def _compact_record(rec: dict, detail_name=None) -> str:
     """The <1.5 KB stdout summary of a full benchmark record: top-level
-    verdict + per-config value/p50/p99/path/gate (and the pipeline
-    config's speedup/divergence fields). BENCH_r05.json had parsed:null
+    verdict + per-config value/p50/p99/path/gate. BENCH_r05.json had parsed:null
     because one giant line (runs_s arrays inline) truncated in capture —
     arrays and calibration detail now live in the detail file only.
     Degrades by dropping optional keys before it would ever exceed the
     budget."""
     out = {}
-    for k in ("metric", "value", "unit", "vs_baseline", "pipeline_speedup",
-              "divergent_decisions", "platform", "device_kind",
-              "device_count", "partial"):
+    for k in ("metric", "value", "unit", "vs_baseline", "platform",
+              "device_kind", "device_count", "partial"):
         if k in rec:
             out[k] = rec[k]
     if "error" in rec:
@@ -314,20 +298,15 @@ def timed_wave(nodes, existing, pending, services, batch_policy=None,
         jax.block_until_ready(out)
         compile_s = time.perf_counter() - t0
 
-    def one_wave(pre=None):
+    def one_wave():
         """The FULL wave pipeline, exactly as a live scheduler runs it:
         encode, then ship with no sync between transfer and solve (the
         dispatch pipelines the uploads into the device call; the decision
         readback is the one sync), then the gang post-pass.
-        ``pre=(snap, host_inputs)`` skips the encode (the double-buffered
-        loop encodes on a side thread).
         Returns (snap, decisions, encode_end_t)."""
-        if pre is None:
-            snap = encode_snapshot(nodes, existing, pending, services,
-                                   policy=batch_policy)
-            host = snapshot_to_host_inputs(snap)
-        else:
-            snap, host = pre
+        snap = encode_snapshot(nodes, existing, pending, services,
+                               policy=batch_policy)
+        host = snapshot_to_host_inputs(snap)
         t_enc = time.perf_counter()
         inp = ship_inputs(host, plan.device)
         chosen, _scores = solve_device(inp, snap.policy, gangs, peer_bound,
@@ -337,8 +316,8 @@ def timed_wave(nodes, existing, pending, services, batch_policy=None,
             chosen_np = gang_mod.apply_all_or_nothing(snap.pod_rid, chosen_np)
         return snap, chosen_np, t_enc
 
-    # -- one untimed COLD pipelined pass ------------------------------------
-    # The first pipelined pass may pay one-time costs the sequential
+    # -- one untimed COLD full pass ------------------------------------------
+    # The first unsynced pass may pay one-time costs the sequential
     # warmup above did not. A live scheduler pays them once per process;
     # pay and log them here so the timed distribution is pure steady
     # state.
@@ -360,34 +339,6 @@ def timed_wave(nodes, existing, pending, services, batch_policy=None,
     if profile:
         jax.profiler.stop_trace()
         log(f"jax.profiler trace written to {profile}")
-
-    # -- double-buffered throughput: encode wave k+1 WHILE wave k solves ----
-    # A live batch scheduler's waves are independent snapshots, so the host
-    # can encode the next wave while the device works on
-    # the current one — steady-state cost per wave becomes
-    # max(encode, transfer+solve+readback) instead of their sum. The device
-    # wait releases the GIL inside jax, so one encode-ahead thread is
-    # enough. Decisions are identical (same snapshot per wave); this
-    # measures THROUGHPUT, while wave_s/p99 above remain the per-wave
-    # LATENCY a single decision observes.
-    import concurrent.futures as _cf
-
-    def encode_next():
-        snap = encode_snapshot(nodes, existing, pending, services,
-                               policy=batch_policy)
-        return snap, snapshot_to_host_inputs(snap)
-
-    pipelined_wave_s = None
-    if plan.path == "device":
-        with _cf.ThreadPoolExecutor(max_workers=1) as ex:
-            fut = ex.submit(encode_next)
-            t_start = time.perf_counter()
-            for k in range(runs):
-                pre = fut.result()
-                if k + 1 < runs:                  # overlaps the solve below
-                    fut = ex.submit(encode_next)
-                one_wave(pre=pre)
-            pipelined_wave_s = (time.perf_counter() - t_start) / runs
 
     srt = sorted(wave_runs)
     p50, p95, p99 = (float(v) for v in
@@ -419,14 +370,6 @@ def timed_wave(nodes, existing, pending, services, batch_policy=None,
         "scheduled": int((chosen_np[:n] >= 0).sum()),
     }
     res["cold_pipeline_s"] = round(cold_pipeline_s, 3)
-    if pipelined_wave_s is not None:
-        # throughput under double-buffering, reported alongside. The
-        # shipped BatchScheduler runs exactly this loop under --pipeline
-        # (scheduler/tpu_batch.py speculative mode), so bench.py
-        # --pipeline promotes this rate to `value`; without the flag,
-        # `value` stays the median sequential wave.
-        res["pipelined_wave_s"] = round(pipelined_wave_s, 4)
-        res["pipelined_pods_per_sec"] = round(n / pipelined_wave_s, 1)
     if calibrated:
         res["router_host_s"] = round(plan.host_s, 4)
         res["router_device_s"] = round(plan.device_s, 4)
@@ -611,7 +554,7 @@ def check_equivalence(tag, snap, chosen_np, nodes, existing, pending,
 def run_solver_config(tag, n_nodes, n_pods, gate_nodes=0, gate_pods=0,
                      policy=None, three_resources=False, gang_groups=0,
                      gang_size=8, profile=None, full_gate=False,
-                     gate_budget_s=75.0, runs=30, pipeline=False):
+                     gate_budget_s=75.0, runs=30):
     """Benchmark one solver-path config. Gate variants: full_gate runs the
     serial oracle over the whole wave; gate_pods/gate_nodes take a fixed
     slice; gate_pods=0 with gate_nodes=0 sizes the pod slice to
@@ -695,24 +638,12 @@ def run_solver_config(tag, n_nodes, n_pods, gate_nodes=0, gate_pods=0,
         log(f"[{tag}] all-or-nothing invariant OK: "
             f"{placed}/{gang_groups} groups fully placed")
 
-    if pipeline and "pipelined_pods_per_sec" in res:
-        # --pipeline: the shipped driver double-buffers, so the
-        # double-buffered rate IS the mode's throughput; the causal rate
-        # and the measured speedup ride alongside (same backend, same run)
-        res["causal_pods_per_s"] = res["value"]
-        res["value"] = res["pipelined_pods_per_sec"]
-        res["pipeline_speedup"] = round(
-            res["pipelined_pods_per_sec"] / res["causal_pods_per_s"], 3)
-
-    pipe = (f"; pipelined {res['pipelined_wave_s']:.3f}s/wave = "
-            f"{res['pipelined_pods_per_sec']:.0f} pods/s"
-            if "pipelined_wave_s" in res else "")
     log(f"[{tag}] wave {res['wave_s']:.3f}s over {res['runs']} runs "
         f"(p95 {res['wave_s_p95']:.3f} p99 {res['wave_s_p99']:.3f} "
         f"max {res['wave_s_max']:.3f}; path={res['path']}) "
         f"= encode {res['encode_s']:.3f} "
         f"+ device(transfer+solve+readback) {res['device_s']:.4f}; "
-        f"{res['value']:.0f} pods/s{pipe}; "
+        f"{res['value']:.0f} pods/s; "
         f"scheduled {res['scheduled']}/{res['pods']}")
     return res
 
@@ -822,201 +753,14 @@ def run_mesh_config(tag, n_nodes, n_pods, pods_axis=1, gate_nodes=600,
     return res
 
 
-def _pipeline_counters() -> dict:
-    """Snapshot of the scheduler_pipeline_* counters (process-global)."""
-    from kubernetes_tpu.scheduler.tpu_batch import _pipeline_metrics
-    pm = _pipeline_metrics()
-    return {
-        "hits": pm.hits.value(),
-        "invalidations": pm.invalidations.total(),
-        "unspeculated": pm.unspeculated.value(),
-        "overlap_s": pm.overlap.value(),
-    }
-
-
-def _pipeline_delta(before: dict) -> dict:
-    now = _pipeline_counters()
-    return {
-        "speculation_hits": int(now["hits"] - before["hits"]),
-        "speculation_invalidations": int(now["invalidations"]
-                                         - before["invalidations"]),
-        "unspeculated_waves": int(now["unspeculated"]
-                                  - before["unspeculated"]),
-        "overlap_seconds": round(now["overlap_s"] - before["overlap_s"], 3),
-    }
-
-
-def run_pipeline_config(tag, n_nodes, n_pods, wave_size=1024,
-                        oracle_pods=None):
-    """The shipped --pipeline mode, measured end-to-end through the live
-    stack: a pre-created backlog of ``n_pods`` drained through the REAL
-    BatchScheduler (in-process apiserver, reflectors, FIFO, incremental
-    encoder, Binding writes) twice on the same backend — once with the
-    causal wave loop, once with the speculative double-buffered loop —
-    after an untimed warmup pass per mode that pays the once-per-shape XLA
-    compiles both modes share.
-
-    Gates (zero tolerance):
-    - every committed (pod -> node) placement bit-identical between the
-      two modes across the whole record — the oracle/fullgate-style
-      divergence check for the speculation machinery;
-    - the first wave's placements equal the serial oracle run over the
-      same pods and nodes (the causal loop's own equivalence anchor);
-    - all pods bound in both modes.
-
-    ``value`` is the pipelined mode's sustained bind rate; the causal
-    rate, speedup, and speculation hit/invalidation counts ride along."""
-    from kubernetes_tpu.api import types as api
-    from kubernetes_tpu.api.quantity import Quantity
-    from kubernetes_tpu.apiserver.master import Master
-    from kubernetes_tpu.client.client import Client, InProcessTransport
-    from kubernetes_tpu.scheduler.driver import ConfigFactory
-    from kubernetes_tpu.scheduler.tpu_batch import BatchScheduler
-
-    def mk_pod(i):
-        return api.Pod(
-            metadata=api.ObjectMeta(name=f"pipe-{i:06d}",
-                                    namespace="default",
-                                    uid=f"uid-pipe-{i:06d}"),
-            spec=api.PodSpec(containers=[api.Container(
-                name="c", image="img",
-                resources=api.ResourceRequirements(limits={
-                    "cpu": Quantity(f"{100 + (i % 8) * 100}m"),
-                    "memory": Quantity(f"{128 + (i % 6) * 64}Mi")}))]))
-
-    def one_run(pipeline: bool, timed: bool):
-        m = Master()
-        client = Client(InProcessTransport(m))
-        for i in range(n_nodes):
-            client.nodes().create(api.Node(
-                metadata=api.ObjectMeta(name=f"node-{i:05d}"),
-                spec=api.NodeSpec(capacity={"cpu": Quantity("64"),
-                                            "memory": Quantity("256Gi")})))
-        for i in range(n_pods):
-            client.pods().create(mk_pod(i))
-        factory = ConfigFactory(client, node_poll_period=2.0)
-        config = factory.create(pipeline=pipeline)
-        # the backlog and the node set must be fully synced BEFORE the
-        # first drain so both modes see identical deterministic waves
-        deadline = time.monotonic() + 60.0
-        while time.monotonic() < deadline:
-            if len(factory.pod_queue.list()) >= n_pods and \
-                    len(factory.node_store.list()) >= n_nodes:
-                break
-            time.sleep(0.02)
-        else:
-            log(f"[{tag}] PIPELINE FAILURE: reflectors never synced the "
-                f"backlog")
-            return None
-        sched = BatchScheduler(config, factory, client, wave_size=wave_size,
-                               wave_linger_s=0.02)
-        t0 = time.perf_counter()
-        sched.run()
-        deadline = time.monotonic() + 600.0
-        bound = 0
-        while time.monotonic() < deadline:
-            bound = len(factory.scheduled_pods.list())
-            if bound >= n_pods:
-                break
-            time.sleep(0.02)
-        dt = time.perf_counter() - t0
-        placements = {p.metadata.name: p.spec.host
-                      for p in client.pods().list().items}
-        sched.stop()
-        factory.stop()
-        if bound < n_pods:
-            log(f"[{tag}] PIPELINE FAILURE: "
-                f"{'pipelined' if pipeline else 'causal'} run bound only "
-                f"{bound}/{n_pods}")
-            return None
-        mode = "pipelined" if pipeline else "causal"
-        log(f"[{tag}] {mode}{'' if timed else ' (warmup)'}: {n_pods} pods "
-            f"in {dt:.2f}s = {n_pods / dt:.0f} pods/s")
-        return dt, placements
-
-    log(f"[{tag}] backlog {n_pods} pods x {n_nodes} nodes, wave "
-        f"{wave_size}: causal vs speculative double-buffered loop through "
-        f"the live stack")
-    # untimed warmup pass per mode: pays the shared once-per-shape XLA
-    # compiles so neither timed mode carries the other's compile bill
-    if one_run(False, timed=False) is None:
-        return None
-    if one_run(True, timed=False) is None:
-        return None
-    causal = one_run(False, timed=True)
-    if causal is None:
-        return None
-    before = _pipeline_counters()
-    piped = one_run(True, timed=True)
-    if piped is None:
-        return None
-    spec = _pipeline_delta(before)
-    dt_c, pl_c = causal
-    dt_p, pl_p = piped
-
-    divergent = sum(1 for k, v in pl_c.items() if pl_p.get(k) != v)
-    if divergent:
-        diffs = [(k, v, pl_p.get(k)) for k, v in pl_c.items()
-                 if pl_p.get(k) != v][:5]
-        log(f"[{tag}] PIPELINE FAILURE: {divergent} committed decisions "
-            f"diverge between causal and pipelined runs; first: {diffs}")
-        return None
-
-    # first-wave serial-oracle anchor: the causal loop's equivalence story
-    # is carried by the solver-config gates; this re-checks it end-to-end
-    # through the live stack on exactly the wave the schedulers solved
-    from kubernetes_tpu.models.oracle import solve_serial
-    n_gate = min(wave_size, n_pods) if oracle_pods is None \
-        else min(oracle_pods, wave_size, n_pods)
-    nodes = [api.Node(
-        metadata=api.ObjectMeta(name=f"node-{i:05d}"),
-        spec=api.NodeSpec(capacity={"cpu": Quantity("64"),
-                                    "memory": Quantity("256Gi")}))
-        for i in range(n_nodes)]
-    first = [mk_pod(i) for i in range(n_gate)]
-    t0 = time.perf_counter()
-    oracle = solve_serial(nodes, [], first, [])
-    oracle_s = time.perf_counter() - t0
-    actual = [pl_p[p.metadata.name] for p in first]
-    if actual != oracle:
-        n_div = sum(1 for a, b in zip(actual, oracle) if a != b)
-        log(f"[{tag}] PIPELINE FAILURE: first wave diverges from the "
-            f"serial oracle on {n_div}/{n_gate} pods")
-        return None
-    log(f"[{tag}] first-wave oracle OK on {n_gate} pods "
-        f"({oracle_s:.1f}s); zero divergent decisions across "
-        f"{n_pods} commits")
-
-    speedup = dt_c / dt_p
-    log(f"[{tag}] causal {n_pods / dt_c:.0f} pods/s vs pipelined "
-        f"{n_pods / dt_p:.0f} pods/s -> speedup {speedup:.2f}x "
-        f"(hits {spec['speculation_hits']}, invalidations "
-        f"{spec['speculation_invalidations']})")
-    rec = {
-        "pods": n_pods, "nodes": n_nodes, "wave_size": wave_size,
-        "value": round(n_pods / dt_p, 1), "unit": "pods/s",
-        "causal_pods_per_s": round(n_pods / dt_c, 1),
-        "pipeline_speedup": round(speedup, 3),
-        "causal_total_s": round(dt_c, 2),
-        "pipelined_total_s": round(dt_p, 2),
-        "divergent_decisions": 0,
-        "gate": (f"bit-identical-{n_pods}-commits+"
-                 f"first-wave-oracle-{n_gate}x{n_nodes}"),
-    }
-    rec.update(spec)
-    return rec
-
-
 def run_churn_config(tag, n_nodes, n_pods, rate_pods_per_s, wave_size=1024,
-                     solver_addr="", pipeline=False):
+                     solver_addr=""):
     """Churn replay through the REAL BatchScheduler: in-process apiserver,
     reflectors, FIFO, incremental encoder, Binding writes — pods offered at
     a fixed rate, sustained bind throughput measured. With ``solver_addr``
     the waves solve on a shared kube-solverd daemon (cmd/solverd) instead
     of in-process — the record then carries the remote/fallback wave
-    split so a silently-down daemon can't pass as a solverd measurement.
-    With ``pipeline`` the scheduler runs the speculative double-buffered
-    loop; its hit/invalidation counters land in the record."""
+    split so a silently-down daemon can't pass as a solverd measurement."""
     import threading
 
     from kubernetes_tpu.api import types as api
@@ -1028,8 +772,7 @@ def run_churn_config(tag, n_nodes, n_pods, rate_pods_per_s, wave_size=1024,
 
     log(f"[{tag}] {n_pods} pods at {rate_pods_per_s}/s onto {n_nodes} nodes "
         f"through the live scheduler stack"
-        + (f" (solverd at {solver_addr})" if solver_addr else "")
-        + (" (pipelined waves)" if pipeline else ""))
+        + (f" (solverd at {solver_addr})" if solver_addr else ""))
     m = Master()
     client = Client(InProcessTransport(m))
     for i in range(n_nodes):
@@ -1038,8 +781,7 @@ def run_churn_config(tag, n_nodes, n_pods, rate_pods_per_s, wave_size=1024,
             spec=api.NodeSpec(capacity={"cpu": Quantity("64"),
                                         "memory": Quantity("256Gi")})))
     factory = ConfigFactory(client, node_poll_period=0.5)
-    config = factory.create(solver_addr=solver_addr, pipeline=pipeline)
-    pipe_before = _pipeline_counters() if pipeline else None
+    config = factory.create(solver_addr=solver_addr)
     sched = BatchScheduler(config, factory, client, wave_size=wave_size,
                            wave_linger_s=0.1).run()
     try:
@@ -1191,9 +933,6 @@ def run_churn_config(tag, n_nodes, n_pods, rate_pods_per_s, wave_size=1024,
             rec["solverd_remote_waves"] = rs.remote_waves
             rec["solverd_fallback_waves"] = rs.fallback_waves
             rec["solverd_busy_waves"] = rs.busy_waves
-        if pipeline:
-            rec["pipeline"] = True
-            rec.update(_pipeline_delta(pipe_before))
         if sat_bound >= sat_total:
             rec["saturation_pods_per_s"] = round(sat_value, 1)
             rec["saturation_offered_pods_per_s"] = round(
@@ -1228,15 +967,6 @@ def _parser() -> argparse.ArgumentParser:
                          "waves there instead of in-process. The "
                          "multi-process analog is hack/churn_mp.py "
                          "--solverd, which spawns the daemon itself.")
-    ap.add_argument("--pipeline", action="store_true",
-                    help="measure the speculative double-buffered wave "
-                         "mode (kube-scheduler --pipeline): solver "
-                         "configs claim the double-buffered rate as "
-                         "value (causal rate + speedup alongside), the "
-                         "churn scheduler runs pipelined, and the "
-                         "'pipeline' config races the causal vs "
-                         "pipelined BatchScheduler through the live "
-                         "stack with a bit-identity gate")
     ap.add_argument("--detail-out", "--detail_out", default=None,
                     help="full-record sidecar (runs_s arrays, router "
                          "calibration); default BENCH_detail.json next "
@@ -1282,15 +1012,11 @@ def main(argv=None) -> int:
     s = args.smoke
     runs = args.runs or (5 if s else 30)
     known = {"north_star", "basic", "affinity", "binpack3", "gang", "churn",
-             "pipeline", "mesh", "priority"}
+             "mesh", "priority"}
     if args.configs != "all":
         want = set(args.configs.split(","))
     else:
         want = set(known)
-        if not args.pipeline:
-            # the pipeline config races two full live-stack drains; only
-            # meaningful (and only paid for) when the mode is requested
-            want.discard("pipeline")
         if len(devices) <= 1:
             # the mesh config races two device layouts; without a second
             # device there is nothing to race (run under XLA_FLAGS=
@@ -1329,13 +1055,6 @@ def main(argv=None) -> int:
             **device,
             "configs": configs,
         }
-        if "pipeline" in configs:
-            # the shipped --pipeline mode's headline claim, surfaced at
-            # top level: speedup vs causal on the same backend and run,
-            # with the zero-divergence gate it passed
-            rec["pipeline_speedup"] = configs["pipeline"]["pipeline_speedup"]
-            rec["divergent_decisions"] = \
-                configs["pipeline"]["divergent_decisions"]
         if failed:
             rec["value"], rec["vs_baseline"] = 0.0, 0.0
             rec["error"] = f"failed configs: {failed}"
@@ -1376,27 +1095,26 @@ def main(argv=None) -> int:
     run("north_star", run_solver_config,
         args.nodes or (100 if s else ns_nodes),
         args.pods or (500 if s else ns_pods),
-        full_gate=s, profile=args.profile, runs=runs,
-        pipeline=args.pipeline)
+        full_gate=s, profile=args.profile, runs=runs)
     b_nodes, b_pods, _ = FULL_SHAPES["basic"]
     run("basic", run_solver_config,
         50 if s else b_nodes, 100 if s else b_pods, full_gate=True,
-        runs=runs, pipeline=args.pipeline)
+        runs=runs)
     a_nodes, a_pods, _ = FULL_SHAPES["affinity"]
     run("affinity", run_solver_config,
         100 if s else a_nodes, 200 if s else a_pods,
         gate_nodes=100 if s else 600, gate_pods=200 if s else 600,
-        policy=aff_policy, runs=runs, pipeline=args.pipeline)
+        policy=aff_policy, runs=runs)
     p3_nodes, p3_pods, p3_kw = FULL_SHAPES["binpack3"]
     run("binpack3", run_solver_config,
         100 if s else p3_nodes, 300 if s else p3_pods,
         gate_nodes=100 if s else 600, gate_pods=300 if s else 600,
-        runs=runs, pipeline=args.pipeline, **p3_kw)
+        runs=runs, **p3_kw)
     g_nodes, g_pods, g_kw = FULL_SHAPES["gang"]
     run("gang", run_solver_config,
         100 if s else g_nodes, g_pods,
         gate_nodes=50 if s else 200, gate_pods=160 if s else 400,
-        runs=runs, pipeline=args.pipeline,
+        runs=runs,
         **({"gang_groups": 20, "gang_size": 8} if s else g_kw))
     m_nodes, m_pods, _ = FULL_SHAPES["mesh"]
     run("mesh", run_mesh_config,
@@ -1411,10 +1129,7 @@ def main(argv=None) -> int:
     run("churn", run_churn_config,
         20 if s else 500, 300 if s else 8_000,
         rate_pods_per_s=300 if s else 1_000,
-        solver_addr=args.solver_addr, pipeline=args.pipeline)
-    run("pipeline", run_pipeline_config,
-        32 if s else 256, 512 if s else 8_192,
-        wave_size=128 if s else 1_024)
+        solver_addr=args.solver_addr)
 
     record = build_record()
     if not configs and not failed:

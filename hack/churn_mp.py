@@ -1557,25 +1557,6 @@ def _scrape_unschedulable(ports) -> dict:
     return out
 
 
-def _scrape_pipeline(port: int) -> dict:
-    """Speculation counters from a pipelined scheduler worker's /metrics."""
-    raw = urllib.request.urlopen(
-        f"http://127.0.0.1:{port}/metrics", timeout=5).read().decode()
-    out = {"speculation_hits": 0, "speculation_invalidations": 0,
-           "overlap_seconds": 0.0}
-    for line in raw.splitlines():
-        if line.startswith("scheduler_pipeline_speculation_hits_total "):
-            out["speculation_hits"] += int(float(line.rsplit(None, 1)[1]))
-        elif line.startswith(
-                "scheduler_pipeline_speculation_invalidations_total{"):
-            out["speculation_invalidations"] += int(
-                float(line.rsplit(None, 1)[1]))
-        elif line.startswith("scheduler_pipeline_overlap_seconds_total "):
-            out["overlap_seconds"] += float(line.rsplit(None, 1)[1])
-    out["overlap_seconds"] = round(out["overlap_seconds"], 3)
-    return out
-
-
 def _wave_stats_delta(start: dict, end: dict) -> dict:
     """Steady-state per-wave stats: END minus the post-warmup BASELINE, so
     the once-per-bucket XLA compiles paid during warmup don't pollute the
@@ -1639,12 +1620,6 @@ def main(argv=None) -> int:
                     "every scheduler worker at it (--solver-addr): waves "
                     "coalesce into batched solves in ONE hot solver "
                     "process instead of N cold in-process ones")
-    ap.add_argument("--pipeline", action="store_true",
-                    help="run every scheduler worker with --pipeline "
-                    "(speculative double-buffered waves): the encode and "
-                    "dispatch of wave k+1 overlap the HTTP commit "
-                    "round-trips of wave k — and the solverd round-trip "
-                    "when combined with --solverd")
     ap.add_argument("--mesh-devices", type=int, default=0,
                     help="carve the solverd child's CPU backend into N "
                     "virtual devices (XLA_FLAGS="
@@ -2330,8 +2305,6 @@ def main(argv=None) -> int:
             if solver_addr:
                 cmd += ["--solver-addr", solver_addr,
                         "--solver-fallback", args.solver_fallback]
-            if args.pipeline:
-                cmd += ["--pipeline"]
             if args.prewarm:
                 # with --solver-addr the shared programs live in solverd
                 # (whose own --prewarm covers them); the scheduler then
@@ -2869,8 +2842,6 @@ def main(argv=None) -> int:
         sched_desc = ("tpu-batch scheduler"
                       if args.schedulers == 1 else
                       f"{args.schedulers} tpu-batch scheduler workers")
-        if args.pipeline:
-            sched_desc += " (--pipeline speculative double-buffering)"
         if solver_addr:
             sched_desc += " -> shared kube-solverd (wave coalescing"
             if args.mesh_devices:
@@ -3034,16 +3005,6 @@ def main(argv=None) -> int:
                       f"schedulers solved waves in-process beside "
                       f"solverd: {in_process}", file=sys.stderr, flush=True)
                 ok = False
-        if args.pipeline:
-            try:
-                pipes = [_scrape_pipeline(p) for p in sched_metrics_ports]
-                record["pipeline"] = {
-                    k: (round(sum(p[k] for p in pipes), 3)
-                        if k == "overlap_seconds"
-                        else sum(p[k] for p in pipes))
-                    for k in pipes[0]}
-            except Exception as e:
-                record["pipeline"] = {"error": f"scrape failed: {e}"}
         # pod-lifecycle latency: always scraped (the histograms are
         # metrics, on regardless of --trace) and logged as quantiles at
         # the end of every run; required in r10+ records

@@ -76,7 +76,7 @@ done
 # already forces 8 virtual devices for every run above; this step pins
 # the flag EXPLICITLY (immune to a pre-set XLA_FLAGS in the environment)
 # so the mesh executor, delta-onto-sharded-planes, and
-# pipeline-through-mesh suites always see the multi-device topology the
+# wave-loop-through-mesh suites always see the multi-device topology the
 # production solverd --mesh path ships with.
 echo "=== tier-2: solver suites under xla_force_host_platform_device_count=8 ==="
 XLA_FLAGS="--xla_force_host_platform_device_count=8" JAX_PLATFORMS=cpu \

@@ -44,18 +44,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "the daemon, which a supervisor (hack/churn_mp "
                         "--chaos, docs/design/ha.md) respawns within "
                         "seconds. CAS-convergent either way.")
-    p.add_argument("--pipeline", action="store_true",
-                   help="tpu-batch: speculative double-buffered wave "
-                        "scheduling — overlap the encode of wave k+1 "
-                        "(against the predicted post-commit state) and "
-                        "its solve dispatch with the solve/commit of "
-                        "wave k. Committed decisions stay bit-identical "
-                        "to the causal path: every speculation is "
-                        "verified against actual bind outcomes and store "
-                        "deltas before wave k+1 may commit, and "
-                        "divergence re-encodes first. Composes with "
-                        "--solver-addr (the speculative encode overlaps "
-                        "the daemon round-trip).")
     p.add_argument("--mesh", choices=("auto", "on", "off"), default="auto",
                    help="tpu-batch: device-mesh solve for in-process waves "
                         "(parallel/mesh.py): auto shards waves above the "
@@ -254,15 +242,11 @@ def build_scheduler(opts):
     config = factory.create(provider=opts.algorithm_provider,
                             policy=policy, recorder=recorder,
                             solver_addr=getattr(opts, "solver_addr", ""),
-                            pipeline=getattr(opts, "pipeline", False),
                             mesh=getattr(opts, "mesh", "auto"),
                             pods_axis=getattr(opts, "pods_axis", 1),
                             solver_fallback=getattr(
                                 opts, "solver_fallback", "inprocess"),
                             prewarm=getattr(opts, "prewarm", False))
-    if getattr(opts, "pipeline", False) and opts.algorithm != "tpu-batch":
-        print("kube-scheduler: --pipeline requires --algorithm tpu-batch; "
-              "ignoring", file=sys.stderr)
     if opts.algorithm == "tpu-batch":
         from kubernetes_tpu.models.policy import (UnsupportedPolicy,
                                                   batch_policy_from)

@@ -49,8 +49,7 @@ unschedulable pods, host-side on the planes the encoder already holds
 comparison-exact, so the unscaled snapshot planes give identical
 verdicts), through a jitted kernel whose pod axis is pow-2 bucketed
 (``_EXPLAIN_MAX_BATCH`` cap) so one pending pod does not compile per
-distinct count. The :class:`Explainer` adds a token-bucket rate limit
-and refuses to run on the pipelined loop's solve/commit threads; a
+distinct count. The :class:`Explainer` adds a token-bucket rate limit; a
 declined wave keeps the legacy generic event message and is counted in
 ``scheduler_explain_skipped_total``. Accepted tradeoff: the FIRST
 diagnosed bucket of a shape pays its jit compile inline on the loop
@@ -70,7 +69,6 @@ from __future__ import annotations
 
 import functools
 import logging
-import threading
 import time
 from typing import Dict, List, NamedTuple, Optional, Sequence
 
@@ -372,12 +370,9 @@ def explain_wave(snap: ClusterSnapshot, chosen, scores
 
 
 class Explainer:
-    """The live scheduler's diagnosis gate: rate limit + thread
-    discipline + metrics around :func:`explain_wave`.
+    """The live scheduler's diagnosis gate: rate limit + metrics around
+    :func:`explain_wave`, called on the wave loop's thread.
 
-    Runs ONLY on the wave loop thread — never on the pipelined loop's
-    solve or commit threads (their names are refused outright), so
-    diagnosis can never ride inside the solve/commit overlap window.
     A token bucket caps invocations (unschedulable pods requeue and
     re-diagnose every wave in a full cluster; the events compress
     client-side but the diagnosis work would not). Declined waves fall
@@ -388,8 +383,6 @@ class Explainer:
     (``unexplained`` when diagnosis was skipped), so the by-reason
     family always sums to the pods family.
     """
-
-    _HOT_THREAD_PREFIXES = ("tpu-batch-solve", "tpu-batch-commit")
 
     def __init__(self, qps: float = 2.0, burst: int = 4, top_k: int = 4):
         self._qps = qps
@@ -434,9 +427,6 @@ class Explainer:
         if n == 0:
             return {}
         self._mx.pods.inc(by=n)
-        if threading.current_thread().name.startswith(
-                self._HOT_THREAD_PREFIXES):
-            return self._skip("hot_path", n)
         if not self._admit():
             return self._skip("rate_limited", n)
         t0 = time.thread_time()

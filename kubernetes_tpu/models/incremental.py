@@ -581,9 +581,8 @@ class IncrementalEncoder:
 
     def restore(self, ckpt: dict) -> None:
         """Reset the encoder to a checkpoint() state wholesale — including
-        dropping any pods applied (speculatively or otherwise) since. The
-        checkpoint itself is re-copied, so it remains valid for further
-        restores."""
+        dropping any pods applied since. The checkpoint itself is
+        re-copied, so it remains valid for further restores."""
         for a in self._CKPT_ARRAYS:
             setattr(self, a, ckpt[a].copy())
         for a in self._CKPT_LISTS:
@@ -641,34 +640,6 @@ class IncrementalEncoder:
             "G": len(self._grp_rows),
             "B": len(self._bands),
         }
-
-    # -- speculation support (scheduler/tpu_batch.py pipelined mode) --------
-    def has_pod(self, uid: str) -> bool:
-        """Whether ``uid`` already contributes to the resident planes."""
-        return uid in self._pods
-
-    def is_noop_upsert(self, pod: api.Pod) -> bool:
-        """True when applying ``pod`` as an upsert would not change the
-        resident planes: same uid already accounted at the same host row.
-        (Pod specs are immutable post-creation — see module docstring — so
-        host identity is the whole delta surface.) The pipelined
-        scheduler's divergence check uses this to classify watch-confirm
-        migrations (assumed -> scheduled re-delivery of a pod it already
-        applied speculatively) as benign."""
-        rec = self._pods.get(pod.metadata.uid)
-        if rec is None:
-            return False
-        return rec.host_idx == self._node_index.get(pod.status.host, self._N)
-
-    def forget_pods(self, uids) -> None:
-        """Exact rollback of speculative upserts: remove each uid's
-        contribution from the resident planes (no-op for absent uids).
-        Only sound for pods that were NOT resident before the speculative
-        apply — the pipelined scheduler refuses to speculate otherwise
-        (see BatchScheduler._speculate)."""
-        for uid in uids:
-            if uid in self._pods:
-                self._remove_pod(uid)
 
     # -- wave encode --------------------------------------------------------
     def encode(self, nodes: Sequence[api.Node],

@@ -181,12 +181,6 @@ class SchedulerConfig:
     # respawns within seconds (docs/design/ha.md). CAS-convergent
     # either way.
     solver_fallback: str = "inprocess"
-    # Speculative double-buffered wave scheduling (kube-scheduler
-    # --pipeline): overlap the encode of wave k+1 with the solve/commit of
-    # wave k. Decisions stay bit-identical to the causal path — the
-    # speculative encode is verified against actual commit outcomes before
-    # wave k+1 ever dispatches (scheduler/tpu_batch.py divergence protocol).
-    pipeline: bool = False
     # Device-mesh solve for the IN-PROCESS path (kube-scheduler --mesh):
     # "auto" shards waves above parallel.mesh.DEFAULT_MESH_MIN_NODES over
     # the attached device mesh when >1 device exists, "on" requires one,
@@ -341,7 +335,7 @@ class ConfigFactory:
                policy: Optional[schedplugins.Policy] = None,
                algorithm_override=None,
                recorder: Optional[EventRecorder] = None,
-               solver_addr: str = "", pipeline: bool = False,
+               solver_addr: str = "",
                mesh: str = "auto", pods_axis: int = 1,
                solver_fallback: str = "inprocess",
                prewarm: bool = False) -> SchedulerConfig:
@@ -396,7 +390,6 @@ class ConfigFactory:
             policy=policy,
             solver_addr=solver_addr,
             solver_fallback=solver_fallback,
-            pipeline=pipeline,
             mesh=mesh,
             pods_axis=pods_axis,
             prewarm=prewarm,

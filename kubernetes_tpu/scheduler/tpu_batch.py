@@ -15,42 +15,6 @@ boundary the reference exposes for alternate schedulers.
 
 Bind conflicts (another scheduler won the CAS) invalidate that pod only; the
 error handler requeues it and the next wave re-solves against fresh state.
-
-**Pipelined mode** (``SchedulerConfig.pipeline`` / ``kube-scheduler
---pipeline``): the causal loop serializes drain -> encode -> solve ->
-commit, so the host sits idle while the device (or the solverd round-trip)
-works and vice versa. The pipelined loop double-buffers:
-
-- wave k's solve runs on a side thread while the loop thread drains wave
-  k+1 (the linger window rides the solve, free);
-- once wave k's decisions exist, its bindings commit on a commit thread
-  while the loop thread encodes wave k+1 against the PREDICTED
-  post-commit state — the incremental encoder's resident planes plus
-  wave k's not-yet-committed placements — and dispatches wave k+1's
-  solve speculatively, so the solve of wave k+1 rides the commit of
-  wave k;
-- when the commit lands, the prediction is verified before anything from
-  wave k+1 may commit: every placed pod must have bound at its chosen
-  host, and the modeler's changelog since the encoder's token must
-  contain exactly those events (watch re-deliveries of already-resident
-  pods are classified benign). Any divergence — a CAS-lost bind, a
-  foreign store delta, a changelog resync — invalidates the speculation:
-  the in-flight speculative solve is discarded unseen, the predicted
-  rows roll back (exact inverse on the resident planes), and the wave
-  re-encodes causally before re-dispatching.
-
-Committed decisions therefore stay bit-identical to the causal path (and
-to the serial oracle): speculation only ever changes WHEN work runs,
-never what state a committed decision was solved against. Steady-state
-wave cost drops from ``drain + encode + solve + commit`` to roughly
-``encode + max(solve, commit + drain)``. Instrumented as the
-``scheduler_pipeline_*`` metric family (speculation hits, invalidations
-by reason, overlapped seconds).
-
-Speculation requires the incremental encoder (delta-maintained planes) and
-the modeler changelog; waves carrying gang members skip speculation (their
-quorum gate needs an authoritative existing-pod list) and encode causally
-— correctness never depends on speculation being available.
 """
 
 from __future__ import annotations
@@ -161,42 +125,6 @@ def _wave_metrics() -> _WaveMetrics:
     return _WaveMetrics._singleton
 
 
-class _PipelineMetrics:
-    """The scheduler_pipeline_* family: speculative double-buffering
-    effectiveness. hits/invalidations partition the speculated waves;
-    overlap_seconds_total is the wall time of host work that ran under a
-    solve or a commit instead of after it."""
-
-    _singleton = None
-
-    def __init__(self):
-        reg = metrics.default_registry()
-        self.waves = reg.counter(
-            "scheduler_pipeline_waves_total",
-            "Waves run by the pipelined loop")
-        self.hits = reg.counter(
-            "scheduler_pipeline_speculation_hits_total",
-            "Speculative encodes verified and dispatched without re-encode")
-        self.invalidations = reg.counter(
-            "scheduler_pipeline_speculation_invalidations_total",
-            "Speculative encodes invalidated before dispatch, by divergence "
-            "reason", label_names=("reason",))
-        self.unspeculated = reg.counter(
-            "scheduler_pipeline_unspeculated_waves_total",
-            "Next waves encoded causally without a speculation attempt "
-            "(gang members present, or no resident delta state yet)")
-        self.overlap = reg.counter(
-            "scheduler_pipeline_overlap_seconds_total",
-            "Wall seconds of drain/encode work overlapped with the solve "
-            "and commit of the preceding wave")
-
-
-def _pipeline_metrics() -> _PipelineMetrics:
-    if _PipelineMetrics._singleton is None:
-        _PipelineMetrics._singleton = _PipelineMetrics()
-    return _PipelineMetrics._singleton
-
-
 class _WaveDecisions(NamedTuple):
     """One wave's solve outcome: per-pod host names (None =
     unschedulable) plus, for pods the solver placed VIA PREEMPTION
@@ -205,8 +133,8 @@ class _WaveDecisions(NamedTuple):
     start of the preempt-to-bind latency window.
 
     ``snap``/``chosen``/``scores`` carry the solved wave's inputs and
-    raw outputs to the loop thread so kube-explain (models/explain.py)
-    can decompose any unschedulable rows against the planes the scan
+    raw outputs to the commit so kube-explain (models/explain.py) can
+    decompose any unschedulable rows against the planes the scan
     consumed — references only, nothing is copied, and they die with
     the wave."""
 
@@ -216,25 +144,6 @@ class _WaveDecisions(NamedTuple):
     snap: object = None     # ClusterSnapshot the solve consumed
     chosen: object = None   # raw [P] node indices (-1 = unschedulable)
     scores: object = None   # raw [P] score channel (preempt encoding)
-
-
-class _SpecResult(NamedTuple):
-    """Outcome of a speculative encode (see BatchScheduler._speculate)."""
-
-    snap: object           # ClusterSnapshot, or None when speculation failed
-    pending: Optional[list]  # ordered wave pods (None when snap is None)
-    applied: bool          # predicted rows were applied to the encoder
-    reason: str            # "" on success, else the failure class
-    encode_s: float
-
-
-class _Inflight(NamedTuple):
-    """Carry between pipelined cycles: the wave whose solve is running on
-    the solve thread right now."""
-
-    fut: object            # Future -> decision host names
-    pending: list          # the wave's ordered pods (snap row order)
-    tctx: object = None    # kube-trace wave context (None = untraced)
 
 
 class BatchScheduler:
@@ -251,7 +160,7 @@ class BatchScheduler:
     def __init__(self, config: SchedulerConfig, factory: ConfigFactory,
                  client, wave_size: int = 1024, wave_linger_s: float = 0.02,
                  solve_fn=None, batch_policy: BatchPolicy = None,
-                 solver=None, pipeline: Optional[bool] = None):
+                 solver=None):
         self.config = config
         self.factory = factory
         self.client = client
@@ -274,10 +183,6 @@ class BatchScheduler:
                 fallback=getattr(config, "solver_fallback",
                                  "inprocess") != "requeue")
         self.solver = solver
-        # speculative double-buffered wave loop (module docstring); None
-        # inherits the config's recorded --pipeline flag
-        self.pipeline = bool(getattr(config, "pipeline", False)
-                             if pipeline is None else pipeline)
         # in-process device-mesh solve (kube-scheduler --mesh): resolved
         # once — None when single-device or off. Waves above the node
         # floor then take parallel.mesh.solve_sharded (its measured
@@ -491,20 +396,18 @@ class BatchScheduler:
 
     def _solve_snap(self, snap, n_pending: int, tctx=None):
         """One wave's solve (in-process or via the shared daemon) ->
-        _WaveDecisions. Thread-safe: runs on the pipelined loop's
-        solve thread; both paths include the gang all-or-nothing post-pass
-        and RemoteSolver falls back in-process when the daemon is
-        absent/busy. ``tctx`` carries the wave's trace across the thread
-        boundary; the span's ambient context is what RemoteSolver ships
-        on the v3 frame so solverd's spans join this trace.
+        _WaveDecisions, on the loop's one thread. Both paths include the
+        gang all-or-nothing post-pass and RemoteSolver falls back
+        in-process when the daemon is absent/busy. ``tctx`` is the wave's
+        trace; the span's ambient context is what RemoteSolver ships on
+        the v3 frame so solverd's spans join this trace.
 
         kube-preempt: a placed pod whose returned score encodes a
         preemption threshold (models/preempt.py score channel) gets its
         victim set materialized here from the incremental encoder's
         per-node registry — the deterministic replay the oracle gate
-        pins. Safe on the solve thread: the encoder is only mutated
-        after this wave's decisions are collected (speculation ordering
-        in _pipelined_cycle)."""
+        pins. The registry is the one this wave was encoded from: the
+        encoder is next written by the following wave's encode."""
         wm = _wave_metrics()
         t0 = time.perf_counter()
         with tracing.phase("wave.solve", wm.solve, parent=tctx,
@@ -545,9 +448,9 @@ class BatchScheduler:
                 else:
                     # the full-encoder path has no resident pod registry
                     # to name victims from: fail those pods back to the
-                    # queue (preemption requires the incremental encoder,
-                    # like speculation; policies it cannot model keep the
-                    # serial no-preemption behavior)
+                    # queue (preemption requires the incremental encoder;
+                    # policies it cannot model keep the serial
+                    # no-preemption behavior)
                     if not getattr(self, "_warned_preempt_encoder", False):
                         self._warned_preempt_encoder = True
                         _log.warning(
@@ -610,11 +513,11 @@ class BatchScheduler:
 
     def _maybe_checkpoint(self, token) -> None:
         """Cadence-gated encoder checkpoint at a clean, token-paired
-        state (delta success, verified speculation hit, or post-full-
-        sync). Every ``checkpoint_every`` waves keeps the replay gap a
-        few thousand events deep — far inside the store changelog window
-        — while the copy-on-write snapshot stays a per-wave rounding
-        error on the loop thread."""
+        state (delta success or post-full-sync). Every
+        ``checkpoint_every`` waves keeps the replay gap a few thousand
+        events deep — far inside the store changelog window — while the
+        copy-on-write snapshot stays a per-wave rounding error on the
+        loop thread."""
         self._ckpt_waves += 1
         if self._ckpt is not None and \
                 self._ckpt_waves < self.checkpoint_every:
@@ -794,11 +697,9 @@ class BatchScheduler:
         pod is unschedulable, the diagnosis layer (rate-limited, loop
         thread only — models/explain.Explainer) renders the k8s-idiom
         per-filter breakdown into the FailedScheduling event, replacing
-        the empty-map FitError line. Runs HERE — after the solve result
-        exists and before this wave's commit is submitted — so it never
-        sits inside the pipelined solve/commit overlap window. A
-        declined diagnosis keeps the legacy message; the error handed to
-        the requeue path is unchanged either way."""
+        the empty-map FitError line. A declined diagnosis keeps the
+        legacy message; the error handed to the requeue path is
+        unchanged either way."""
         c = self.config
         if isinstance(decisions, _WaveDecisions):
             hosts, victims = decisions.hosts, decisions.victims
@@ -832,13 +733,10 @@ class BatchScheduler:
                 placed.append((pod, host, vict))
         return placed
 
-    def _commit_wave(self, placed, assumed: Optional[list] = None,
-                     tctx=None, preempt_t0: Optional[float] = None):
+    def _commit_wave(self, placed, tctx=None,
+                     preempt_t0: Optional[float] = None):
         """Bind the wave's placements, event every outcome, assume the
-        winners. ``assumed`` optionally supplies the pre-built post-bind
-        clones — the pipelined path shares them with the speculative
-        encode so the encoder and the modeler account the IDENTICAL
-        objects. Returns (outcomes, bound): outcomes[i] is None on
+        winners. Returns (outcomes, bound): outcomes[i] is None on
         success, else the bind error (aligned with ``placed``).
 
         kube-preempt: a placed triple carrying victims commits as an
@@ -849,9 +747,9 @@ class BatchScheduler:
         any other delete."""
         with tracing.phase("wave.commit", _wave_metrics().commit,
                            parent=tctx, pods=len(placed)):
-            return self._commit_wave_inner(placed, assumed, preempt_t0)
+            return self._commit_wave_inner(placed, preempt_t0)
 
-    def _commit_wave_inner(self, placed, assumed: Optional[list] = None,
+    def _commit_wave_inner(self, placed,
                            preempt_t0: Optional[float] = None):
         c = self.config
         part = _wave_metrics().part
@@ -914,16 +812,15 @@ class BatchScheduler:
                         outcomes[idx] = e
 
         with tracing.phase("wave.commit.assume", part, "commit.assume"):
-            if assumed is None:
-                # value copy before mutating (the popped pod may be shared);
-                # deep_clone, not copy.deepcopy — at churn rates the stdlib
-                # deepcopy was the scheduler's single largest CPU sink
-                assumed = []
-                for pod, host, _vict in placed:
-                    cl = deep_clone(pod)
-                    cl.spec.host = host
-                    cl.status.host = host
-                    assumed.append(cl)
+            # value copy before mutating (the popped pod may be shared);
+            # deep_clone, not copy.deepcopy — at churn rates the stdlib
+            # deepcopy was the scheduler's single largest CPU sink
+            assumed = []
+            for pod, host, _vict in placed:
+                cl = deep_clone(pod)
+                cl.spec.host = host
+                cl.status.host = host
+                assumed.append(cl)
 
             # preemption outcome accounting (scheduler_preemption_* family)
             pmx = None
@@ -986,8 +883,8 @@ class BatchScheduler:
         return outcomes, bound
 
     def schedule_wave(self, timeout: Optional[float] = None) -> int:
-        """Drain, solve, commit — the causal wave. Returns the number of
-        pods bound."""
+        """Drain, solve, commit — one wave. Returns the number of pods
+        bound."""
         c = self.config
         pods = self._drain_wave(timeout)
         # one trace per wave: a bare root context (no span of its own) the
@@ -1029,278 +926,6 @@ class BatchScheduler:
             if isinstance(decisions, _WaveDecisions) else None)
         return bound
 
-    # -- pipelined wave loop ------------------------------------------------
-    def _can_pipeline(self) -> bool:
-        return (self._encoder is not None and self._using_default_solve
-                and hasattr(self.config.modeler, "delta")
-                and hasattr(self.config.modeler, "token"))
-
-    def _pipeline_unavailable_reason(self) -> str:
-        if self._encoder is None:
-            return "policy needs the order-dependent full encoder"
-        if not self._using_default_solve:
-            return "custom solve_fn bypasses the snapshot seam"
-        return "modeler lacks the token/delta changelog"
-
-    def _speculate(self, pods: List[api.Pod],
-                   predicted: List[api.Pod], tctx=None) -> _SpecResult:
-        """Encode wave k+1 against the PREDICTED post-commit state: the
-        encoder's resident planes plus wave k's not-yet-committed
-        placements. Runs on the loop thread while the commit thread binds
-        wave k — the commit path never touches the encoder, and this
-        never reads the modeler (a half-committed view would be
-        unverifiable)."""
-        t0 = time.perf_counter()
-        enc = self._encoder
-        wm = _wave_metrics()
-        if any(enc.has_pod(p.metadata.uid) for p in predicted):
-            # a predicted pod is already resident (e.g. a stale requeue of
-            # a pod another scheduler bound — its CAS will lose): applying
-            # would re-account the row and rollback could not restore it
-            return _SpecResult(None, None, False, "resident_conflict",
-                               time.perf_counter() - t0)
-        try:
-            nodes = self.config.minion_lister.list().items
-            services = self.factory.service_store.list()
-        except Exception:
-            return _SpecResult(None, None, False, "lister_error",
-                               time.perf_counter() - t0)
-        pending = gang.order_wave(pods)  # identity: wave is gang-free
-        with tracing.phase("wave.encode", wm.encode, parent=tctx,
-                           pods=len(pending), speculative=True) as ph:
-            snap = enc.encode_delta(nodes, predicted, [], pending, services)
-            if snap is None:
-                ph.cancel()   # declined: the causal encode that follows counts
-        if snap is None:
-            # encode_delta declines before applying anything when the
-            # node/service planes changed, but an overflow is detected
-            # after the apply — has_pod says which happened
-            applied = any(enc.has_pod(p.metadata.uid) for p in predicted)
-            return _SpecResult(None, None, applied, "encoder_fallback",
-                               time.perf_counter() - t0)
-        return _SpecResult(snap, pending, True, "", time.perf_counter() - t0)
-
-    def _verify_speculation(self, spec: _SpecResult, predicted, outcomes):
-        """The divergence check: compare the prediction (every placed pod
-        bound at its chosen host, nothing else changed) against what
-        actually happened. Returns (reason, token, failed_uids):
-
-        - ``""``: the prediction held exactly — the speculative encode
-          (and any solve already in flight on it) is valid;
-        - ``"bind_failed"``: the only divergence is CAS-lost/failed binds
-          (or a speculative overflow) — O(changed) repair is possible;
-        - ``"store_delta"`` / ``"resync"``: foreign interference (another
-          scheduler's pod landed, a pod was removed, the changelog
-          window was exceeded) — full causal re-encode required.
-        """
-        failed_uids = {cl.metadata.uid for cl, err in zip(predicted, outcomes)
-                       if err is not None}
-        ok_uids = {cl.metadata.uid for cl in predicted} - failed_uids
-        d = self.config.modeler.delta(self._delta_token)
-        if d is None:
-            return "resync", None, failed_uids
-        upserted, removed, token = d
-        by_uid = {cl.metadata.uid: cl.status.host for cl in predicted}
-        matched = set()
-        for p in upserted:
-            uid = p.metadata.uid
-            if uid in ok_uids and by_uid.get(uid) == p.status.host:
-                matched.add(uid)
-                continue
-            if self._encoder.is_noop_upsert(p):
-                continue  # watch-confirm re-delivery of a resident pod
-            return "store_delta", None, failed_uids
-        if removed or matched != ok_uids:
-            # a removal touches node capacity; a missing assume event
-            # means the changelog raced — both are foreign interference
-            return "store_delta", None, failed_uids
-        if failed_uids or spec.snap is None:
-            return "bind_failed", token, failed_uids
-        return "", token, failed_uids
-
-    def _dispatch_causal(self, pods, solve_pool,
-                         pm: _PipelineMetrics, tctx=None
-                         ) -> Optional[_Inflight]:
-        """Prepare + causally encode + dispatch a wave (bootstrap, and the
-        restart path after a divergence or an unspeculated wave).
-        ``tctx`` reuses a trace the caller already opened for these pods
-        (the pipelined drain leg); None starts a fresh wave trace."""
-        if not pods:
-            return None
-        if tctx is None:
-            tctx = tracing.new_ctx()
-        with tracing.phase("wave.prepare", _wave_metrics().part, "prepare",
-                           parent=tctx):
-            prep = self._prepare_wave(pods)
-        if prep is None:
-            return None
-        pending, nodes, services, get_existing = prep
-        snap = self._encode_wave(nodes, pending, services, get_existing,
-                                 tctx=tctx)
-        pm.waves.inc()
-        return _Inflight(solve_pool.submit(self._solve_snap, snap,
-                                           len(pending), tctx),
-                         pending, tctx)
-
-    def _pipelined_cycle(self, inflight: Optional[_Inflight], solve_pool,
-                         commit_pool, pm: _PipelineMetrics
-                         ) -> Optional[_Inflight]:
-        """One double-buffered wave. With wave k's solve in flight:
-
-        1. drain wave k+1 (the linger rides the solve);
-        2. collect wave k's decisions;
-        3. start wave k's commit on the commit thread;
-        4. speculatively encode wave k+1 against the predicted post-commit
-           planes and dispatch its solve — both riding wave k's commit;
-        5. when the commit lands, verify the prediction: a hit keeps the
-           in-flight wave k+1 solve, a divergence discards it, rolls the
-           predicted rows back, and re-encodes before re-dispatching.
-
-        Committed decisions are bit-identical to the causal loop:
-        speculation changes when work runs, never what state it sees."""
-        c = self.config
-        if inflight is None:
-            # bootstrap / restart: nothing in flight, encode causally.
-            # An empty queue is a normal idle tick, NOT an error — and it
-            # must be distinguished here, not by exception type in the
-            # loop: on py3.10+ socket.timeout IS TimeoutError, so a
-            # network timeout escaping a cycle must never be mistaken
-            # for an empty drain (the stale in-flight wave would then be
-            # committed twice by the next iteration).
-            try:
-                pods = self._drain_wave(timeout=0.2)
-            except TimeoutError:
-                return None
-            return self._dispatch_causal(pods, solve_pool, pm,
-                                         tctx=self._wave_ctx(pods))
-        pending = inflight.pending
-        # overlap 1: drain wave k+1 while wave k solves
-        t0 = time.perf_counter()
-        next_pods: List[api.Pod] = []
-        try:
-            next_pods = self._drain_wave(timeout=self.wave_linger_s)
-        except TimeoutError:
-            # the rest of this cycle is work, not waiting for a pod: the
-            # next drain starts its wait anew
-            self._wait = None
-        drain_s = time.perf_counter() - t0
-        # wave k+1's trace opens at its drain; every later leg (spec
-        # encode, solve, commit — or the causal re-encode on divergence)
-        # attaches to this context
-        next_tctx = self._wave_ctx(next_pods)
-        try:
-            decisions = inflight.fut.result()
-        except Exception as e:
-            for pod in pending:
-                self._record(pod, "FailedScheduling",
-                             "Error scheduling wave: %s", e)
-                c.error(pod, e)
-            return self._dispatch_causal(next_pods, solve_pool, pm,
-                                         tctx=next_tctx)
-        solve_s = time.perf_counter() - t0
-        pm.overlap.inc(by=min(drain_s, solve_s))
-        placed = self._split_decisions(pending, decisions)
-        if not placed:
-            return self._dispatch_causal(next_pods, solve_pool, pm,
-                                         tctx=next_tctx)
-        # the predicted post-bind clones: shared verbatim between the
-        # speculative encode and assume_pod, so a verified hit leaves the
-        # encoder accounting the very objects the modeler holds
-        predicted = []
-        for pod, host, _vict in placed:
-            cl = deep_clone(pod)
-            cl.spec.host = host
-            cl.status.host = host
-            predicted.append(cl)
-        # wave k's bindings commit on the commit thread; the speculative
-        # encode (overlap 2) and wave k+1's solve (overlap 3) ride it
-        t_c0 = time.perf_counter()
-        commit_fut = commit_pool.submit(
-            self._commit_wave, placed, predicted, inflight.tctx,
-            decisions.t0 if isinstance(decisions, _WaveDecisions) else None)
-        # kube-preempt: a wave that evicts changes the cluster beyond its
-        # own binds (victim deletions land in the changelog), so the
-        # predicted post-commit state would always verify as divergent —
-        # don't speculate on top of it
-        wave_evicts = any(vict for _pod, _host, vict in placed)
-        spec = None
-        next_fut = None
-        if next_pods and self._delta_token is not None and \
-                not wave_evicts and \
-                not any(gang.gang_key(p) is not None for p in next_pods):
-            spec = self._speculate(next_pods, predicted, tctx=next_tctx)
-            if spec.snap is not None:
-                next_fut = solve_pool.submit(self._solve_snap, spec.snap,
-                                             len(spec.pending), next_tctx)
-        elif next_pods:
-            pm.unspeculated.inc()
-        try:
-            outcomes, _bound = commit_fut.result()
-        except Exception as e:
-            # infra fault mid-commit: roll the speculation back and force
-            # a full resync — the encoder must not keep unverified rows.
-            # The already-drained next wave would otherwise be stranded
-            # (popped from the FIFO, never solved): hand it to the error
-            # handler, which re-fetches and requeues still-unbound pods.
-            if spec is not None and spec.applied:
-                self._encoder.forget_pods(
-                    [cl.metadata.uid for cl in predicted])
-            self._delta_token = None
-            for pod in next_pods:
-                self._record(pod, "FailedScheduling",
-                             "Error scheduling wave: %s", e)
-                c.error(pod, e)
-            raise
-        commit_s = time.perf_counter() - t_c0
-        if spec is None:
-            return self._dispatch_causal(next_pods, solve_pool, pm,
-                                         tctx=next_tctx)
-        pm.overlap.inc(by=min(commit_s, spec.encode_s))
-        reason, token, failed_uids = self._verify_speculation(
-            spec, predicted, outcomes)
-        if not reason:
-            # prediction held: wave k+1 is already solving on the exact
-            # state the causal path would have encoded — a clean,
-            # token-paired state, so it is also a checkpoint site
-            self._delta_token = token
-            self._maybe_checkpoint(token)
-            pm.hits.inc()
-            pm.waves.inc()
-            return _Inflight(next_fut, spec.pending, next_tctx)
-        # divergence: the in-flight speculative solve (if any) is
-        # discarded — its results never commit
-        if reason == "bind_failed" and spec.applied:
-            # only this wave's own CAS losers (and/or an overflow) diverged:
-            # roll back the losing rows and rebuild over corrected planes
-            self._encoder.forget_pods(failed_uids)
-            self._delta_token = token
-            pm.invalidations.inc("bind_failed" if failed_uids
-                                 else spec.reason or "encoder_fallback")
-            pending2 = spec.pending if spec.pending is not None \
-                else gang.order_wave(next_pods)
-            try:
-                nodes = c.minion_lister.list().items
-                services = self.factory.service_store.list()
-                snap2 = self._encoder.encode_delta(nodes, [], [], pending2,
-                                                   services)
-            except Exception:
-                snap2 = None
-            if snap2 is not None:
-                pm.waves.inc()
-                return _Inflight(solve_pool.submit(self._solve_snap, snap2,
-                                                   len(pending2), next_tctx),
-                                 pending2, next_tctx)
-            return self._dispatch_causal(next_pods, solve_pool, pm,
-                                         tctx=next_tctx)
-        # foreign interference: exact rollback of every speculative row;
-        # the un-advanced token re-delivers the actual events (including
-        # this wave's real binds) to the causal encode below
-        if spec.applied:
-            self._encoder.forget_pods([cl.metadata.uid for cl in predicted])
-        pm.invalidations.inc(reason or spec.reason or "speculation_failed")
-        return self._dispatch_causal(next_pods, solve_pool, pm,
-                                     tctx=next_tctx)
-
     # -- loop ---------------------------------------------------------------
     def run(self) -> "BatchScheduler":
         if self._prewarm is not None:
@@ -1325,82 +950,25 @@ class BatchScheduler:
             self._prewarm.stop()
 
     def _loop(self) -> None:
-        tracing.role("wave_loop")
-        try:
-            if self.pipeline:
-                if self._can_pipeline():
-                    return self._loop_pipelined()
-                _log.warning("pipeline mode unavailable (%s); falling back "
-                             "to the causal wave loop",
-                             self._pipeline_unavailable_reason())
-            self._loop_causal()
-        finally:
-            tracing.role_end()
-
-    def _loop_causal(self) -> None:
         # per-pod and per-wave failures are evented + requeued inside
         # schedule_wave; an exception escaping to here is an infrastructure
         # fault that must not spin silently
         errs = metrics.default_registry().counter(
             "scheduler_wave_loop_errors_total",
             "exceptions escaping the tpu-batch wave loop")
-        while not self._stop.is_set():
-            try:
-                self.schedule_wave(timeout=0.2)
-            except TimeoutError:
-                continue
-            except Exception:
-                errs.inc()
-                _log.exception("wave loop error (backing off 10ms)")
-                time.sleep(0.01)
-
-    def _loop_pipelined(self) -> None:
-        import concurrent.futures as cf
-        errs = metrics.default_registry().counter(
-            "scheduler_wave_loop_errors_total",
-            "exceptions escaping the tpu-batch wave loop")
-        pm = _pipeline_metrics()
-        # the loop's two side threads are the wave loop too
-        solve_pool = cf.ThreadPoolExecutor(
-            max_workers=1, thread_name_prefix="tpu-batch-solve",
-            initializer=tracing.role, initargs=("wave_loop",))
-        commit_pool = cf.ThreadPoolExecutor(
-            max_workers=1, thread_name_prefix="tpu-batch-commit",
-            initializer=tracing.role, initargs=("wave_loop",))
-        inflight: Optional[_Inflight] = None
+        tracing.role("wave_loop")
         try:
             while not self._stop.is_set():
-                prev = inflight
                 try:
-                    inflight = self._pipelined_cycle(inflight, solve_pool,
-                                                     commit_pool, pm)
-                except Exception as e:
-                    # includes TimeoutError: the empty-queue drain timeout
-                    # is handled INSIDE the cycle (returns None), so any
-                    # TimeoutError here is a real fault (socket.timeout is
-                    # TimeoutError on py3.10+) and must reset state like
-                    # every other error — continuing with the consumed
-                    # in-flight wave would commit it twice
+                    self.schedule_wave(timeout=0.2)
+                except TimeoutError:
+                    continue
+                except Exception:
                     errs.inc()
-                    _log.exception(
-                        "pipelined wave loop error (backing off 10ms)")
-                    # heal: drop the speculation cursor (the next encode
-                    # full-resyncs, clearing any unverified rows) and hand
-                    # the in-flight wave's pods to the error handler — an
-                    # already-bound pod re-fetches as scheduled and is not
-                    # requeued, so this can never double-schedule
-                    self._delta_token = None
-                    inflight = None
-                    if prev is not None:
-                        for pod in prev.pending:
-                            try:
-                                self.config.error(pod, e)
-                            except Exception:
-                                pass
+                    _log.exception("wave loop error (backing off 10ms)")
                     time.sleep(0.01)
         finally:
-            solve_pool.shutdown(wait=False)
-            commit_pool.shutdown(wait=False)
+            tracing.role_end()
 
     def _record(self, pod, reason, fmt, *args):
         if self.config.recorder is not None:

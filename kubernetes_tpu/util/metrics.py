@@ -657,7 +657,7 @@ class ExplainMetrics:
             "scheduler_explain_skipped_total",
             "Waves with unschedulable pods whose diagnosis was "
             "declined, by reason (rate_limited / unsupported / "
-            "hot_path / error)", ("reason",))
+            "error)", ("reason",))
 
 
 def explain_metrics() -> ExplainMetrics:

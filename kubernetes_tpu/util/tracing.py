@@ -187,10 +187,10 @@ def current() -> Optional[Tuple[str, str]]:
 
 
 def new_ctx() -> Optional[Tuple[str, str]]:
-    """A fresh root context for a trace whose spans will be recorded
-    from several threads (the pipelined wave loop): no span is recorded
-    for the root itself — stages attach to it with ``parent=ctx`` and
-    the merged view groups them by trace id."""
+    """A fresh root context for a trace whose spans share no enclosing
+    span (a wave's phases): no span is recorded for the root itself —
+    stages attach to it with ``parent=ctx`` and the merged view groups
+    them by trace id."""
     if not _on:
         return None
     return (_new_trace_id(), _new_span_id())

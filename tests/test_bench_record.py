@@ -33,7 +33,7 @@ def _load_churn_mp():
 def _fat_record():
     cfgs = {}
     for tag in ("north_star", "basic", "affinity", "binpack3", "gang",
-                "churn", "pipeline"):
+                "churn"):
         cfgs[tag] = {
             "pods": 10_000, "nodes": 5_000, "value": 48867.1,
             "unit": "pods/s", "wave_s": 0.2046, "wave_s_p50": 0.2046,
@@ -45,9 +45,6 @@ def _fat_record():
             "serial_oracle_pods_per_s": 33.1,
             "router_host_s": 1.43, "router_device_s": 0.13,
             "router_cal_s": 21.4, "router_cold_s": 4.61,
-            "pipeline_speedup": 1.535, "causal_pods_per_s": 48867.1,
-            "speculation_hits": 7, "speculation_invalidations": 0,
-            "divergent_decisions": 0,
         }
     return {
         "metric": "pods_scheduled_per_sec_10000pods_5000nodes",

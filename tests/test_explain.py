@@ -9,17 +9,16 @@ The contract under test (models/explain.py attribution contract):
 - the FailedScheduling event carries the k8s-idiom top-k line
   (``0/N nodes available: ...``) end-to-end through a live
   BatchScheduler, with zero new plumbing past the recorder;
-- diagnosis stays off the hot path: rate-limited, refused on the
-  pipelined loop's solve/commit threads, never invoked when every pod
-  binds, and declined waves still count every pod in the
+- diagnosis stays off the hot path: rate-limited, never invoked when
+  every pod binds, and declined waves still count every pod in the
   unschedulable metric families (reason ``unexplained``);
 - the ``failed_scheduling_burst`` SLO rule fires and resolves on the
   unschedulable-rate curve.
 """
 
 import random
-import threading
 import time
+import types
 
 from kubernetes_tpu.api import types as api
 from kubernetes_tpu.api.quantity import Quantity
@@ -273,21 +272,27 @@ class TestOffHotPathGuard:
         assert mx.reasons.value(explain.REASON_UNEXPLAINED) - unexp0 == 1
         assert mx.invocations.value() - inv0 == 1
 
-    def test_refused_on_solve_and_commit_threads(self):
+    def test_rate_limit_refills_with_time(self, monkeypatch):
+        # the bucket is the only gate: a declined wave is admitted again
+        # once 1/qps seconds have passed, and never holds more than burst
+        now = [1000.0]
+        monkeypatch.setattr(explain, "time", types.SimpleNamespace(
+            monotonic=lambda: now[0], thread_time=time.thread_time))
         mx = metrics.explain_metrics()
-        ex = explain.Explainer()
+        ex = explain.Explainer(qps=2.0, burst=2)
         snap, chosen, scores = _solved_wave()
-        skip0 = mx.skipped.value("hot_path")
-        out = {}
-
-        def run():
-            out["msgs"] = ex.diagnose_wave(snap, chosen, scores)
-
-        t = threading.Thread(target=run, name="tpu-batch-solve_0")
-        t.start()
-        t.join()
-        assert out["msgs"] == {}
-        assert mx.skipped.value("hot_path") - skip0 == 1
+        skip0 = mx.skipped.value("rate_limited")
+        assert ex.diagnose_wave(snap, chosen, scores)
+        assert ex.diagnose_wave(snap, chosen, scores)
+        assert ex.diagnose_wave(snap, chosen, scores) == {}
+        now[0] += 0.5                       # one token at 2/s
+        assert ex.diagnose_wave(snap, chosen, scores)
+        assert ex.diagnose_wave(snap, chosen, scores) == {}
+        now[0] += 3600.0                    # refills to burst, not past it
+        assert ex.diagnose_wave(snap, chosen, scores)
+        assert ex.diagnose_wave(snap, chosen, scores)
+        assert ex.diagnose_wave(snap, chosen, scores) == {}
+        assert mx.skipped.value("rate_limited") - skip0 == 3
 
     def test_schedulable_wave_is_free(self):
         # no unschedulable rows: diagnose_wave returns without touching
@@ -355,7 +360,7 @@ def _wait(pred, timeout=15.0):
 
 
 class TestSchedulerEndToEnd:
-    def _run(self, pipeline):
+    def test_event_carries_breakdown(self):
         m = Master()
         client = Client(InProcessTransport(m))
         client.nodes().create(mknode(0, cpu="1"))
@@ -365,15 +370,7 @@ class TestSchedulerEndToEnd:
         factory.backoff = PodBackoff(initial=0.05, max_duration=0.2)
         config = factory.create(recorder=recorder)
         sched = BatchScheduler(config, factory, client, wave_size=8,
-                               wave_linger_s=0.05, pipeline=pipeline)
-        threads = []
-        orig = sched._explainer.diagnose_wave
-
-        def spy(*a, **kw):
-            threads.append(threading.current_thread().name)
-            return orig(*a, **kw)
-
-        sched._explainer.diagnose_wave = spy
+                               wave_linger_s=0.05)
         sched.run()
         try:
             time.sleep(0.3)
@@ -395,17 +392,6 @@ class TestSchedulerEndToEnd:
         from kubernetes_tpu.kubectl.describe import describe
         text = describe(client, "pods", "default", "wont-fit")
         assert "0/1 nodes available: 1 Insufficient cpu" in text, text
-        # off-hot-path: diagnosis only ever ran on the wave loop thread,
-        # never the pipelined solve/commit workers
-        assert threads and all(
-            not t.startswith(("tpu-batch-solve", "tpu-batch-commit"))
-            for t in threads), threads
-
-    def test_causal_event_carries_breakdown(self):
-        self._run(pipeline=False)
-
-    def test_pipelined_event_carries_breakdown_off_hot_path(self):
-        self._run(pipeline=True)
 
 
 def _ns(s):

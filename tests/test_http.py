@@ -759,13 +759,23 @@ class TestWatchFanout:
             # instead of queueing without bound. Watcher.send runs
             # synchronously in the create path, so by the time these
             # requests return the resync has already been counted.
+            # The fast watcher is held to "fast" by the test itself: each
+            # create's frame is read before the next create is sent, so
+            # its queue never holds more than one event however slowly a
+            # loaded host runs its writer thread (40 creates back to back
+            # can overrun a bound of 8 on ANY watcher — that is the bound
+            # working, not a fault).
+            fast_frames = []
             for i in range(40):
                 client.pods().create(make_pod(f"lag-{i:03d}"))
+                fast_frames.append(fast.read_frame(timeout=30))
+            # exactly one watcher was dropped: the parked one
             assert metrics_pkg.default_registry().counter(
-                "watch_lag_resyncs_total").total() > resyncs0
+                "watch_lag_resyncs_total").total() == resyncs0 + 1
             # fast watcher: lossless, streaming the whole time
-            fast_frames = [fast.read_frame(timeout=30) for _ in range(40)]
-            assert all(f is not None for f in fast_frames)
+            names = [json.loads(f)["object"]["metadata"]["name"]
+                     for f in fast_frames]
+            assert names == [f"lag-{i:03d}" for i in range(40)]
             # open the gate: the slow writer wakes, finds the cleared
             # queue, and delivers exactly ERROR + end-of-stream
             chaos.release_gate("apiserver.watch.write.lagger")
@@ -779,7 +789,7 @@ class TestWatchFanout:
             assert last["type"] == "ERROR"
             assert last["object"]["code"] == 410
             assert last["object"]["reason"] == "Expired"
-            assert srv.metric_watch_lag_drops.total() >= 1
+            assert srv.metric_watch_lag_drops.total() == 1
             # the 410 ended the stream cleanly -> a client re-lists and
             # re-watches (the Reflector contract) and sees current state
             assert len(client.pods().list().items) == 40

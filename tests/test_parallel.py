@@ -168,7 +168,7 @@ def test_sharded_at_partitioning_scale():
 # --------------------------------------------------------------------------
 # MeshExecutor: the daemon's device-resident mesh dispatch
 # (solver/mesh_exec.py) — delta-wire onto sharded planes, donation
-# safety, and pipeline-speculation-through-mesh parity.
+# safety, and wave-loop-through-mesh parity.
 # --------------------------------------------------------------------------
 
 from kubernetes_tpu.models.incremental import IncrementalEncoder  # noqa: E402
@@ -385,12 +385,12 @@ class TestMeshExecutorDirect:
         assert me2.parity_checks == 0  # calibration hit: no probe
 
 
-def test_pipeline_speculation_through_mesh_parity(monkeypatch):
-    """--pipeline + --mesh together: the pipelined scheduler whose waves
-    solve through the sharded program must commit EXACTLY the placements
-    of the causal single-device run (speculative encodes, divergence
-    verification, and all). The node floor is lowered so the toy backlog
-    takes the mesh path for real."""
+def test_wave_loop_through_mesh_parity(monkeypatch):
+    """--mesh on against --mesh off: the wave loop whose waves solve
+    through the sharded program (node planes resident on the mesh,
+    patched between waves) must commit EXACTLY the placements of the
+    single-device run. The node floor is lowered and the kernel's domain
+    emptied so the toy backlog takes the mesh path for real (counted)."""
     import kubernetes_tpu.parallel.mesh as pm
     from kubernetes_tpu.apiserver.master import Master
     from kubernetes_tpu.client.client import Client, InProcessTransport
@@ -398,8 +398,12 @@ def test_pipeline_speculation_through_mesh_parity(monkeypatch):
     from kubernetes_tpu.scheduler.tpu_batch import BatchScheduler
 
     monkeypatch.setattr(pm, "DEFAULT_MESH_MIN_NODES", 1)
+    # a wave inside the Pallas kernel's domain takes the one-device arm
+    # whatever the mesh (batch_solver._takes_mesh): put these outside it
+    from kubernetes_tpu.ops import pallas_solver
+    monkeypatch.setattr(pallas_solver, "eligible", lambda *a, **kw: False)
 
-    def run_stack(pipeline, mesh, n_nodes=10, n_pods=192, wave=64):
+    def run_stack(mesh, n_nodes=10, n_pods=192, wave=64):
         m = Master()
         client = Client(InProcessTransport(m))
         for i in range(n_nodes):
@@ -418,7 +422,7 @@ def test_pipeline_speculation_through_mesh_parity(monkeypatch):
                         "cpu": Quantity(f"{100 + (i % 8) * 100}m"),
                         "memory": Quantity(f"{128 + (i % 4) * 64}Mi")}))])))
         factory = ConfigFactory(client, node_poll_period=1.0)
-        config = factory.create(pipeline=pipeline, mesh=mesh)
+        config = factory.create(mesh=mesh)
         import time as _time
         deadline = _time.monotonic() + 30.0
         while _time.monotonic() < deadline:
@@ -430,8 +434,7 @@ def test_pipeline_speculation_through_mesh_parity(monkeypatch):
             pytest.fail("reflectors never synced the backlog")
         sched = BatchScheduler(config, factory, client, wave_size=wave,
                                wave_linger_s=0.02)
-        if mesh == "on":
-            assert sched._mesh is not None
+        assert (sched._mesh is not None) == (mesh == "on")
         sched.run()
         try:
             deadline = _time.monotonic() + 60.0
@@ -449,6 +452,14 @@ def test_pipeline_speculation_through_mesh_parity(monkeypatch):
             sched.stop()
             factory.stop()
 
-    causal = run_stack(pipeline=False, mesh="off")
-    piped_mesh = run_stack(pipeline=True, mesh="on")
-    assert piped_mesh == causal
+    from kubernetes_tpu.models.batch_solver import wave_programs
+
+    def sharded_waves():
+        return sum(v for (program, _platform), v
+                   in wave_programs().by_label().items()
+                   if program == "scan-sharded")
+
+    before = sharded_waves()
+    on = run_stack(mesh="on")
+    assert sharded_waves() - before >= 3     # 192 pods in waves of 64
+    assert on == run_stack(mesh="off")

@@ -272,17 +272,23 @@ class TestIncrementalEvictPlanes:
         assert np.array_equal(want_cap, snap.evict_cap)
         assert np.array_equal(want_cnt, snap.evict_cnt)
 
-    def test_forget_pods_rolls_evict_planes_back_exactly(self):
+    def test_a_pod_gone_from_existing_rolls_evict_planes_back_exactly(self):
+        # the full diff-walk: a pod that left ``existing_pods`` is removed
+        # by encode(), and the evict planes equal a fresh encoder's
         nodes = [mknode(i) for i in range(2)]
         existing = [mkpod("e-0", host="n000", prio=10)]
         enc = IncrementalEncoder()
         snap0 = enc.encode(nodes, existing, [mkpod("p", prio=100)])
-        spec = mkpod("spec", host="n001", prio=20)
-        enc.encode_delta(nodes, [spec], [], [mkpod("p2", prio=100)])
-        enc.forget_pods([spec.metadata.uid])
-        snap2 = enc.encode_delta(nodes, [], [], [mkpod("p3", prio=100)])
-        assert np.array_equal(snap0.evict_cnt, snap2.evict_cnt)
-        assert np.array_equal(snap0.evict_cap, snap2.evict_cap)
+        gone = mkpod("gone", host="n001", prio=20)
+        enc.encode_delta(nodes, [gone], [], [mkpod("p2", prio=100)])
+        assert gone.metadata.uid in enc._pods
+        snap2 = enc.encode(nodes, existing, [mkpod("p3", prio=100)])
+        assert gone.metadata.uid not in enc._pods
+        fresh = IncrementalEncoder().encode(nodes, existing,
+                                            [mkpod("p3", prio=100)])
+        for snap in (snap0, fresh):
+            assert np.array_equal(snap.evict_cnt, snap2.evict_cnt)
+            assert np.array_equal(snap.evict_cap, snap2.evict_cap)
 
 
 class TestAtomicEvictBind:

@@ -323,8 +323,8 @@ def test_the_routers_host_route_leaves_the_device_planes_alone(monkeypatch):
 # -- the encoder's side: what it touched, by sequence ------------------------
 
 def test_touched_rows_are_asked_for_by_sequence_not_by_build():
-    """A snapshot that nobody applied (the pipelined loop's discarded
-    speculation) loses no row: the consumer asks for everything since the
+    """A snapshot that nobody applied (a solve that raised, a wave dropped
+    at the gate) loses no row: the consumer asks for everything since the
     sequence of the snapshot it applied last."""
     c = _settled(None)
     pending = c.pods(4)

@@ -592,7 +592,7 @@ def test_default_churn_rules_include_slipstream():
     assert "encode_resync_full_zero" in names
 
 
-# -- live pipelined e2e ------------------------------------------------------
+# -- live e2e ----------------------------------------------------------------
 
 
 N_NODES = 12
@@ -618,8 +618,8 @@ def mk_cluster_pod(i):
                 "memory": Quantity(f"{128 + (i % 4) * 64}Mi")}))]))
 
 
-def test_pipelined_e2e_mid_run_resync_zero_full(monkeypatch):
-    """Live stack, pipelined loop, KTPU_DEBUG replay gate armed: a
+def test_e2e_mid_run_resync_zero_full(monkeypatch):
+    """Live stack, the wave loop, KTPU_DEBUG replay gate armed: a
     mid-run resync (the delta cursor's journal reads fail until a replay
     lands, as a watch-window loss would) drains the full backlog with
     ZERO full re-encodes — every resync replays the journal."""
@@ -632,7 +632,7 @@ def test_pipelined_e2e_mid_run_resync_zero_full(monkeypatch):
     for i in range(N_PODS):
         client.pods().create(mk_cluster_pod(i))
     factory = ConfigFactory(client, node_poll_period=1.0)
-    config = factory.create(pipeline=True)
+    config = factory.create()
     deadline = time.monotonic() + 30.0
     while time.monotonic() < deadline:
         if len(factory.pod_queue.list()) >= N_PODS and \
