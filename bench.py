@@ -780,7 +780,7 @@ def run_churn_config(tag, n_nodes, n_pods, rate_pods_per_s, wave_size=1024,
             metadata=api.ObjectMeta(name=f"node-{i:05d}"),
             spec=api.NodeSpec(capacity={"cpu": Quantity("64"),
                                         "memory": Quantity("256Gi")})))
-    factory = ConfigFactory(client, node_poll_period=0.5)
+    factory = ConfigFactory(client)
     config = factory.create(solver_addr=solver_addr)
     sched = BatchScheduler(config, factory, client, wave_size=wave_size,
                            wave_linger_s=0.1).run()

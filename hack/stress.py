@@ -57,7 +57,7 @@ def main() -> int:
 
     cluster = Cluster(ClusterConfig(
         num_nodes=3, node_cpu="64", node_memory="256Gi",
-        rc_sync_period=0.1, kubelet_resync=0.1, node_poll_period=0.1,
+        rc_sync_period=0.1, kubelet_resync=0.1,
         batch_scheduler=args.batch)).start()
     client = cluster.client
     store = cluster.master.store

@@ -4,7 +4,6 @@
 - ``FIFO``: Store-shaped producer/consumer queue with blocking Pop (fifo.go)
 - ``Reflector``: list+watch a resource into a Store, resuming from
   resourceVersion and relisting when the watch expires (reflector.go:43-91)
-- ``Poller``: periodic list -> Store.replace (poller.go)
 - ``ListWatch``: the pluggable list/watch source (listwatch.go)
 - Typed listers over a Store (listers.go)
 
@@ -28,7 +27,7 @@ from kubernetes_tpu.util import tracing
 from kubernetes_tpu.util.retry import Backoff
 
 __all__ = ["meta_namespace_key_func", "Store", "FIFO", "ListWatch", "Reflector",
-           "Poller", "StorePodLister", "StoreNodeLister", "StoreServiceLister"]
+           "StorePodLister", "StoreNodeLister", "StoreServiceLister"]
 
 
 def meta_namespace_key_func(obj: Any) -> str:
@@ -284,15 +283,6 @@ class ListWatch:
         self.watch_fn = watch_fn
 
 
-def _join_thread(t: Optional[threading.Thread],
-                 timeout: Optional[float]) -> bool:
-    """True once the thread is down (or was never started)."""
-    if t is None:
-        return True
-    t.join(timeout)
-    return not t.is_alive()
-
-
 class Reflector:
     """Mirrors a resource into a Store via list+watch (ref: reflector.go:43-91).
 
@@ -318,6 +308,7 @@ class Reflector:
         # kube-slipstream: streams re-opened at the last seen rv instead
         # of relisting (visible in tests and the debug narrative)
         self.watch_resumes = 0
+        self._listed = threading.Event()
 
     def run(self) -> "Reflector":
         self._thread = threading.Thread(target=self._run_loop, daemon=True, name=self.name)
@@ -327,12 +318,25 @@ class Reflector:
     def stop(self) -> None:
         self._stop.set()
 
+    def wait_listed(self, timeout: Optional[float] = None) -> bool:
+        """Wait for the first LIST after run(): True once it has landed in
+        the store, or failed (the run loop is then backing off towards its
+        next try), or the reflector was stopped before either. A caller
+        that must not hand out an empty store waits here (the scheduler's
+        node source, as the reference's poller listed once before it
+        returned)."""
+        return self._listed.wait(timeout)
+
     def join(self, timeout: Optional[float] = None) -> bool:
         """Wait for the run loop to exit after stop(). Returns True once the
         thread is down — after which no further event can be applied to the
         store (the graceful-shutdown contract callers need to freeze a
         cache deterministically)."""
-        return _join_thread(self._thread, timeout)
+        t = self._thread
+        if t is None:
+            return True    # never started
+        t.join(timeout)
+        return not t.is_alive()
 
     def _run_loop(self) -> None:
         tracing.role("reflector")
@@ -342,6 +346,7 @@ class Reflector:
                     self._list_and_watch()
                     self._backoff.reset()  # listed fine: source is healthy
                 except Exception:
+                    self._listed.set()
                     if self._stop.is_set():
                         return
                     # interruptible backoff: stop() during an outage must
@@ -349,12 +354,14 @@ class Reflector:
                     if self._stop.wait(self._backoff.next()):
                         return
         finally:
+            self._listed.set()
             tracing.role_end()
 
     def _list_and_watch(self) -> None:
         lst = self.lw.list_fn()
         rv = lst.metadata.resource_version
         self.store.replace(lst.items)
+        self._listed.set()
         self.last_sync_resource_version = rv
         resync_deadline = (time.monotonic() + self.resync_period
                            if self.resync_period else None)
@@ -402,47 +409,6 @@ class Reflector:
                         progressed = True
             finally:
                 w.stop()
-
-
-class Poller:
-    """Periodic list -> Store.replace (ref: poller.go — the node source in the
-    scheduler factory uses this, factory.go:139)."""
-
-    def __init__(self, list_fn, period: float, store):
-        self.list_fn = list_fn
-        self.period = period
-        self.store = store
-        self._stop = threading.Event()
-        self._thread: Optional[threading.Thread] = None
-
-    def run(self) -> "Poller":
-        self._run_once()
-        t = threading.Thread(target=self._loop, daemon=True, name="poller")
-        self._thread = t
-        t.start()
-        return self
-
-    def _run_once(self):
-        try:
-            lst = self.list_fn()
-            self.store.replace(lst.items)
-        except Exception:
-            pass
-
-    def _loop(self):
-        tracing.role("reflector")   # the node source's reflector, by polls
-        try:
-            while not self._stop.wait(self.period):
-                self._run_once()
-        finally:
-            tracing.role_end()
-
-    def stop(self):
-        self._stop.set()
-
-    def join(self, timeout: Optional[float] = None) -> bool:
-        """Wait for the poll loop to exit after stop() (see Reflector.join)."""
-        return _join_thread(self._thread, timeout)
 
 
 # -- typed listers (ref: listers.go) ---------------------------------------

@@ -48,7 +48,6 @@ class ClusterConfig:
     endpoints_sync_period: float = 0.5
     node_sync_period: float = 0.5
     kubelet_resync: float = 0.5
-    node_poll_period: float = 0.5
     static_pod_dirs: Dict[str, str] = field(default_factory=dict)  # node -> dir
     kubelet_http: bool = False      # start a KubeletServer per node
     batch_scheduler: bool = False   # tpu-batch wave scheduler instead of serial
@@ -121,8 +120,7 @@ class Cluster:
                 node_prober=self._probe_node))
 
         # scheduler (ref: plugin/cmd/kube-scheduler wiring)
-        self.scheduler_factory = ConfigFactory(
-            self.client, node_poll_period=c.node_poll_period)
+        self.scheduler_factory = ConfigFactory(self.client)
         self._scheduler: Optional[Scheduler] = None
 
     def _probe_node(self, node: api.Node) -> bool:

@@ -10,9 +10,9 @@ Rebuild of ``plugin/pkg/scheduler/`` — the harness around the pure algorithm:
 - ``PodBackoff`` (factory.go:245-369): per-pod exponential backoff 1s -> 60s
   with gc; the default error handler re-fetches and re-queues.
 - ``ConfigFactory`` (factory.go:40-172): wires reflectors (unassigned pods ->
-  FIFO via field selector spec.host=; assigned pods -> store), a node poller
-  filtering Schedulable/Ready conditions (factory.go:203-238), and a services
-  reflector.
+  FIFO via field selector spec.host=; assigned pods -> store; nodes -> store
+  through the Schedulable/Ready filter of factory.go:203-238, where the
+  reference polled every 10 s; services -> store).
 
 The ``algorithm`` seam accepts anything with ``schedule(pod, minion_lister)``
 — the serial GenericScheduler or the TPU-backed batch adapter — so both sit
@@ -32,7 +32,6 @@ from kubernetes_tpu.api import labels as labels_pkg
 from kubernetes_tpu.api import types as api
 from kubernetes_tpu.client.cache import (
     FIFO,
-    Poller,
     Reflector,
     Store,
     StorePodLister,
@@ -48,7 +47,7 @@ from kubernetes_tpu.util import metrics
 _log = logging.getLogger("kubernetes_tpu.scheduler")
 
 __all__ = ["Scheduler", "SchedulerConfig", "SimpleModeler", "PodBackoff",
-           "ConfigFactory", "filter_schedulable_nodes"]
+           "ConfigFactory", "filter_schedulable_nodes", "node_is_schedulable"]
 
 
 class SimpleModeler:
@@ -265,50 +264,112 @@ class Scheduler:
         return dest
 
 
+def node_is_schedulable(node: api.Node) -> bool:
+    """ref: factory.go:203-238 pollMinions, for one node — its Schedulable
+    condition isn't false and it is Ready (or Reachable, or carries no
+    conditions at all), and it is not cordoned (``spec.unschedulable``,
+    kubectl cordon). The scheduler's own Schedulable predicate and the
+    dense ``node_extra_ok`` fold are the belt to this filter's suspenders:
+    a cordon reaches the node store one watch hop after the apiserver
+    took it, and a wave cut inside that hop must not bind onto the node."""
+    if node.spec.unschedulable:
+        return False
+    conds = {c.type: c for c in node.status.conditions}
+    sched = conds.get(api.NodeSchedulable)
+    if sched is not None and sched.status != api.ConditionTrue:
+        return False
+    for kind in (api.NodeReady, api.NodeReachable):
+        cond = conds.get(kind)
+        if cond is not None:
+            return cond.status == api.ConditionTrue
+    return True
+
+
 def filter_schedulable_nodes(nodes: api.NodeList) -> api.NodeList:
-    """ref: factory.go:203-238 pollMinions — keep nodes whose Schedulable
-    condition isn't false and that are Ready (or Reachable, or carry no
-    conditions at all). Cordoned nodes (``spec.unschedulable``, kubectl
-    cordon) are dropped here too — the scheduler's own Schedulable
-    predicate and the dense ``node_extra_ok`` fold are the belt to this
-    poller's suspenders (a cordon landing mid-poll-period must not win a
-    race into a wave)."""
-    out = []
-    for node in nodes.items:
-        if node.spec.unschedulable:
-            continue
-        conds = {c.type: c for c in node.status.conditions}
-        sched = conds.get(api.NodeSchedulable)
-        if sched is not None and sched.status != api.ConditionTrue:
-            continue
-        ready = conds.get(api.NodeReady)
-        reachable = conds.get(api.NodeReachable)
-        if ready is not None:
-            if ready.status == api.ConditionTrue:
-                out.append(node)
-        elif reachable is not None:
-            if reachable.status == api.ConditionTrue:
-                out.append(node)
+    """The nodes of a list that pass ``node_is_schedulable``."""
+    return api.NodeList(
+        items=[n for n in nodes.items if node_is_schedulable(n)])
+
+
+class _SchedulableNodes:
+    """What the node reflector writes through: ``node_is_schedulable``
+    applied at ingest, so the node store only ever holds nodes a wave may
+    use and nothing filters at list time. A node that stops passing (a
+    cordon, a condition gone false) leaves the store with the event that
+    says so; one that passes again comes back the same way.
+
+    Counts what the node source decoded, by how it arrived:
+    ``scheduler_node_source_objects_total{via="list"|"watch"}``, and the
+    LISTs after the first, ``scheduler_node_source_relists_total`` (a 410,
+    an ERROR event or a stream closed cold: ``Reflector``'s conditions)."""
+
+    def __init__(self, store: Store):
+        self.store = store
+        reg = metrics.default_registry()
+        self._objects = reg.counter(
+            "scheduler_node_source_objects_total",
+            "Node objects the scheduler's node source decoded, by how "
+            "they arrived: in a LIST of every node, or one in a watch event",
+            ("via",))
+        self._relists = reg.counter(
+            "scheduler_node_source_relists_total",
+            "LISTs of every node the scheduler's node source made after "
+            "its first (its watch expired, broke or closed cold)")
+        self._listed = False
+
+    def add(self, node: api.Node) -> None:
+        self._objects.inc("watch")
+        if node_is_schedulable(node):
+            self.store.add(node)
         else:
-            out.append(node)
-    return api.NodeList(items=out)
+            self.store.delete(node)
+
+    update = add
+
+    def delete(self, node: api.Node) -> None:
+        self._objects.inc("watch")
+        self.store.delete(node)
+
+    def replace(self, nodes) -> None:
+        self._objects.inc("list", by=len(nodes))
+        if self._listed:
+            self._relists.inc()
+        self._listed = True
+        self.store.replace([n for n in nodes if node_is_schedulable(n)])
 
 
 class _StoreMinionLister:
+    """The node store's nodes by name. The sorted list is kept until the
+    store's token moves: a wave over an unchanged cluster gets the list
+    (and so the node objects) it got last time, which is what keeps the
+    encoder's ``_nodes_changed`` on its identity path."""
+
     def __init__(self, store: Store):
         self.store = store
+        self._kept = (None, None)      # (store token, NodeList)
 
     def list(self) -> api.NodeList:
-        items = sorted(self.store.list(), key=lambda n: n.metadata.name)
-        return api.NodeList(items=items)
+        # the token first: a write between the two reads leaves a list
+        # newer than its token, which the next call sorts again
+        token = self.store.token()
+        kept_token, kept = self._kept
+        if token != kept_token:
+            kept = api.NodeList(items=sorted(
+                self.store.list(), key=lambda n: n.metadata.name))
+            self._kept = (token, kept)
+        return kept
 
 
 class ConfigFactory:
-    """ref: factory.go:40-172 ConfigFactory/CreateFromKeys."""
+    """ref: factory.go:40-172 ConfigFactory/CreateFromKeys.
+
+    Every source is a ``Reflector``: one LIST at start, then watch events.
+    ``node_poll_period`` is accepted and unused: it was the period of the
+    LIST of every node that the node source made before it watched
+    (ROADMAP.md D11 has the caller that still passes it)."""
 
     def __init__(self, client, node_poll_period: float = 10.0):
         self.client = client
-        self.node_poll_period = node_poll_period
         # unassigned pods; how long each waited for a wave is the one
         # queueing delay on the timed path
         self.pod_queue = FIFO(wait_hist=metrics.default_registry().histogram(
@@ -349,14 +410,18 @@ class ConfigFactory:
         self._runners.append(Reflector(
             self.client.pods(api.NamespaceAll).list_watch(field_selector="spec.host!="),
             self.scheduled_pods, name="assigned-pods").run())
-        # poller: nodes every node_poll_period, filtered (factory.go:139)
-        self._runners.append(Poller(
-            lambda: filter_schedulable_nodes(self.client.nodes().list()),
-            self.node_poll_period, self.node_store).run())
+        # reflector: nodes -> store through the schedulable filter (the
+        # reference polled here, factory.go:139). node_store holds the
+        # first LIST, if it could be made, when create() returns
+        nodes = Reflector(self.client.nodes().list_watch(),
+                          _SchedulableNodes(self.node_store),
+                          name="nodes").run()
+        self._runners.append(nodes)
         # reflector: services
         self._runners.append(Reflector(
             self.client.services(api.NamespaceAll).list_watch(),
             self.service_store, name="services").run())
+        nodes.wait_listed()
 
         minion_lister = _StoreMinionLister(self.node_store)
         pod_lister = self.modeler.pod_lister()
@@ -396,7 +461,7 @@ class ConfigFactory:
         )
 
     def stop(self, join: bool = False, timeout: float = 2.0) -> bool:
-        """Stop every reflector/poller. With ``join=True``, wait for their
+        """Stop every reflector. With ``join=True``, wait for their
         threads to exit so no in-flight watch delivery can land in the
         stores afterwards — the deterministic-freeze contract the
         stale-wave tests rely on. Returns False iff a join timed out
@@ -412,8 +477,7 @@ class ConfigFactory:
         frozen = True
         if join:
             for r in self._runners:
-                joiner = getattr(r, "join", None)
-                if joiner is not None and not joiner(timeout):
+                if not r.join(timeout):
                     frozen = False
         with self._requeue_lock:
             requeues = list(self._requeue_threads)

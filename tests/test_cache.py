@@ -11,7 +11,6 @@ from kubernetes_tpu.api.latest import scheme
 from kubernetes_tpu.client.cache import (
     FIFO,
     ListWatch,
-    Poller,
     Reflector,
     Store,
     StorePodLister,
@@ -167,22 +166,31 @@ def test_reflector_survives_watch_closure():
         r.stop()
 
 
-def test_poller_replaces():
-    calls = []
+@pytest.mark.parametrize("list_fails", [False, True])
+def test_reflector_wait_listed(list_fails):
+    """wait_listed returns once the first LIST has landed in the store,
+    or has failed (the run loop then backs off and tries again)."""
+    release = threading.Event()
 
     def list_fn():
-        calls.append(1)
-        return api.PodList(items=[_pod(f"p{len(calls)}")],
+        assert release.wait(5.0)
+        if list_fails:
+            raise OSError("apiserver away")
+        return api.PodList(items=[_pod("a")],
                            metadata=api.ListMeta(resource_version="1"))
 
+    from kubernetes_tpu import watch as watchpkg
     store = Store()
-    p = Poller(list_fn, period=0.02, store=store)
-    p.run()
+    r = Reflector(ListWatch(list_fn, lambda rv: watchpkg.Watcher()),
+                  store).run()
     try:
-        assert _wait_for(lambda: len(calls) >= 3)
-        assert len(store) == 1
+        assert not r.wait_listed(0.1)      # the LIST has not come back
+        release.set()
+        assert r.wait_listed(5.0)
+        assert len(store) == (0 if list_fails else 1)
     finally:
-        p.stop()
+        r.stop()
+        assert r.join(5.0)
 
 
 def test_pod_and_service_listers():

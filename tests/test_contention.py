@@ -47,7 +47,7 @@ def wait_for(pred, timeout=30.0, interval=0.05):
 
 def start_batch(master, wave_size=16, linger=0.05):
     client = Client(InProcessTransport(master))
-    factory = ConfigFactory(client, node_poll_period=0.1)
+    factory = ConfigFactory(client)
     config = factory.create()
     sched = BatchScheduler(config, factory, client, wave_size=wave_size,
                            wave_linger_s=linger).run()
@@ -56,7 +56,7 @@ def start_batch(master, wave_size=16, linger=0.05):
 
 def start_serial(master):
     client = Client(InProcessTransport(master))
-    factory = ConfigFactory(client, node_poll_period=0.1)
+    factory = ConfigFactory(client)
     config = factory.create()
     sched = Scheduler(config).run()
     return sched, factory

@@ -366,7 +366,7 @@ class TestSchedulerEndToEnd:
         client.nodes().create(mknode(0, cpu="1"))
         client.pods().create(mkpod("resident", host="n000", mcpu=900))
         recorder = EventRecorder(client, api.EventSource(component="sched"))
-        factory = ConfigFactory(client, node_poll_period=0.05)
+        factory = ConfigFactory(client)
         factory.backoff = PodBackoff(initial=0.05, max_duration=0.2)
         config = factory.create(recorder=recorder)
         sched = BatchScheduler(config, factory, client, wave_size=8,

@@ -421,7 +421,7 @@ def test_wave_loop_through_mesh_parity(monkeypatch):
                     resources=api.ResourceRequirements(limits={
                         "cpu": Quantity(f"{100 + (i % 8) * 100}m"),
                         "memory": Quantity(f"{128 + (i % 4) * 64}Mi")}))])))
-        factory = ConfigFactory(client, node_poll_period=1.0)
+        factory = ConfigFactory(client)
         config = factory.create(mesh=mesh)
         import time as _time
         deadline = _time.monotonic() + 30.0

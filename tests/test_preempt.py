@@ -526,7 +526,7 @@ class TestLiveStack:
                         "memory": Quantity("128Mi")}))],
                     priority_class_name=cls))
 
-        factory = ConfigFactory(client, node_poll_period=0.2)
+        factory = ConfigFactory(client)
         config = factory.create()
         sched = BatchScheduler(config, factory, client,
                                wave_linger_s=0.01).run()

@@ -417,7 +417,7 @@ def test_scheduler_against_master():
     client = Client(InProcessTransport(m))
     for i in range(3):
         client.nodes().create(mk_node(f"n{i}"))
-    factory = ConfigFactory(client, node_poll_period=0.1)
+    factory = ConfigFactory(client)
     config = factory.create()
     sched = Scheduler(config).run()
     try:
@@ -445,7 +445,7 @@ def test_scheduler_retries_when_no_fit():
     m = Master()
     client = Client(InProcessTransport(m))
     client.nodes().create(mk_node("small", cpu="1", mem="1Gi"))
-    factory = ConfigFactory(client, node_poll_period=0.05)
+    factory = ConfigFactory(client)
     factory.backoff = PodBackoff(initial=0.05, max_duration=0.2)
     config = factory.create()
     sched = Scheduler(config).run()

@@ -631,7 +631,7 @@ def test_e2e_mid_run_resync_zero_full(monkeypatch):
         client.nodes().create(mk_cluster_node(i))
     for i in range(N_PODS):
         client.pods().create(mk_cluster_pod(i))
-    factory = ConfigFactory(client, node_poll_period=1.0)
+    factory = ConfigFactory(client)
     config = factory.create()
     deadline = time.monotonic() + 30.0
     while time.monotonic() < deadline:

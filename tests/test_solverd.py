@@ -576,7 +576,7 @@ class TestSchedulerIntegration:
         for i in range(4):
             client.nodes().create(mk_node(f"n{i}"))
         client.services().create(SERVICES[0])
-        factory = ConfigFactory(client, node_poll_period=0.1)
+        factory = ConfigFactory(client)
         config = factory.create(solver_addr=srv.address)
         assert config.solver_addr == srv.address
         sched = BatchScheduler(config, factory, client, wave_size=64,

@@ -63,7 +63,7 @@ def wait_for(cond, timeout=10.0):
 
 
 def mk_sched(client):
-    factory = ConfigFactory(client, node_poll_period=0.2)
+    factory = ConfigFactory(client)
     config = factory.create()
     return factory, BatchScheduler(config, factory, client,
                                    wave_linger_s=0.05)

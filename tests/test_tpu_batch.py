@@ -48,7 +48,7 @@ def test_batch_scheduler_schedules_and_spreads():
     client.services().create(api.Service(
         metadata=api.ObjectMeta(name="web", namespace="default"),
         spec=api.ServiceSpec(port=80, selector={"app": "web"})))
-    factory = ConfigFactory(client, node_poll_period=0.1)
+    factory = ConfigFactory(client)
     config = factory.create()
     sched = BatchScheduler(config, factory, client, wave_size=64,
                            wave_linger_s=0.1).run()
@@ -71,7 +71,7 @@ def test_batch_scheduler_requeues_unschedulable():
     m = Master()
     client = Client(InProcessTransport(m))
     client.nodes().create(mk_node("tiny", cpu="1", mem="1Gi"))
-    factory = ConfigFactory(client, node_poll_period=0.05)
+    factory = ConfigFactory(client)
     factory.backoff = PodBackoff(initial=0.05, max_duration=0.2)
     config = factory.create()
     sched = BatchScheduler(config, factory, client, wave_size=8,
@@ -103,7 +103,7 @@ def test_batch_scheduler_many_service_groups():
         client.services().create(api.Service(
             metadata=api.ObjectMeta(name=f"svc-{s:03d}", namespace="default"),
             spec=api.ServiceSpec(port=80, selector={"app": f"app-{s:03d}"})))
-    factory = ConfigFactory(client, node_poll_period=0.1)
+    factory = ConfigFactory(client)
     config = factory.create()
     sched = BatchScheduler(config, factory, client, wave_size=256,
                            wave_linger_s=0.2).run()
@@ -233,7 +233,7 @@ def test_loop_under_fault_commits_the_serial_oracles_decisions(
         client.nodes().create(mk_node(f"n{i:03d}", cpu="64", mem="256Gi"))
     for p in pods:
         client.pods().create(p)
-    factory = ConfigFactory(client, node_poll_period=1.0)
+    factory = ConfigFactory(client)
     factory.backoff = PodBackoff(initial=0.05, max_duration=0.2)
     config = factory.create()
     binder = None
@@ -330,7 +330,7 @@ def test_batch_scheduler_holds_a_gang_below_quorum_until_it_is_whole():
     client = Client(InProcessTransport(m))
     for i in range(2):
         client.nodes().create(mk_node(f"n{i}"))
-    factory = ConfigFactory(client, node_poll_period=0.05)
+    factory = ConfigFactory(client)
     factory.backoff = PodBackoff(initial=0.05, max_duration=0.2)
     config = factory.create()
     sched = BatchScheduler(config, factory, client, wave_size=8,
