@@ -24,6 +24,11 @@ One general generator reads every traffic file:
                    ``arrival_block`` pods from the same fixed set (the
                    exponential's quantiles) in an order shuffled by the seed,
                    so every seed offers the same arrivals in another order.
+
+Which template a pod takes (``harness/deployment.py``: ``pod_templates``) is
+the pod plan's to say, drawn from the seed before the window opens: blocks
+that each hold every template in proportion to its weight, so every seed
+offers the same mix in another order.
 """
 
 from __future__ import annotations
@@ -40,6 +45,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+from benchmarks.harness import deployment as dep     # noqa: E402
 from kubernetes_tpu.api import types as api          # noqa: E402
 from kubernetes_tpu.api.quantity import Quantity     # noqa: E402
 from kubernetes_tpu.client.client import Client      # noqa: E402
@@ -85,37 +91,49 @@ def open_loop_schedule(rate: float, count: int, arrivals: str, seed: int,
 
 
 class PodFactory:
-    """Pods of the configuration's template; the seed varies what the source
-    leaves free: names and uids (the uid feeds the scheduler's tie-break)."""
+    """Pods of the configuration's templates, each taken as the plan of its
+    phase says; the seed varies what the source leaves free: names and uids
+    (the uid feeds the scheduler's tie-break) and the order of the plan."""
 
-    def __init__(self, template: dict, namespace: str, seed: int):
-        self.template = template
-        self.namespace = namespace
+    def __init__(self, templates: list, plans: dict, seed: int):
+        self.templates = templates
+        self.plans = plans            # phase -> template index of each pod
         self.tag = f"{seed & 0xFFFFFFFFFF:x}"
         self._n = 0
+        self._made = dict.fromkeys(plans, 0)
         self._lock = threading.Lock()
 
-    def make(self) -> api.Pod:
+    def make(self, phase: str = "window") -> tuple:
+        """(template index, pod): the phase's next pod."""
         with self._lock:
             i = self._n
             self._n += 1
+            plan = self.plans[phase]
+            index = plan[self._made[phase] % len(plan)]
+            self._made[phase] += 1
+        template = self.templates[index]
         name = f"p{self.tag}-{i:07d}"
         limits = {k: Quantity(str(v))
-                  for k, v in self.template["limits"].items()}
-        return api.Pod(
-            metadata=api.ObjectMeta(name=name, namespace=self.namespace,
-                                    uid=f"uid-{name}"),
-            spec=api.PodSpec(containers=[api.Container(
-                name=self.template.get("container", "pause"),
-                image=self.template.get("image", "pause"),
-                resources=api.ResourceRequirements(limits=limits))]))
+                  for k, v in template["limits"].items()}
+        return index, api.Pod(
+            metadata=api.ObjectMeta(name=name,
+                                    namespace=template["namespace"],
+                                    uid=f"uid-{name}",
+                                    labels=dict(template["labels"])),
+            spec=api.PodSpec(
+                node_selector=dict(template["node_selector"]),
+                containers=[api.Container(
+                    name=template["container"], image=template["image"],
+                    ports=[api.ContainerPort(host_port=p, container_port=p)
+                           for p in template["host_ports"]],
+                    resources=api.ResourceRequirements(limits=limits))]))
 
 
 class Feeder:
-    def __init__(self, base_url: str, namespace: str, factory: PodFactory,
+    def __init__(self, base_url: str, namespaces: list, factory: PodFactory,
                  threads: int):
         self.base_url = base_url
-        self.namespace = namespace
+        self.namespaces = namespaces      # every one a template names
         self.factory = factory
         self.threads = threads
         self.lock = threading.Lock()
@@ -126,29 +144,33 @@ class Feeder:
         self.on_bound = None          # closed loop: releases a slot
         self.watch_relists = 0
         self.stopping = threading.Event()
-        self._watcher = None
-        self._watch_thread = None
+        self._watchers: dict = {}     # namespace -> its open watch
         self._warm_clients = None
 
     # -- the client's watch -------------------------------------------------
     def start_watch(self) -> None:
         self._client = Client(HTTPTransport(self.base_url,
                                             user_agent="bench-feeder-watch"))
-        self._watch_thread = threading.Thread(target=self._watch_loop,
-                                              name="feeder-watch", daemon=True)
-        self._watch_thread.start()
+        for namespace in self.namespaces:
+            threading.Thread(target=self._watch_loop, args=(namespace,),
+                             name="feeder-watch", daemon=True).start()
 
-    def _watch_loop(self) -> None:
-        pods = self._client.pods(self.namespace)
+    def stop_watch(self) -> None:
+        self.stopping.set()
+        for watcher in list(self._watchers.values()):
+            watcher.stop()
+
+    def _watch_loop(self, namespace: str) -> None:
+        pods = self._client.pods(namespace)
         while not self.stopping.is_set():
             try:
                 listed = pods.list(field_selector=BOUND_FILTER)
                 for p in listed.items:
                     self._saw_bound(p.metadata.name, p.spec.host)
-                self._watcher = pods.watch(
+                watcher = self._watchers[namespace] = pods.watch(
                     field_selector=BOUND_FILTER,
                     resource_version=listed.metadata.resource_version)
-                for ev in self._watcher:
+                for ev in watcher:
                     obj = ev.object
                     if ev.type == "ERROR" or not hasattr(obj, "spec"):
                         break
@@ -184,14 +206,15 @@ class Feeder:
 
     # -- creating -----------------------------------------------------------
     def create(self, client: Client, phase: str, due_t=None) -> None:
-        pod = self.factory.make()
+        template, pod = self.factory.make(phase)
         name = pod.metadata.name
         sent = time.monotonic()
         try:
-            client.pods(self.namespace).create(pod)
+            client.pods(pod.metadata.namespace).create(pod)
         except Exception as e:  # noqa: BLE001 — counted, reported
             with self.lock:
-                self.pods[name] = {"phase": phase, "sent_t": sent,
+                self.pods[name] = {"phase": phase, "template": template,
+                                   "sent_t": sent,
                                    "due_t": due_t, "error": repr(e)[:200]}
                 self.order.append(name)
             if self.on_bound is not None:
@@ -201,7 +224,8 @@ class Feeder:
         with self.lock:
             early = self.pods.get(name) or {}
             rec = self.pods[name] = {
-                "phase": phase, "uid": pod.metadata.uid, "sent_t": sent,
+                "phase": phase, "template": template,
+                "uid": pod.metadata.uid, "sent_t": sent,
                 "due_t": due_t, "created_t": done}
             self.order.append(name)
             self.unbound += 1
@@ -297,16 +321,21 @@ def _offered_t(rec: dict) -> float:
 
 
 def summarize(pods: dict, order: list, open_t: float, close_t: float,
-              loop: str) -> dict:
+              loop: str, names: tuple = ("default",)) -> dict:
     """The end-to-end numbers, from the client's stamps alone. Latency is
     taken over EVERY pod created in the window: bound seen - create sent
     (closed loop) or - create DUE (open loop); one never seen bound, or
-    whose create failed, is +inf and counts in ``failed``."""
+    whose create failed, is +inf and counts in ``failed``. ``by_template``
+    (keyed by ``names``) is the mix that was really offered."""
     window = [pods[n] for n in order if pods[n].get("phase") == "window"
               and open_t <= _offered_t(pods[n]) <= close_t]
     lat, late, create = [], [], []
     failed = 0
+    by_template = {n: {"attempted": 0, "bound": 0} for n in names}
     for r in window:
+        mix = by_template[names[r.get("template", 0)]]
+        mix["attempted"] += 1
+        mix["bound"] += "bound_t" in r
         start = _offered_t(r) if loop == "open" else r["sent_t"]
         if r.get("due_t") is not None:
             late.append(r["sent_t"] - r["due_t"])
@@ -331,9 +360,11 @@ def summarize(pods: dict, order: list, open_t: float, close_t: float,
            "backlog_half": backlog(half_t), "backlog_close": backlog(close_t),
            "bound_in_window": bound_in_window,
            "window_s": close_t - open_t,
-           "pods_per_s": bound_in_window / (close_t - open_t)}
+           "pods_per_s": bound_in_window / (close_t - open_t),
+           "by_template": by_template}
     if lat:
         out["bound_p50_s"] = percentile(lat, 0.50)
+        out["bound_p90_s"] = percentile(lat, 0.90)
         out["bound_p99_s"] = percentile(lat, 0.99)
     if create:
         out["create_p99_s"] = percentile(create, 0.99)
@@ -364,9 +395,18 @@ def main(argv=None) -> int:
     with open(args.traffic) as f:
         traffic = json.load(f)
 
-    feeder = Feeder(args.base_url, config["namespace"],
-                    PodFactory(config["pod_template"], config["namespace"],
-                               args.seed),
+    templates = dep.pod_templates(config)
+    rate = float(traffic.get("rate", 0))
+    # the window's plan: an open loop's every due pod; a closed loop makes
+    # as many as are bound, so its plan is long and comes round again
+    planned = {"warm": sum(int(n) for n in traffic["warm_rounds"]),
+               "window": int(rate * args.max_seconds) + 1
+               if traffic["loop"] == "open" else 64 * dep.PLAN_BLOCK}
+    plans = {phase: dep.pod_plan(templates, phase, args.seed, count)
+             for phase, count in planned.items() if count}
+    feeder = Feeder(args.base_url,
+                    sorted({t["namespace"] for t in templates}),
+                    PodFactory(templates, plans, args.seed),
                     int(traffic["feeders"]))
     feeder.start_watch()
     stop = threading.Event()
@@ -388,9 +428,8 @@ def main(argv=None) -> int:
             if traffic["loop"] == "closed":
                 threads = feeder.run_closed(int(traffic["in_flight"]), stop)
             elif traffic["loop"] == "open":
-                rate = float(traffic["rate"])
                 offsets = open_loop_schedule(
-                    rate, int(rate * args.max_seconds) + 1,
+                    rate, planned["window"],
                     traffic.get("arrivals", "uniform"), args.seed,
                     int(traffic.get("arrival_block", 1000)))
                 threads = feeder.run_open(offsets, stop)
@@ -411,9 +450,7 @@ def main(argv=None) -> int:
         return 1
     if open_t is None:
         return 1
-    feeder.stopping.set()
-    if feeder._watcher is not None:
-        feeder._watcher.stop()
+    feeder.stop_watch()
     with feeder.lock:
         pods = {n: r for n, r in feeder.pods.items() if "phase" in r}
         order = list(feeder.order)
@@ -421,11 +458,13 @@ def main(argv=None) -> int:
            "open_t": open_t, "close_t": close_t, "drained": drained,
            "drain_s": drain_s, "watch_relists": feeder.watch_relists,
            "summary": summarize(pods, order, open_t, close_t,
-                                traffic["loop"]),
-           "template_limits": config["pod_template"]["limits"],
+                                traffic["loop"],
+                                tuple(t["name"] for t in templates)),
+           "pod_templates": templates,
            "pods": [[n, pods[n].get("uid"), pods[n].get("phase"),
                      pods[n].get("host"), pods[n].get("bound_t"),
-                     pods[n].get("error"), pods[n].get("rebound_to")]
+                     pods[n].get("error"), pods[n].get("rebound_to"),
+                     pods[n].get("template")]
                     for n in order]}
     tmp = args.out + ".tmp"
     with open(tmp, "w") as f:

@@ -16,6 +16,8 @@ import random
 import threading
 import time
 
+from benchmarks.harness import deployment as dep
+
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 HOST_SPAN = "bench/"     # prefix of the host spans the harness writes
 # the wave loop's phases, each one call of the scheduler's own loop thread
@@ -79,9 +81,11 @@ class CompileLog:
 
 class GcLog:
     """The interpreter's full collections (generation 2) of this process:
-    when each began and how long it held every thread. The program tunes no
-    collector, so a heap that grows with every pod is walked whole now and
-    then; the client sees that walk as its tail."""
+    when each began and how long it held every thread. Since PR 28 the
+    program freezes the survivors of every full collection
+    (``util/gcpolicy.py``), so these walk what was made since the last one;
+    the program's own series count every generation, this hook the full
+    collections alone, on the harness's clock."""
 
     def __init__(self, annotation=None):
         self.pauses: list = []       # (began, seconds)
@@ -170,24 +174,31 @@ def wait_for(predicate, timeout_s: float, what: str) -> None:
         time.sleep(0.02)
 
 
-def node_names(config: dict) -> list:
-    return [f"node-{i:05d}" for i in range(int(config["nodes"]))]
-
-
 def make_nodes(config: dict, seed: int) -> list:
-    """The configuration's nodes, registered in an order drawn from the
-    seed (the scheduler's node-list order is by name, so the order of
-    arrival must not matter)."""
+    """The configuration's nodes, each of its own template, registered in an
+    order drawn from the seed (the scheduler's node-list order is by name,
+    so the order of arrival must not matter)."""
     from kubernetes_tpu.api import types as api
     from kubernetes_tpu.api.quantity import Quantity
 
-    caps = {k: Quantity(str(v))
-            for k, v in config["node_template"]["capacity"].items()}
-    nodes = [api.Node(metadata=api.ObjectMeta(name=name),
-                      spec=api.NodeSpec(capacity=dict(caps)))
-             for name in node_names(config)]
+    caps = {t["name"]: {k: Quantity(str(v))
+                        for k, v in t["capacity"].items()}
+            for t in dep.node_templates(config)}
+    nodes = [api.Node(
+        metadata=api.ObjectMeta(name=name, labels=dict(t["labels"])),
+        spec=api.NodeSpec(capacity=dict(caps[t["name"]])))
+        for name, t in dep.nodes_of(config).items()]
     random.Random(seed).shuffle(nodes)
     return nodes
+
+
+def make_services(config: dict) -> list:
+    from kubernetes_tpu.api import types as api
+
+    return [api.Service(
+        metadata=api.ObjectMeta(name=s["name"], namespace=s["namespace"]),
+        spec=api.ServiceSpec(port=80, selector=dict(s["selector"])))
+        for s in dep.services(config)]
 
 
 class ControlPlane:
@@ -216,6 +227,10 @@ class ControlPlane:
             feed(self.srv.base_url, lambda c, o: c.nodes().create(o),
                  make_nodes(config, seed), 4)
             self.register_nodes_s = time.perf_counter() - t0
+            services = make_services(config)
+            if services:
+                feed(self.srv.base_url, lambda c, o: c.services(
+                    o.metadata.namespace).create(o), services, 1)
             # the scheduler as cmd/scheduler builds it: its own HTTP
             # client, rate-limited async events, default wave size, linger
             client = Client(HTTPTransport(self.srv.base_url,
@@ -234,8 +249,11 @@ class ControlPlane:
             self._gate.set()
             self._install_gate()
             wait_for(lambda: len(self.factory.node_store.list())
-                     == int(config["nodes"]), 120.0,
-                     "the scheduler's node poller to hold every node")
+                     == int(config["nodes"])
+                     and len(self.factory.service_store.list())
+                     == len(services), 120.0,
+                     "the scheduler's node and service watches to hold "
+                     "every node and service")
             self.sched.run()
         except BaseException:
             self.stop()
@@ -350,9 +368,14 @@ def _wave_dims(snap, n_pending: int, n_nodes: int) -> dict:
 
 def check_final_list(pods, nodes) -> dict:
     """The served result from one LIST, with no solver code: which pod sits
-    on which node, nodes over capacity, host ports taken twice."""
-    cap = {n.metadata.name: (n.spec.capacity["cpu"].milli_value(),
-                             n.spec.capacity["memory"].int_value())
+    on which node, nodes over a capacity they state (every resource of it),
+    host ports taken twice."""
+    def amount(resource, quantity):
+        return quantity.milli_value() if resource == "cpu" \
+            else quantity.int_value()
+
+    cap = {n.metadata.name: {r: amount(r, q)
+                             for r, q in n.spec.capacity.items()}
            for n in nodes}
     where: dict = {}
     used: dict = {}
@@ -368,22 +391,21 @@ def check_final_list(pods, nodes) -> dict:
         if host != p.status.host or host not in cap:
             unknown_nodes += 1
             continue
-        cpu, mem = used.get(host, (0, 0))
+        sums = used.setdefault(host, {})
         for c in p.spec.containers:
-            cpu += c.resources.limits["cpu"].milli_value()
-            mem += c.resources.limits["memory"].int_value()
+            for r, q in c.resources.limits.items():
+                sums[r] = sums.get(r, 0) + amount(r, q)
             for port in c.ports:
                 if port.host_port:
                     taken = ports.setdefault(host, set())
                     port_clashes += port.host_port in taken
                     taken.add(port.host_port)
-        used[host] = (cpu, mem)
-    over = sum(1 for h, (cpu, mem) in used.items()
-               if cpu > cap[h][0] or mem > cap[h][1])
+    over = sum(1 for h, sums in used.items()
+               if any(sums.get(r, 0) > c for r, c in cap[h].items()))
     return {"where": where, "nodes_over_capacity": over,
             "host_port_clashes": port_clashes,
             "bound_to_unknown_node": unknown_nodes, "listed_twice": twice,
             "nodes_used": len(used),
-            "max_cpu_share": max((c / cap[h][0]
-                                  for h, (c, _) in used.items()),
-                                 default=0.0)}
+            "max_cpu_share": max((sums.get("cpu", 0) / cap[h]["cpu"]
+                                  for h, sums in used.items()
+                                  if cap[h].get("cpu")), default=0.0)}

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import importlib
 
-from benchmarks.harness.control_plane import node_names
+from benchmarks.harness import deployment as dep
 
 
 def load_reference(config: dict):
@@ -23,23 +23,20 @@ def load_reference(config: dict):
         f"benchmarks.references.{config['reference']}")
 
 
-def _cluster(ref, config: dict):
-    cap = config["node_template"]["capacity"]
-    per_node = (ref.milli(cap["cpu"]), ref.whole(cap["memory"]))
-    return ref.Cluster({name: per_node for name in node_names(config)})
-
-
-def _request(ref, limits: dict) -> tuple:
-    return ref.milli(limits["cpu"]), ref.whole(limits["memory"])
-
-
-def replay(ref, config: dict, waves: list, uid_of: dict, request: tuple,
-           solve=None) -> list:
-    """The reference's (host, score) for every pod of every wave."""
-    cluster = _cluster(ref, config)
+def replay(ref, config: dict, waves: list, pod_of: dict, solve=None) -> list:
+    """The reference's (host, score) for every pod of every wave.
+    ``pod_of``: {pod name: (uid, its pod template)}; the nodes and the
+    services are the configuration's own, each as it states them."""
+    cluster = ref.Cluster(dep.nodes_of(config), dep.services(config))
     solve = solve or ref.solve_wave
-    return [solve(cluster, [(uid_of[name], request) for name in w["pods"]])
+    return [solve(cluster, [pod_of[name] for name in w["pods"]])
             for w in waves]
+
+
+def _pod_of(feeder_doc: dict) -> dict:
+    templates = feeder_doc["pod_templates"]
+    return {row[0]: (row[1] or "", templates[row[7]])
+            for row in feeder_doc["pods"]}
 
 
 def _known_waves(waves: list, created) -> tuple:
@@ -54,15 +51,13 @@ def _known_waves(waves: list, created) -> tuple:
 def compare(config: dict, feeder_doc: dict, waves: list, listed: dict,
             programs: dict, events: dict, kernel_program: str) -> dict:
     ref = load_reference(config)
-    request = _request(ref, feeder_doc["template_limits"])
     created = {}          # name -> (uid, client's host, error)
-    for name, uid, _phase, host, _bound_t, error, rebound in \
+    for name, uid, _phase, host, _bound_t, error, rebound, _template in \
             feeder_doc["pods"]:
         created[name] = (uid, host, error, rebound)
-    uid_of = {n: (c[0] or "") for n, c in created.items()}
 
     known, foreign = _known_waves(waves, created)
-    expected = replay(ref, config, known, uid_of, request)
+    expected = replay(ref, config, known, _pod_of(feeder_doc))
 
     decisions_differ = scores_differ = 0
     decided: dict = {}
@@ -123,11 +118,10 @@ def control_reading(config: dict, feeder_doc: dict, waves: list) -> int:
     reference with the in-wave commit put off, over the same waves, held
     against the reference."""
     ref = load_reference(config)
-    request = _request(ref, feeder_doc["template_limits"])
-    uid_of = {p[0]: (p[1] or "") for p in feeder_doc["pods"]}
-    known, _ = _known_waves(waves, uid_of)
-    expected = replay(ref, config, known, uid_of, request)
-    control = replay(ref, config, known, uid_of, request,
+    pod_of = _pod_of(feeder_doc)
+    known, _ = _known_waves(waves, pod_of)
+    expected = replay(ref, config, known, pod_of)
+    control = replay(ref, config, known, pod_of,
                      solve=ref.solve_wave_uncommitted)
     return sum(1 for e, c in zip(expected, control)
                for (eh, _), (ch, _) in zip(e, c) if eh != ch)

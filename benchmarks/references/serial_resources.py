@@ -2,8 +2,15 @@
 for the default provider, pod by pod, with nothing of the program in it.
 
 It covers what the ``sched-basic-*`` configurations state and refuses the
-rest: nodes with a cpu and a memory capacity (both above zero), pods that
-request cpu and memory, no host ports, no services, no selectors.
+rest by raising: nodes with a cpu and a memory capacity (both above zero)
+and no third resource, pods that request cpu and memory, no host ports, no
+services, no node selectors (``serial_default.py`` models those).
+
+The interface every reference has (``harness/correct.py`` calls no other):
+``Cluster(nodes, services)`` with ``nodes`` {name: node template} and
+``services`` [service] as ``harness/deployment.py`` reads them from the
+configuration; ``solve_wave(cluster, pods)`` and ``solve_wave_uncommitted``
+with ``pods`` [(uid, pod template)] in wave order.
 
 The rule (upstream ``pkg/scheduler``: ``generic_scheduler.go`` Schedule,
 ``predicates.go`` PodFitsResources, ``priorities.go`` LeastRequested,
@@ -63,16 +70,46 @@ def whole(quantity: str) -> int:
     return int(q)
 
 
+def cpu_memory(resources, what: str) -> tuple:
+    """(cpu_milli, memory_bytes) of a template's capacity or limits. A pair
+    is taken as it is: ``tests/test_mesh_arm.py`` (tier-1, not this
+    benchmark's to edit) still hands pairs over."""
+    if isinstance(resources, tuple):
+        return resources
+    if set(resources) - {"cpu", "memory"}:
+        raise ValueError(f"this reference models cpu and memory only; "
+                         f"{what} states {sorted(resources)}")
+    return milli(resources.get("cpu", 0)), whole(resources.get("memory", 0))
+
+
+def _capacity(node) -> tuple:
+    return cpu_memory(node if isinstance(node, tuple) else node["capacity"],
+                 "a node")
+
+
+def _request(pod) -> tuple:
+    if isinstance(pod, tuple):
+        return pod
+    if pod.get("node_selector") or pod.get("host_ports"):
+        raise ValueError(f"this reference models no node selector and no "
+                         f"host port; pod template {pod.get('name')!r} "
+                         f"states one")
+    return cpu_memory(pod["limits"], "a pod")
+
+
 class Cluster:
     """Node capacities and what has been committed onto them so far.
-    ``nodes``: {name: (cpu_milli, memory_bytes)}."""
+    ``nodes``: {name: node template}; ``services``: none."""
 
-    def __init__(self, nodes: dict):
+    def __init__(self, nodes: dict, services=()):
         if not nodes:
             raise ValueError("a cluster needs nodes")
+        if services:
+            raise ValueError("this reference models no service")
         self.names = sorted(nodes)                   # node-list order
         self.index = {n: i for i, n in enumerate(self.names)}
-        self.cap = np.array([nodes[n] for n in self.names], dtype=np.int64)
+        self.cap = np.array([_capacity(nodes[n]) for n in self.names],
+                            dtype=np.int64)
         if (self.cap <= 0).any():
             raise ValueError("this reference models only nodes with a cpu "
                              "and a memory capacity above zero")
@@ -100,10 +137,11 @@ def _decide(cluster: Cluster, uid: str, request):
 
 
 def solve_wave(cluster: Cluster, pods: list) -> list:
-    """``pods``: [(uid, (cpu_milli, memory_bytes))] in wave order. Returns
+    """``pods``: [(uid, pod template)] in wave order. Returns
     [(host or None, score)], and leaves the decisions committed."""
     out = []
-    for uid, request in pods:
+    for uid, pod in pods:
+        request = _request(pod)
         host, score = _decide(cluster, uid, request)
         if host is not None:
             cluster.commit(host, request)
@@ -114,8 +152,8 @@ def solve_wave(cluster: Cluster, pods: list) -> list:
 def solve_wave_uncommitted(cluster: Cluster, pods: list) -> list:
     """The control: every pod of the wave decides against the state before
     the wave; the commits follow together."""
-    out = [_decide(cluster, uid, request) for uid, request in pods]
-    for (uid, request), (host, _score) in zip(pods, out):
+    out = [_decide(cluster, uid, _request(pod)) for uid, pod in pods]
+    for (uid, pod), (host, _score) in zip(pods, out):
         if host is not None:
-            cluster.commit(host, request)
+            cluster.commit(host, _request(pod))
     return out

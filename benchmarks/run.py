@@ -103,6 +103,19 @@ def memory_peak_bytes(jax) -> int:
     return peak
 
 
+def resident_waves(text: str) -> dict:
+    """``solver_resident_waves_total`` so far, by outcome and reason: how
+    often a wave's node planes were patched, and why not."""
+    from benchmarks.readers import promtext
+    out: dict = {}
+    for name, labels, value in promtext.parse(text):
+        if name == "solver_resident_waves_total" and value:
+            key = "/".join(labels[k] for k in ("outcome", "reason")
+                           if labels.get(k))
+            out[key] = out.get(key, 0) + int(value)
+    return out
+
+
 def run(args) -> int:
     bench = load_json(ROOT, "BENCHMARK.json")
     cell = find_cell(bench, args.workload)
@@ -303,6 +316,11 @@ def run(args) -> int:
 
     compared = {k: {"value": v, "limit": lim}
                 for k, (v, lim) in numbers.items()}
+    resident0 = resident_waves(before_text)
+    resident = {"setup": resident0,
+                "window": {k: n - resident0.get(k, 0) for k, n in
+                           resident_waves(after_text).items()
+                           if n - resident0.get(k, 0)}}
     in_window = [w["t"] for w in waves
                  if feeder_doc["open_t"] <= w["t"] <= feeder_doc["close_t"]]
     edges = [feeder_doc["open_t"]] + in_window + [feeder_doc["close_t"]]
@@ -313,6 +331,7 @@ def run(args) -> int:
             "window_waves": len(waves) - warm_waves,
             "compared_pods": verdict["compared_pods"],
             "first_diff": verdict["first_diff"], "programs": programs,
+            "setup_programs": programs0, "resident": resident,
             "compiles": compiles, "setup_compiles": setup_compiles,
             "reference_s": reference_s,
             "gc_pauses_s": [[round(began - feeder_doc["open_t"], 3),

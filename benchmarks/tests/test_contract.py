@@ -79,7 +79,10 @@ def test_every_per_layer_metric_has_a_file_a_reader_and_cells_to_move():
                for m in b["end_to_end"]}
     for m in b["per_layer"]:
         doc = _load(HERE, "metrics", m["name"] + ".json")
-        assert {k: doc[k] for k in m} == m
+        # a metric's file is written once; the cells that report it grow
+        # in BENCHMARK.json alone
+        assert {k: doc[k] for k in m if k != "workloads"} == \
+            {k: v for k, v in m.items() if k != "workloads"}
         reader = importlib.import_module(f"benchmarks.readers.{doc['reader']}")
         assert callable(reader.read)
         where = set(m.get("workloads", cells))

@@ -66,6 +66,8 @@ def test_a_stall_in_the_window_moves_the_tail_and_the_rate():
     stalled = feeder.summarize(*_pods(1000, 970, 2.0), 100.0, 110.0, "open")
     assert stalled["bound_p50_s"] == pytest.approx(0.05)
     assert stalled["bound_p99_s"] == pytest.approx(2.05)
+    # 30 of 1,000 stalled: the 90th percentile does not see them
+    assert stalled["bound_p90_s"] == pytest.approx(0.05)
     assert stalled["pods_per_s"] == pytest.approx(97.0, abs=0.2)
 
 

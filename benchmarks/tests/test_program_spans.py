@@ -51,7 +51,7 @@ def _one_wave_texts():
                 spec=api.NodeSpec(capacity={"cpu": Quantity("4"),
                                             "memory": Quantity("32Gi")})))
         before = text()
-        factory = ConfigFactory(client, node_poll_period=0.2)
+        factory = ConfigFactory(client)
         sched = BatchScheduler(factory.create(), factory, client).run()
         deadline = time.monotonic() + 60.0
         while len(factory.node_store.list()) < 8:

@@ -10,6 +10,7 @@ from benchmarks.references import serial_resources as ref
 CONFIG = {"nodes": 12, "reference": "serial_resources",
           "node_template": {"capacity": {"cpu": "4", "memory": "32Gi"}}}
 LIMITS = {"cpu": "100m", "memory": "500Mi"}
+TEMPLATE = {"name": "default", "namespace": "default", "limits": LIMITS}
 
 
 def test_quantities():
@@ -52,15 +53,20 @@ def test_agrees_with_the_serial_oracle_until_the_cluster_is_full():
                 limits={k: Quantity(v) for k, v in LIMITS.items()}))]))
         for n in names]
     want = solve_serial(nodes, [], pods)
-    got = cor.replay(ref, CONFIG, waves, uid_of, (100, 500 * 2 ** 20))[0]
+    got = cor.replay(ref, CONFIG, waves,
+                     {n: (uid_of[n], TEMPLATE) for n in names})[0]
     assert [h for h, _ in got] == want
     assert want[-5:] == [None] * 5 and None not in want[:-5]
 
 
 def _run_doc(names):
-    return {"template_limits": LIMITS,
-            "pods": [[n, f"uid-{n}", "window", None, 1.0, None, None]
+    return {"pod_templates": [TEMPLATE],
+            "pods": [[n, f"uid-{n}", "window", None, 1.0, None, None, 0]
                      for n in names]}
+
+
+def _pod_of(names):
+    return {n: (f"uid-{n}", TEMPLATE) for n in names}
 
 
 def _as_recorded(waves, solved):
@@ -74,11 +80,11 @@ def test_the_control_comes_out_as_not_correct():
     The reference itself in the program's place is correct."""
     names, waves = _waves(400, [1, 2, 4, 64])
     doc = _run_doc(names)
-    uid_of = {n: f"uid-{n}" for n in names}
-    req = (100, 500 * 2 ** 20)
-    sound = _as_recorded(waves, cor.replay(ref, CONFIG, waves, uid_of, req))
+    sound = _as_recorded(waves, cor.replay(ref, CONFIG, waves,
+                                           _pod_of(names)))
     control = _as_recorded(waves, cor.replay(
-        ref, CONFIG, waves, uid_of, req, solve=ref.solve_wave_uncommitted))
+        ref, CONFIG, waves, _pod_of(names),
+        solve=ref.solve_wave_uncommitted))
 
     def verdict(recorded):
         where = {n: h for w in recorded for n, h in zip(w["pods"],
@@ -109,9 +115,7 @@ def test_the_control_comes_out_as_not_correct():
 def test_each_guarantee_has_a_number_that_fails(fault, number):
     names, waves = _waves(50, [10])
     doc = _run_doc(names)
-    uid_of = {n: f"uid-{n}" for n in names}
-    rec = _as_recorded(waves, cor.replay(ref, CONFIG, waves, uid_of,
-                                         (100, 500 * 2 ** 20)))
+    rec = _as_recorded(waves, cor.replay(ref, CONFIG, waves, _pod_of(names)))
     where = {n: h for w in rec for n, h in zip(w["pods"], w["hosts"])}
     for row in doc["pods"]:
         row[3] = where[row[0]]

@@ -3,8 +3,11 @@ the kernel through the interpreter: feeder child, window, wave recorder,
 final LIST, reference, a well-formed last line. Then the same run with the
 timed path broken underneath — an answer altered where it is produced, a
 step that leaves its state unchanged — which has to come out as not
-correct. A rehearsal finds wrong paths; it says nothing about the chip, and
-prints no metric."""
+correct. And the fixture (``fixtures/three-class-5000n.json``: three machine
+types, three kinds of pod with a node selector, a host port and a service)
+cut to 60 nodes, through the same path, with ``serial_default`` deciding. A
+rehearsal finds wrong paths; it says nothing about the chip, and prints no
+metric."""
 
 import json
 import os
@@ -18,6 +21,27 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 RUN = [sys.executable, os.path.join(ROOT, "benchmarks", "run.py")]
 SMALL = ["--rehearse", "1", "--config-set", "nodes=50",
          "--traffic-set", "warm_rounds=[1, 2, 4, 8]"]
+
+
+def _fixture_args(counts=(36, 18, 6), rate=40):
+    """The fixture's keys as run.py takes them: by --config-set."""
+    with open(os.path.join(ROOT, "benchmarks", "tests", "fixtures",
+                           "three-class-5000n.json")) as f:
+        doc = json.load(f)
+    doc["nodes"] = sum(counts)
+    for template, count in zip(doc["node_templates"], counts):
+        template["count"] = count
+    args = ["--rehearse", "1", "--traffic-set", "warm_rounds=[1, 2, 4, 8]",
+            "--traffic-set", f"rate={rate}"]
+    for key in ("nodes", "node_templates", "pod_templates", "services",
+                "reference"):
+        args += ["--config-set", f"{key}={json.dumps(doc[key])}"]
+    return args
+
+
+def _steady_cell():
+    return [w["name"] for w in _bench()["workloads"]
+            if "steady" in w["traffic"]][0]
 
 
 def _bench():
@@ -121,5 +145,52 @@ def test_a_run_with_the_timed_path_broken_underneath_is_not_correct(
                          "--seconds", "3", "--trace", "0"] + SMALL)
     out = capsys.readouterr().out.strip().splitlines()
     res = json.loads(out[-1])
+    assert rc == 0 and res["correct"] is False
+    assert res["compared"]["decisions_differ"]["value"] >= 1
+
+
+def test_the_fixture_runs_through_with_every_template_bound_in_proportion():
+    proc = subprocess.run(
+        RUN + ["--workload", _steady_cell(), "--seed", str(2 ** 31 + 99),
+               "--seconds", "4", "--trace", "0", "--control", "1"]
+        + _fixture_args(), capture_output=True, text=True, cwd=ROOT,
+        timeout=600)
+    res = _last_line(proc)
+    compared = res["compared"]
+    control = compared.pop("control.decisions_differ")
+    assert res["correct"] is True, compared
+    assert all(v["value"] == 0 for v in compared.values())
+    assert control["value"] > 0 and control["limit"] is None
+    side = json.loads([ln for ln in proc.stderr.splitlines()
+                       if ln.startswith("run.py: {")][-1][len("run.py: "):])
+    assert side["overrides"]["config.reference"] == "serial_default"
+    mix = side["summary"]["by_template"]
+    assert set(mix) == {"small", "zoned", "ported"}
+    assert all(m["bound"] == m["attempted"] > 0 for m in mix.values())
+    offered = sum(m["attempted"] for m in mix.values())
+    assert offered == res["attempted"] and res["failed"] == 0
+    # 6 : 3 : 1 over whole blocks; a window ends inside one
+    assert mix["small"]["attempted"] > mix["zoned"]["attempted"] \
+        > mix["ported"]["attempted"]
+
+
+def test_a_selector_the_reference_does_not_see_reads_as_decisions_that_differ(
+        monkeypatch, capsys):
+    """The comparison sees the new fields: the same run, the 'zoned'
+    template's node selector dropped on the reference's side only."""
+    from benchmarks import run as bench_run
+    from benchmarks.harness import correct as cor
+
+    inner = cor._pod_of
+
+    def blind(feeder_doc):
+        doc = dict(feeder_doc, pod_templates=[
+            dict(t, node_selector={}) for t in feeder_doc["pod_templates"]])
+        return inner(doc)
+
+    monkeypatch.setattr(cor, "_pod_of", blind)
+    rc = bench_run.main(["--workload", _steady_cell(), "--seed", "78",
+                         "--seconds", "3", "--trace", "0"] + _fixture_args())
+    res = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert rc == 0 and res["correct"] is False
     assert res["compared"]["decisions_differ"]["value"] >= 1

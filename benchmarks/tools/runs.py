@@ -90,6 +90,7 @@ def main(argv=None) -> int:
                          ("window_waves", "compiles", "setup_compiles",
                           "reference_s",
                           "drain_s", "watch_relists", "programs",
+                          "setup_programs", "resident",
                           "notes", "max_wave_gap_s", "max_wave_gap_at_s",
                           "gc_pauses_s",
                           "setup_parts_s", "summary")},
