@@ -83,6 +83,7 @@ from kubernetes_tpu.ops.kernels import (
     u64_mod_small as _u64_mod,
 )
 from kubernetes_tpu.util import metrics, tracing
+from kubernetes_tpu.util.metrics import wave_parts
 
 __all__ = ["solve", "solve_jit", "solve_device", "SolverInputs",
            "decisions_to_names", "WaveRouter", "WavePlan", "default_router",
@@ -920,20 +921,6 @@ def mesh_placed_bytes() -> metrics.Counter:
         "solver_mesh_placed_bytes_total",
         "Bytes of solver planes that crossed from the host to the device(s) "
         "of in-process waves")
-
-
-_WAVE_PART_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
-                      0.02, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5)
-
-
-def wave_parts() -> metrics.Histogram:
-    """Seconds of each part of a wave: the parts of the solve timed here
-    (``solve.route`` ... ``solve.post``) and those the wave loop times
-    (scheduler/tpu_batch.py). One observation a wave and part."""
-    return metrics.default_registry().histogram(
-        "scheduler_wave_part_seconds",
-        "Wall seconds per wave of one part of the wave loop's phases",
-        ("part",), buckets=_WAVE_PART_BUCKETS)
 
 
 def peer_bound_of(source) -> int:

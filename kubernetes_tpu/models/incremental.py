@@ -62,6 +62,7 @@ from kubernetes_tpu.models.snapshot import (
 )
 from kubernetes_tpu.scheduler import predicates as _preds
 from kubernetes_tpu.scheduler.generic import pod_tie_break_key
+from kubernetes_tpu.util import metrics, tracing
 
 __all__ = ["IncrementalEncoder"]
 
@@ -78,6 +79,14 @@ _EPOCHS = itertools.count(1)
 # the touched-row log keeps at most this many entries; a consumer that asks
 # for rows it no longer holds is told so (None) and re-places its planes
 _TOUCH_LOG_MAX = 1 << 16
+
+
+def constrained_pods() -> metrics.Counter:
+    return metrics.default_registry().counter(
+        "scheduler_wave_constrained_pods_total",
+        "Pending pods the encoder built into waves that carry a node "
+        "selector, a host port or the membership of a service (beside "
+        "scheduler_wave_pods_total)")
 
 
 class _PodRec:
@@ -627,15 +636,16 @@ class IncrementalEncoder:
 
     def fill_dims(self) -> dict:
         """True (unpadded) occupancy of the pow-2-bucketed vocabulary
-        axes, in the axis units of the device inputs (port/pd sets pack
-        32 vocab entries per uint32 word). The prewarm fill trigger
-        (solver/prewarm.py) compares these against the compiled bucket
-        so the next bucket's program compiles BEFORE growth crosses the
-        boundary. Axes whose true occupancy the encoder does not track
-        are omitted — absent keys never trigger."""
+        axes, counted in vocabulary entries and stated in the axis units
+        of the device inputs: port/pd sets pack 32 entries per uint32
+        word, so one port is 1/32 of a word and not a word. The prewarm
+        fill trigger (solver/prewarm.py) compares these against the
+        compiled bucket so the next bucket's program compiles BEFORE
+        growth crosses the boundary. Axes whose true occupancy the
+        encoder does not track are omitted — absent keys never trigger."""
         return {
-            "Wp": (len(self._ports) + 31) // 32,
-            "Wd": (len(self._pds) + 31) // 32,
+            "Wp": len(self._ports) / 32,
+            "Wd": len(self._pds) / 32,
             "Ks": len(self._sels),
             "G": len(self._grp_rows),
             "B": len(self._bands),
@@ -722,97 +732,124 @@ class IncrementalEncoder:
         Ppad = _pow2_pad(P, minimum=1) if pad_pods else max(P, 0)
         R0 = len(self._resource_names)
 
-        # -- pending pods pass (sticky vocabs; may grow columns) ------------
-        req = np.zeros((Ppad, R0), np.int64)
-        grow_req: List[Tuple[int, int, int]] = []  # (row, rcol, amt) overflow
-        pp_ij: List[Tuple[int, int]] = []
-        ps_ij: List[Tuple[int, int]] = []
-        pg_ij: List[Tuple[int, int]] = []
-        pod_host_idx = np.full(Ppad, -2, np.int32)
-        pod_host_idx[:P] = -1
-        pod_prio = np.zeros(Ppad, np.int32)
-        pod_can_preempt = np.zeros(Ppad, bool)  # padding rows never preempt
-        pod_names: List[str] = []
-        pod_ns = np.zeros(P, np.int32)
-        feats: List[Tuple[int, int]] = []  # (pod, svc-vocab col)
-        for j, p in enumerate(pending_pods):
-            meta = p.metadata
-            pod_names.append(f"{meta.namespace}/{meta.name}")
-            pod_ns[j] = self._ns.intern(meta.namespace)
-            for kv in (meta.labels or {}).items():
-                t = self._svc_vocab.index.get(kv)
-                if t is not None:
-                    feats.append((j, t))
-            for c in p.spec.containers:
-                for name, q in c.resources.limits.items():
-                    r = self._rix.get(name)
-                    amt = _preds.resource_value(name, q)
-                    if r is None:
-                        grow_req.append((j, self._resource_col(name), amt))
-                    elif r < R0:
-                        req[j, r] += amt
-                    else:
-                        grow_req.append((j, r, amt))
-                for cp in c.ports:
-                    if cp.host_port:
-                        pp_ij.append((j, self._port_col(cp.host_port)))
-            for kv in (p.spec.node_selector or {}).items():
-                ps_ij.append((j, self._sel_col(kv)))
-            for v in p.spec.volumes:
-                if v.source.gce_persistent_disk is not None:
-                    pg_ij.append((j, self._pd_col(
-                        v.source.gce_persistent_disk.pd_name)))
-            if p.spec.host:
-                pod_host_idx[j] = self._node_index.get(p.spec.host, -2)
-            pod_prio[j] = api.pod_priority(p)
-            pod_can_preempt[j] = api.pod_can_preempt(p)
-        R = len(self._resource_names)
-        if R > R0:
-            req = np.pad(req, ((0, 0), (0, R - R0)))
-        for row, r, amt in grow_req:
-            req[row, r] += amt
+        # -- the per-pod half: one pass over the pending pods (sticky vocabs;
+        # may grow columns), their port / selector / disk planes, their
+        # service groups, tie-break keys and gang runs. What is left of the
+        # encode after it is per node.
+        with tracing.phase("wave.encode.pods", metrics.wave_parts(),
+                           "encode.pods"):
+            req = np.zeros((Ppad, R0), np.int64)
+            # (row, rcol, amt) of a resource column grown in this pass
+            grow_req: List[Tuple[int, int, int]] = []
+            pp_ij: List[Tuple[int, int]] = []
+            ps_ij: List[Tuple[int, int]] = []
+            pg_ij: List[Tuple[int, int]] = []
+            pod_host_idx = np.full(Ppad, -2, np.int32)
+            pod_host_idx[:P] = -1
+            pod_prio = np.zeros(Ppad, np.int32)
+            pod_can_preempt = np.zeros(Ppad, bool)  # padding never preempts
+            pod_names: List[str] = []
+            pod_ns = np.zeros(P, np.int32)
+            feats: List[Tuple[int, int]] = []  # (pod, svc-vocab col)
+            for j, p in enumerate(pending_pods):
+                meta = p.metadata
+                pod_names.append(f"{meta.namespace}/{meta.name}")
+                pod_ns[j] = self._ns.intern(meta.namespace)
+                for kv in (meta.labels or {}).items():
+                    t = self._svc_vocab.index.get(kv)
+                    if t is not None:
+                        feats.append((j, t))
+                for c in p.spec.containers:
+                    for name, q in c.resources.limits.items():
+                        r = self._rix.get(name)
+                        amt = _preds.resource_value(name, q)
+                        if r is None:
+                            grow_req.append(
+                                (j, self._resource_col(name), amt))
+                        elif r < R0:
+                            req[j, r] += amt
+                        else:
+                            grow_req.append((j, r, amt))
+                    for cp in c.ports:
+                        if cp.host_port:
+                            pp_ij.append((j, self._port_col(cp.host_port)))
+                for kv in (p.spec.node_selector or {}).items():
+                    ps_ij.append((j, self._sel_col(kv)))
+                for v in p.spec.volumes:
+                    if v.source.gce_persistent_disk is not None:
+                        pg_ij.append((j, self._pd_col(
+                            v.source.gce_persistent_disk.pd_name)))
+                if p.spec.host:
+                    pod_host_idx[j] = self._node_index.get(p.spec.host, -2)
+                pod_prio[j] = api.pod_priority(p)
+                pod_can_preempt[j] = api.pod_can_preempt(p)
+            R = len(self._resource_names)
+            if R > R0:
+                req = np.pad(req, ((0, 0), (0, R - R0)))
+            for row, r, amt in grow_req:
+                req[row, r] += amt
 
-        def scatter(pairs, rows, cols, dtype=bool):
-            out = np.zeros((rows, cols), dtype)
-            if pairs:
-                idx = np.asarray(pairs, np.int64)
-                out[idx[:, 0], idx[:, 1]] = True
-            return out
+            def scatter(pairs, rows, cols, dtype=bool):
+                out = np.zeros((rows, cols), dtype)
+                if pairs:
+                    idx = np.asarray(pairs, np.int64)
+                    out[idx[:, 0], idx[:, 1]] = True
+                return out
 
-        Kp, Ks, Kd = self._ports.cap, self._sels.cap, self._pds.cap
-        pod_ports = scatter(pp_ij, Ppad, Kp)
-        pod_sel = scatter(ps_ij, Ppad, Ks)
-        pod_pds = scatter(pg_ij, Ppad, Kd)
+            Kp, Ks, Kd = self._ports.cap, self._sels.cap, self._pds.cap
+            pod_ports = scatter(pp_ij, Ppad, Kp)
+            pod_sel = scatter(ps_ij, Ppad, Ks)
+            pod_pds = scatter(pg_ij, Ppad, Kd)
 
-        # -- pending service groups (matmul over the sticky svc vocab) ------
-        G = self._grp_cnt.shape[0]
-        pod_gid = np.full(Ppad, -1, np.int32)
-        member = np.zeros((Ppad, G), bool)
-        S = len(self._services)
-        if S and P:
-            T = self._svc_req.shape[1]
-            feat = scatter(feats, P, T).astype(np.float32)
-            hits = feat @ self._svc_req.astype(np.float32).T      # [P, S]
-            subset = hits == self._svc_reqcnt[None, :]
-            eligible = subset & (self._svc_reqcnt[None, :] > 0) & \
-                ((self._svc_ns[None, :] == -1) |
-                 (self._svc_ns[None, :] == pod_ns[:, None]))
-            has = eligible.any(axis=1)
-            first = np.argmax(eligible, axis=1)
-            for j in np.nonzero(has)[0]:
-                key = (int(pod_ns[j]), int(first[j]))
-                row = self._grp_rows.get(key)
-                if row is None:
-                    row = self._new_group_row(key)
-                pod_gid[j] = row
+            # the pods that carry a node selector, a host port or (below)
+            # the membership of a service
+            constrained = pod_sel[:P].any(axis=1) | pod_ports[:P].any(axis=1)
+
+            # -- pending service groups (matmul over the sticky svc vocab) --
             G = self._grp_cnt.shape[0]
-            if member.shape[1] < G:
-                member = np.pad(member, ((0, 0), (0, G - member.shape[1])))
-            if len(self._grp_rows):
-                g_ns = np.array([k[0] for k in self._grp_rows], np.int32)
-                g_si = np.array([k[1] for k in self._grp_rows], np.int64)
-                member[:P, :len(self._grp_rows)] = \
-                    subset[:, g_si] & (pod_ns[:, None] == g_ns[None, :])
+            pod_gid = np.full(Ppad, -1, np.int32)
+            member = np.zeros((Ppad, G), bool)
+            S = len(self._services)
+            if S and P:
+                T = self._svc_req.shape[1]
+                feat = scatter(feats, P, T).astype(np.float32)
+                hits = feat @ self._svc_req.astype(np.float32).T      # [P, S]
+                subset = hits == self._svc_reqcnt[None, :]
+                eligible = subset & (self._svc_reqcnt[None, :] > 0) & \
+                    ((self._svc_ns[None, :] == -1) |
+                     (self._svc_ns[None, :] == pod_ns[:, None]))
+                has = eligible.any(axis=1)
+                constrained |= has
+                first = np.argmax(eligible, axis=1)
+                for j in np.nonzero(has)[0]:
+                    key = (int(pod_ns[j]), int(first[j]))
+                    row = self._grp_rows.get(key)
+                    if row is None:
+                        row = self._new_group_row(key)
+                    pod_gid[j] = row
+                G = self._grp_cnt.shape[0]
+                if member.shape[1] < G:
+                    member = np.pad(member,
+                                    ((0, 0), (0, G - member.shape[1])))
+                if len(self._grp_rows):
+                    g_ns = np.array([k[0] for k in self._grp_rows], np.int32)
+                    g_si = np.array([k[1] for k in self._grp_rows], np.int64)
+                    member[:P, :len(self._grp_rows)] = \
+                        subset[:, g_si] & (pod_ns[:, None] == g_ns[None, :])
+            constrained_pods().inc(by=int(constrained.sum()))
+
+            tie = _fnv1a64_batch([pod_tie_break_key(p)
+                                  for p in pending_pods])
+            tie_hi = np.zeros(Ppad, np.int64)
+            tie_lo = np.zeros(Ppad, np.int64)
+            tie_hi[:P] = (tie >> np.uint64(32)).astype(np.int64)
+            tie_lo[:P] = (tie & np.uint64(0xFFFFFFFF)).astype(np.int64)
+
+            rid, run_start = gang.pod_run_ids(pending_pods)
+            pod_rid = np.full(Ppad, -1, np.int32)
+            pod_rid[:P] = rid
+            pod_run_start = np.ones(Ppad, bool)
+            pod_run_start[:P] = run_start
 
         # -- fit accumulators (greedy only for genuine overflow) ------------
         cap = self._cap
@@ -842,18 +879,6 @@ class IncrementalEncoder:
 
         fit_used, fit_exceeded = greedy_fit_accumulators(
             cap, score_used, recs_in_list_order())
-
-        tie = _fnv1a64_batch([pod_tie_break_key(p) for p in pending_pods])
-        tie_hi = np.zeros(Ppad, np.int64)
-        tie_lo = np.zeros(Ppad, np.int64)
-        tie_hi[:P] = (tie >> np.uint64(32)).astype(np.int64)
-        tie_lo[:P] = (tie & np.uint64(0xFFFFFFFF)).astype(np.int64)
-
-        rid, run_start = gang.pod_run_ids(pending_pods)
-        pod_rid = np.full(Ppad, -1, np.int32)
-        pod_rid[:P] = rid
-        pod_run_start = np.ones(Ppad, bool)
-        pod_run_start[:P] = run_start
 
         # -- kube-preempt planes (sticky emit gate) -------------------------
         if not self._preempt_emitted and len(self._bands) and P \

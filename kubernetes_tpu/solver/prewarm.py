@@ -10,7 +10,11 @@ churn harness needed a ``max(180, nodes * 0.05)`` warmup heuristic.
 The PrewarmController moves that compile OFF the wave loop:
 
 - **fill trigger** — every wave reports its true (unpadded) axis
-  occupancy against the pow-2 bucket it ran in (``observe``); when an
+  occupancy against the pow-2 bucket it ran in (``observe``), each axis
+  in the bucket's own units: a port or disk vocabulary packs 32 entries
+  a ``uint32`` word and ``IncrementalEncoder.fill_dims`` states it as
+  entries / 32, so one host port is 3 % of a one-word bucket and 24
+  ports are the 75 % that queue the two-word programs. When an
   axis reaches ``fill_fraction`` of its bucket, the NEXT bucket's target
   shape is queued and a background thread compiles it through the exact
   entry point live waves use (``models/batch_solver.warm_compile`` in
@@ -149,19 +153,20 @@ class PrewarmController:
             self._refresh_gauges()
         return n
 
-    def observe(self, actual: Dict[str, int], bucket: Dict[str, int],
+    def observe(self, actual: Dict[str, float], bucket: Dict[str, int],
                 frozen: Sequence[str] = ()) -> None:
         """Hot-path fill check: for every axis whose true occupancy
-        ``actual[k]`` reached ``fill_fraction`` of its current bucket,
-        queue the single-axis-advanced next bucket. Axes absent from
-        ``actual`` or listed in ``frozen`` never trigger."""
+        ``actual[k]`` (in the bucket's own units; a fraction of a unit is
+        fine) reached ``fill_fraction`` of its current bucket, queue the
+        single-axis-advanced next bucket. Axes absent from ``actual`` or
+        listed in ``frozen`` never trigger."""
         f = self.fill_fraction
         for k, cur in bucket.items():
             if k in frozen or k == "N1":
                 continue
             cur = int(cur)
             a = actual.get(k)
-            if cur <= 0 or a is None or int(a) < f * cur:
+            if cur <= 0 or a is None or a < f * cur:
                 continue
             nxt = {ax: int(v) for ax, v in bucket.items()}
             nxt[k] = cur * 2

@@ -371,6 +371,22 @@ def solverd_delta_metrics() -> SolverdDeltaMetrics:
     return SolverdDeltaMetrics._singleton
 
 
+_WAVE_PART_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
+                      0.02, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5)
+
+
+def wave_parts() -> Histogram:
+    """Seconds of each part of a wave: the parts of the solve
+    (``solve.route`` ... ``solve.post``, models/batch_solver.py), of the
+    encode (``encode.pods``, models/incremental.py) and
+    those the wave loop times (scheduler/tpu_batch.py). One observation a
+    wave and part."""
+    return default_registry().histogram(
+        "scheduler_wave_part_seconds",
+        "Wall seconds per wave of one part of the wave loop's phases",
+        ("part",), buckets=_WAVE_PART_BUCKETS)
+
+
 class SlipstreamMetrics:
     """The kube-slipstream family — journal-replay encoder resync and
     ahead-of-time shape-bucket prewarm (models/incremental.py checkpoint
