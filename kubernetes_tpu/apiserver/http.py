@@ -700,7 +700,8 @@ class _Handler(BaseHTTPRequestHandler):
             # encode_response seeds the watch frame cache with this very
             # payload: the fan-out of the store event this write produced
             # then copies bytes instead of encoding again
-            self._send_json(code, apisrv.encode_response(out, version))
+            self._send_json(code, apisrv.encode_response(
+                out, version, written=verb in ("create", "update")))
         return code
 
     def _handle_batch_bind(self, version: str, namespace: str,
@@ -729,7 +730,8 @@ class _Handler(BaseHTTPRequestHandler):
             # encode-once at commit: each bound pod's new revision is
             # serialized here, where the write lands, so the watch fan-out
             # of its CAS event is a byte copy for every watcher
-            on_bound=lambda pod: apisrv.seed_frame(pod, version))
+            on_bound=lambda pod: apisrv.seed_frame(pod, version,
+                                                   written=True))
         payload = apisrv.scheme.encode(out, version)
         if apisrv.fairshed is not None:
             bound = sum(1 for item in out.items if not item.error)
@@ -1267,7 +1269,20 @@ class APIServer:
         except Exception:
             return ""
 
-    def seed_frame(self, obj, version: str, wire_json: str = "") -> None:
+    def _encode(self, obj, version: str, written: bool) -> str:
+        """``obj``'s wire JSON in ``version``. The answer to the write
+        that made ``obj`` (``written``), asked for in the version the
+        store holds, is that write's own walk handed on (StoreHelper
+        .take_wire); any other version has transforms and any other
+        object no walk to hand on: the codec."""
+        if written and version == self.scheme.default_version:
+            wire = self.master.helper.take_wire(obj)
+            if wire is not None:
+                return self.scheme.wire_to_json(wire)
+        return self.scheme.encode(obj, version)
+
+    def seed_frame(self, obj, version: str, wire_json: str = "",
+                   written: bool = False) -> None:
         """Seed the wire cache with one object's encoding — called by the
         WRITE path (create/update responses, batch-bind commits), where
         the bytes are being produced anyway, so the watch fan-out of the
@@ -1282,7 +1297,7 @@ class APIServer:
                 return
         if not wire_json:
             try:
-                wire_json = self.scheme.encode(obj, version)
+                wire_json = self._encode(obj, version, written)
             except Exception:
                 return
         self.metric_frame_seeds.inc()
@@ -1299,10 +1314,11 @@ class APIServer:
             # fan-outs import these bytes instead of re-encoding
             self.metric_seed_published.inc()
 
-    def encode_response(self, obj, version: str) -> str:
+    def encode_response(self, obj, version: str,
+                        written: bool = False) -> str:
         """Encode a dispatch result for its HTTP response AND seed the
         frame cache with it (single objects only — see seed_frame)."""
-        payload = self.scheme.encode(obj, version)
+        payload = self._encode(obj, version, written)
         self.seed_frame(obj, version, wire_json=payload)
         return payload
 

@@ -17,11 +17,23 @@ from __future__ import annotations
 import json
 from typing import Any, Callable, Dict, Optional, Tuple, Type
 
-from kubernetes_tpu.runtime.serialize import from_wire, to_wire
+from kubernetes_tpu.runtime.serialize import from_wire, roundtrip, to_wire
+from kubernetes_tpu.util import metrics
 
 __all__ = ["Scheme", "NotRegisteredError"]
 
 WireTransform = Callable[[dict], dict]
+
+# Counted where the codec runs, whoever asked: a pass is one walk between
+# an object and its wire dict. What a write hands on (storage/helper.py)
+# and what the caches serve never gets here.
+_PASSES = metrics.default_registry().counter(
+    "runtime_codec_passes_total",
+    "Walks between an API object and its wire dict, by direction",
+    ("direction",))
+
+# json.dumps(wire, sort_keys=True), less the encoder it builds a call
+_DUMPS = json.JSONEncoder(sort_keys=True).encode
 
 
 class NotRegisteredError(KeyError):
@@ -109,6 +121,7 @@ class Scheme:
 
     # -- codec --------------------------------------------------------------
     def encode_to_wire(self, obj: Any, version: Optional[str] = None) -> dict:
+        _PASSES.inc("encode")
         version = version or self.default_version
         kind = self.object_kind(obj)
         if not self.recognizes(version, kind):
@@ -125,14 +138,21 @@ class Scheme:
         wire["apiVersion"] = version
         return wire
 
+    @staticmethod
+    def wire_to_json(wire: dict) -> str:
+        """The one spelling of a wire dict as bytes: store, response and
+        watch frame all carry it."""
+        return _DUMPS(wire)
+
     def encode(self, obj: Any, version: Optional[str] = None) -> str:
         """ref: runtime.Codec.Encode — JSON with kind + apiVersion set."""
-        return json.dumps(self.encode_to_wire(obj, version), sort_keys=True)
+        return self.wire_to_json(self.encode_to_wire(obj, version))
 
     def decode_from_wire(self, wire: dict, default_kind: str = "",
                          default_version: str = "") -> Any:
         if not isinstance(wire, dict):
             raise ValueError("expected a JSON object")
+        _PASSES.inc("decode")
         wire = dict(wire)
         kind = wire.pop("kind", "") or default_kind
         version = wire.pop("apiVersion", "") or default_version or self.default_version
@@ -156,11 +176,20 @@ class Scheme:
         return self.decode_from_wire(json.loads(data), default_kind, default_version)
 
     def deep_copy(self, obj: Any) -> Any:
-        """Round-trip copy through the wire form (ref: runtime.Scheme.Copy)."""
+        """What decoding ``obj``'s encoding in the default version gives
+        (ref: runtime.Scheme.Copy) — without the wire in between where
+        that version has no transform for the kind: the typed copy, then
+        the version's defaulter, as ``decode_from_wire`` ends."""
         kind = self.object_kind(obj)
         version = self.default_version
-        wire = self.encode_to_wire(obj, version)
-        return self.decode_from_wire(wire)
+        if (version, kind) in self._transforms \
+                or not self.recognizes(version, kind):  # or raises
+            return self.decode_from_wire(self.encode_to_wire(obj, version))
+        out = roundtrip(obj)
+        defaulter = self._defaulters.get((version, kind))
+        if defaulter is not None:
+            defaulter(out)
+        return out
 
     def convert_wire(self, wire: dict, from_version: str, to_version: str) -> dict:
         """Convert a versioned wire dict between versions via the internal form

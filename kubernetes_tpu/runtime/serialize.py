@@ -32,7 +32,8 @@ from typing import (Any, Callable, Dict, Optional, get_args, get_origin,
 
 from kubernetes_tpu.api.quantity import Quantity
 
-__all__ = ["to_wire", "from_wire", "camel", "snake", "now_rfc3339"]
+__all__ = ["to_wire", "from_wire", "roundtrip", "camel", "snake",
+           "now_rfc3339"]
 
 _HINTS_CACHE: Dict[type, Dict[str, Any]] = {}
 
@@ -275,3 +276,88 @@ def from_wire(cls: Any, data: Any) -> Any:
     if dataclasses.is_dataclass(cls) and isinstance(cls, type):
         return _decode_dataclass(cls, data)
     return _compile_decoder(cls)(data)
+
+
+# -- round trip without the wire -----------------------------------------------
+
+def _roundtrip_datetime(v: Any) -> datetime.datetime:
+    if v.__class__ is datetime.datetime and v.tzinfo is datetime.timezone.utc:
+        return v  # what decoding its own encoding gives back
+    return _decode_datetime(_encode_datetime(v))
+
+
+def _roundtrip_quantity(v: Any) -> Quantity:
+    return v if v.__class__ is Quantity else Quantity(str(v))
+
+
+def _compile_roundtrip(hint: Any) -> Callable[[Any], Any]:
+    """Closure giving, for a value of a field hinted ``hint``, what
+    ``_compile_decoder(hint)`` makes of what ``to_wire`` makes of it."""
+    hint = _strip_optional(hint)
+    if hint is Quantity:
+        return _roundtrip_quantity
+    if hint is datetime.datetime:
+        return _roundtrip_datetime
+    origin = get_origin(hint)
+    if origin in (list, tuple):
+        item = _compile_roundtrip((get_args(hint) or (Any,))[0])
+        return lambda v: [None if x is None else item(x) for x in v]
+    if origin is dict:
+        args = get_args(hint)
+        val = _compile_roundtrip(args[1] if len(args) == 2 else Any)
+        return lambda v: {k: None if x is None else val(x)
+                          for k, x in v.items()}
+    if dataclasses.is_dataclass(hint) and isinstance(hint, type):
+        return lambda v: (roundtrip(v) if v.__class__ is hint
+                          else _decode_dataclass(hint, to_wire(v)))
+    if hint in (str, int, float, bool):
+        return lambda v: v if isinstance(v, hint) else hint(to_wire(v))
+    return to_wire  # untyped: the decoder keeps the wire value as it is
+
+
+# per-class plan: (attr, default, default factory or None, the primitive
+# type a value of which is handed back as it is, compiled closure)
+_ROUNDTRIP_PLAN: Dict[type, list] = {}
+
+
+def _roundtrip_plan(cls: type) -> list:
+    plan = _ROUNDTRIP_PLAN.get(cls)
+    if plan is None:
+        hints = _hints(cls)
+        plan = []
+        for f in dataclasses.fields(cls):
+            hint = _strip_optional(hints.get(f.name, Any))
+            factory = None if f.default_factory is dataclasses.MISSING \
+                else f.default_factory
+            plan.append((f.name,
+                         None if f.default is dataclasses.MISSING
+                         else f.default,
+                         factory,
+                         hint if hint in (str, int, float, bool) else None,
+                         _compile_roundtrip(hint)))
+        _ROUNDTRIP_PLAN[cls] = plan
+    return plan
+
+
+def roundtrip(obj: Any) -> Any:
+    """``from_wire(type(obj), to_wire(obj))`` for a dataclass tree, without
+    the wire in between: a private copy in which every value is what the
+    codec would have handed back — None where a field's default is not
+    None becomes that default, a tuple a list, a pre-formatted timestamp
+    a datetime in UTC, a bare number in a Quantity field a Quantity.
+    Atomic leaves are shared, as ``runtime.clone.deep_clone`` shares them.
+    (A field still at a default that the wire omits is kept as it is: it
+    equals what decoding restores.)"""
+    cls = obj.__class__
+    new = object.__new__(cls)
+    nd = new.__dict__
+    d = obj.__dict__
+    for name, default, factory, prim, rt in _roundtrip_plan(cls):
+        v = d[name]
+        if v is None:  # the wire omits it: what an absent key decodes to
+            nd[name] = default if factory is None else factory()
+        elif v.__class__ is prim:
+            nd[name] = v
+        else:
+            nd[name] = rt(v)
+    return new

@@ -18,6 +18,7 @@ from typing import Any, Callable, Optional, Type
 from kubernetes_tpu import watch as watchpkg
 from kubernetes_tpu.api import errors
 from kubernetes_tpu.api.meta import accessor
+from kubernetes_tpu.util import metrics
 from kubernetes_tpu.storage.memstore import (
     ErrCASConflict,
     ErrIndexOutdated,
@@ -27,6 +28,13 @@ from kubernetes_tpu.storage.memstore import (
 )
 
 __all__ = ["StoreHelper", "parse_watch_resource_version"]
+
+# How a revision came into a decode cache: "codec" ran the scheme over
+# the stored bytes, "write" is a write's own object handed on (_landed).
+_CACHED = metrics.default_registry().counter(
+    "storage_decode_cache_total",
+    "Revisions put into a StoreHelper's decode cache, by source",
+    ("source",))
 
 
 def parse_watch_resource_version(rv: str) -> int:
@@ -62,18 +70,35 @@ class StoreHelper:
     # hot path. atomic_update isolates before calling update_fn; the
     # DELETED-event resourceVersion rewrite clones explicitly.
     #
+    # WHO MAY SEED THE CACHE: _cache, from two callers. _decode, with what
+    # the codec made of the stored bytes; and _landed, the one seam every
+    # write verb ends in, with a COPY of the object it just stored
+    # (Scheme.deep_copy: what decoding that object's encoding gives,
+    # field for field, without the wire) — never the caller's object,
+    # which the caller goes on to own and mutate (registries stamp it,
+    # PodStatusREST aliases the request's status into it, in-process
+    # clients hand in whatever they built). Both finish the object the
+    # same way — resourceVersion, then the linkers — before it becomes
+    # visible, so a reader cannot tell which of the two it was handed.
+    #
     # Sized to hold a full-shape churn working set (50k pods): at 8192 a
     # pod created early in the run was evicted by the time its bind
     # committed, so every batched bind paid a cold decode + the bind
     # event's prev_kv decode — two full codec passes back on the hot
     # path the cache exists to remove.
     _DECODE_CACHE_MAX = 65536
+    # A written revision's wire dict waits here for the response or the
+    # bind's frame seed, microseconds as a rule; a write nobody answers
+    # over HTTP (in-process clients) leaves its dict to fall off the end.
+    # Holds more than the largest wave's bindings:batch.
+    _WRITTEN_MAX = 2048
 
     def __init__(self, store: MemStore, scheme):
         self.store = store
         self.scheme = scheme
         self._decode_cache: "OrderedDict" = OrderedDict()
-        self._decode_lock = threading.Lock()
+        self._written: "OrderedDict" = OrderedDict()  # rv -> wire dict
+        self._decode_lock = threading.Lock()          # guards both
         self._linkers: list = []  # (key prefix, decorate_fn)
 
     def register_linker(self, prefix: str, fn) -> None:
@@ -92,56 +117,108 @@ class StoreHelper:
         with self._decode_lock:
             cached = self._decode_cache.get(ck)
         if cached is None:
-            cached = self.scheme.decode(kv.value)
-            accessor.set_resource_version(cached, str(kv.modified_index))
-            for prefix, fn in self._linkers:
-                if kv.key.startswith(prefix):
-                    fn(cached)
-                    break
-            with self._decode_lock:
-                self._decode_cache[ck] = cached
-                while len(self._decode_cache) > self._DECODE_CACHE_MAX:
-                    self._decode_cache.popitem(last=False)
+            cached = self._cache(kv, self.scheme.decode(kv.value), "codec")
         return deep_clone(cached) if isolate else cached
 
-    def _encode(self, obj) -> str:
+    def _cache(self, kv, obj, source: str) -> Any:
+        """Finish ``obj`` as revision ``kv`` (its resourceVersion, then the
+        linkers) and put it into the decode cache: the one way in."""
+        accessor.set_resource_version(obj, str(kv.modified_index))
+        for prefix, fn in self._linkers:
+            if kv.key.startswith(prefix):
+                fn(obj)
+                break
+        _CACHED.inc(source)
+        with self._decode_lock:
+            self._decode_cache[(kv.key, kv.modified_index)] = obj
+            while len(self._decode_cache) > self._DECODE_CACHE_MAX:
+                self._decode_cache.popitem(last=False)
+        return obj
+
+    def _walk(self, obj) -> "tuple[str, tuple]":
+        """One walk a write, before the store is asked: the bytes the store
+        is to hold, and what ``_landed`` hands on once it holds them —
+        ``obj``, its wire dict in the storage version (the bytes are that
+        dict dumped) and the copy that will seed the decode cache. The copy
+        is made here and not after the write, so that what is left to do
+        between a revision's event and its place in the caches is a
+        stamp and two inserts: a watcher that gets there first runs the
+        codec over bytes written microseconds before."""
         # resourceVersion is storage metadata, not payload: clear before
         # encoding, like the reference (etcd_helper.go:236 Versioner).
         rv = accessor.resource_version(obj)
         accessor.set_resource_version(obj, "")
         try:
-            return self.scheme.encode(obj)
+            wire = self.scheme.encode_to_wire(obj)
         finally:
             accessor.set_resource_version(obj, rv)
+        return (self.scheme.wire_to_json(wire),
+                (obj, wire, self.scheme.deep_copy(obj)))
+
+    def _landed(self, kv, obj, wire: dict, copy) -> Any:
+        """The write seam: ``obj``, walked by ``_walk``, is revision ``kv``
+        of the store. Stamps the caller's object in place, like the
+        reference (etcd_helper.go CreateObj leaves the passed
+        runtime.Object as the result), seeds the decode cache with the
+        copy — the caller goes on to own ``obj`` — and keeps ``wire`` for
+        whoever answers this write (take_wire)."""
+        rv = str(kv.modified_index)
+        accessor.set_resource_version(obj, rv)
+        with self._decode_lock:
+            raced = (kv.key, kv.modified_index) in self._decode_cache
+            self._written[rv] = wire
+            while len(self._written) > self._WRITTEN_MAX:
+                self._written.popitem(last=False)
+        if not raced:  # else a watcher was quicker and ran the codec
+            self._cache(kv, copy, "write")
+        return obj
+
+    def take_wire(self, obj) -> Optional[dict]:
+        """The wire dict, in the storage version, of the revision ``obj``
+        is — once, to the caller that answers the write that made it: the
+        write's own walk, with the two metadata keys that walk could not
+        know (the store bytes carry no resourceVersion; the master stamps
+        selfLink on what it returns) as ``obj`` carries them. None when
+        this helper did not write the revision or it was taken already."""
+        m = getattr(obj, "metadata", None)
+        rv = getattr(m, "resource_version", "")
+        if not rv or not hasattr(m, "name"):  # a list's is a store index
+            return None
+        with self._decode_lock:
+            wire = self._written.pop(rv, None)
+        if wire is None:
+            return None
+        meta = wire["metadata"]
+        meta["resourceVersion"] = rv
+        if m.self_link:
+            meta["selfLink"] = m.self_link
+        return wire
 
     # -- CRUD ---------------------------------------------------------------
     def create_obj(self, key: str, obj: Any, ttl: Optional[float] = None) -> Any:
         """ref: etcd_helper.go:205 CreateObj."""
+        encoded, walked = self._walk(obj)
         try:
-            kv = self.store.create(key, self._encode(obj), ttl=ttl)
+            kv = self.store.create(key, encoded, ttl=ttl)
         except ErrKeyExists:
             raise errors.new_already_exists(accessor.kind(obj), accessor.name(obj))
-        # decorate the caller's object in place, like the reference
-        # (etcd_helper.go CreateObj leaves the passed runtime.Object as
-        # the result); nothing stored aliases it — the store holds bytes
-        accessor.set_resource_version(obj, str(kv.modified_index))
-        return obj
+        return self._landed(kv, *walked)
 
     def set_obj(self, key: str, obj: Any, ttl: Optional[float] = None) -> Any:
         """Write; CAS on the object's resourceVersion when set
         (ref: etcd_helper.go:236 SetObj)."""
         rv = accessor.resource_version(obj)
+        encoded, walked = self._walk(obj)
         try:
             if rv:
-                kv = self.store.compare_and_swap(key, self._encode(obj), int(rv), ttl=ttl)
+                kv = self.store.compare_and_swap(key, encoded, int(rv), ttl=ttl)
             else:
-                kv = self.store.set(key, self._encode(obj), ttl=ttl)
+                kv = self.store.set(key, encoded, ttl=ttl)
         except ErrCASConflict:
             raise errors.new_conflict(accessor.kind(obj), accessor.name(obj))
         except ErrKeyNotFound:
             raise errors.new_not_found(accessor.kind(obj), accessor.name(obj))
-        accessor.set_resource_version(obj, str(kv.modified_index))
-        return obj
+        return self._landed(kv, *walked)
 
     def extract_obj(self, key: str, kind: str = "", name: str = "") -> Any:
         """ref: etcd_helper.go:144 ExtractObj."""
@@ -191,7 +268,7 @@ class StoreHelper:
                 current = obj_type()
                 prev_index = None
             desired = update_fn(current)
-            encoded = self._encode(desired)
+            encoded, walked = self._walk(desired)
             try:
                 if prev_index is None:
                     kv = self.store.create(key, encoded, ttl=ttl)
@@ -200,8 +277,7 @@ class StoreHelper:
             except (ErrCASConflict, ErrKeyExists, ErrKeyNotFound):
                 continue  # re-read and retry
             # desired is already private (isolated decode above)
-            accessor.set_resource_version(desired, str(kv.modified_index))
-            return desired
+            return self._landed(kv, *walked)
         raise errors.new_conflict(obj_type.__name__, key, "too many CAS retries")
 
     def atomic_update_many(self, obj_type: Type,
@@ -221,7 +297,7 @@ class StoreHelper:
             if not live:
                 return results
             kvs = self.store.get_many([updates[i][0] for i in live])
-            batch = []            # (slot, key, encoded, prev_index)
+            batch = []            # (slot, key, encoded, walked, prev_index)
             for i, kv in zip(live, kvs):
                 key, fn = updates[i]
                 if kv is None:
@@ -233,12 +309,12 @@ class StoreHelper:
                 except errors.StatusError as e:
                     results[i] = e
                     continue
-                batch.append((i, key, self._encode(desired), desired,
-                              kv.modified_index))
+                encoded, walked = self._walk(desired)
+                batch.append((i, key, encoded, walked, kv.modified_index))
             outcomes = self.store.compare_and_swap_many(
                 [(key, enc, prev) for _, key, enc, _, prev in batch])
             live = []
-            for (i, key, _enc, desired, _prev), oc in zip(batch, outcomes):
+            for (i, key, _enc, walked, _prev), oc in zip(batch, outcomes):
                 if isinstance(oc, ErrCASConflict):
                     live.append(i)        # lost a race: re-read and retry
                 elif isinstance(oc, ErrKeyNotFound):
@@ -247,9 +323,7 @@ class StoreHelper:
                 elif isinstance(oc, Exception):
                     results[i] = errors.new_internal_error(str(oc))
                 else:
-                    accessor.set_resource_version(desired,
-                                                  str(oc.modified_index))
-                    results[i] = desired
+                    results[i] = self._landed(oc, *walked)
         for i in live:
             results[i] = errors.new_conflict(obj_type.__name__, updates[i][0],
                                              "too many CAS retries")
@@ -271,7 +345,7 @@ class StoreHelper:
         for _ in range(max_retries):
             if not live:
                 return results
-            txn = []       # (slot, cas_ops, delete_ops, desired)
+            txn = []       # (slot, cas_ops, delete_ops, walked)
             for i in live:
                 pod_key, fn, victims = items[i]
                 try:
@@ -306,23 +380,22 @@ class StoreHelper:
                 if bad is not None:
                     results[i] = bad
                     continue
-                txn.append((i, [(pod_key, self._encode(desired),
-                                 kv.modified_index)], deletes, desired))
+                encoded, walked = self._walk(desired)
+                txn.append((i, [(pod_key, encoded, kv.modified_index)],
+                            deletes, walked))
             if not txn:
                 live = []
                 return results
             outcomes = self.store.txn_many(
                 [(cas, dels) for _i, cas, dels, _d in txn])
             live = []
-            for (i, _cas, _dels, desired), oc in zip(txn, outcomes):
+            for (i, _cas, _dels, walked), oc in zip(txn, outcomes):
                 if isinstance(oc, (ErrCASConflict, ErrKeyNotFound)):
                     live.append(i)   # raced: re-read and retry
                 elif isinstance(oc, Exception):
                     results[i] = errors.new_internal_error(str(oc))
                 else:
-                    accessor.set_resource_version(
-                        desired, str(oc[0].modified_index))
-                    results[i] = desired
+                    results[i] = self._landed(oc[0], *walked)
         for i in live:
             results[i] = errors.new_conflict(obj_type.__name__,
                                              items[i][0],
