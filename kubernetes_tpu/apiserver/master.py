@@ -41,7 +41,7 @@ class MasterConfig:
     scheme: Any = None
     admission_control: tuple = DEFAULT_ADMISSION
     authorizer: Any = None          # .authorize(user, attrs) raising Forbidden
-    portal_net: str = "10.0.0.0/24"
+    portal_net: str = "10.0.0.0/16"
     event_ttl_seconds: float = 3600.0
     cloud: Any = None               # cloudprovider.Interface (ref: master.go Cloud)
 

@@ -16,7 +16,7 @@ def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(prog="kube-apiserver", exit_on_error=False)
     p.add_argument("--address", default="127.0.0.1")
     p.add_argument("--port", type=int, default=8080)
-    p.add_argument("--portal-net", "--portal_net", default="10.0.0.0/24")
+    p.add_argument("--portal-net", "--portal_net", default="10.0.0.0/16")
     # default shared with apiserver.master.DEFAULT_ADMISSION — a plugin
     # added to the in-process default (PriorityDefault was the incident:
     # priorityClassName silently unresolved in the multi-process

@@ -13,9 +13,16 @@ This encoder keeps the node-side planes *resident* and applies deltas:
   vocabularies whose axes are pow-2 bucketed — so a churning cluster
   re-uses at most log2 distinct compiled solver shapes instead of
   recompiling per wave;
-- **refcounted node planes**: per-node port/PD use and service-group
-  membership counts increment on pod arrival and decrement on departure,
-  so the per-wave cost is O(changed pods), not O(cluster);
+- **refcounted node planes**: per-node port/PD use increments on pod
+  arrival and decrements on departure, so the per-wave cost is O(changed
+  pods), not O(cluster);
+- **service groups kept where they are few**: a pod's services come from an
+  index over selector pairs (``_ServiceIndex``), and a group's peers are
+  kept sparsely (group -> node -> count), maintained pod by pod. A wave
+  carries one dense ``group_counts`` row for each distinct group its
+  pending pods name, made from the sparse counts in O(its peers); no row
+  outlives its wave, so no service and no new group ends a residency
+  epoch (docs/design/solver.md section 2a);
 - **order-exact overflow handling**: greedy-fit usage equals the plain sum
   on every node whose total fits (the common case); only genuinely
   overflowing nodes trigger the sequential in-order walk, over the current
@@ -46,6 +53,7 @@ from __future__ import annotations
 
 import functools
 import itertools
+import operator
 import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -81,6 +89,29 @@ _EPOCHS = itertools.count(1)
 _TOUCH_LOG_MAX = 1 << 16
 
 
+# A wave's group axis: the floor of every pow-2 vocabulary while no wave has
+# named more groups than that; from the first that does, a function of the
+# pod bucket (``IncrementalEncoder._group_bucket``), so that the group axis
+# adds no program shapes of its own.
+GROUP_FLOOR = 8
+
+
+def wave_groups() -> metrics.Counter:
+    return metrics.default_registry().counter(
+        "scheduler_wave_groups_total",
+        "Distinct service groups named by the pending pods of the waves the "
+        "encoder built: the group rows those waves carried (beside "
+        "scheduler_wave_solve_seconds_count)")
+
+
+def peered_pods() -> metrics.Counter:
+    return metrics.default_registry().counter(
+        "scheduler_wave_peered_pods_total",
+        "Pending pods whose service group had a committed peer when their "
+        "wave was built: the pods whose ServiceSpreading term could tell "
+        "nodes apart (beside scheduler_wave_pods_total)")
+
+
 def constrained_pods() -> metrics.Counter:
     return metrics.default_registry().counter(
         "scheduler_wave_constrained_pods_total",
@@ -92,22 +123,24 @@ def constrained_pods() -> metrics.Counter:
 class _PodRec:
     """Cached contribution of one existing pod to the resident planes."""
 
-    __slots__ = ("host_idx", "req", "ports", "pds", "ns_code", "svc_mask",
-                 "prio", "name", "ns")
+    __slots__ = ("host_idx", "req", "ports", "pds", "ns_code", "svcs",
+                 "prio", "name", "ns", "labels")
 
     def __init__(self, host_idx: int, req: List[Tuple[int, int]],
                  ports: List[int], pds: List[int], ns_code: int,
-                 svc_mask: np.ndarray, prio: int = 0, name: str = "",
-                 ns: str = ""):
+                 svcs: Tuple[int, ...], prio: int = 0, name: str = "",
+                 ns: str = "", labels: Optional[dict] = None):
         self.host_idx = host_idx   # node row, or N-sentinel for off-list
         self.req = req             # [(resource column, amount)]
         self.ports = ports         # port vocab columns (with multiplicity)
         self.pds = pds             # pd vocab columns
         self.ns_code = ns_code
-        self.svc_mask = svc_mask   # [S] bool — selector-subset match per svc
+        self.svcs = svcs           # services that select it, ascending
         self.prio = prio           # resolved pod priority (kube-preempt)
         self.name = name           # pod name (victim materialization)
         self.ns = ns               # pod namespace
+        self.labels = labels       # the pod's own (re-matched when the
+        #                            service set changes)
 
 
 class _Vocab:
@@ -130,6 +163,45 @@ class _Vocab:
         return _pow2_pad(len(self.index))
 
 
+class _ServiceIndex:
+    """Which services select a pod, found from the pod's label pairs: a pair
+    leads to the services whose selector holds it (in the pod's namespace,
+    or in none), and a service selects the pod when as many of the pod's
+    pairs led to it as its selector has. O(the pod's labels x the services
+    that share a pair), not O(services). Built once a service set; never
+    written afterwards, so checkpoints share it."""
+
+    __slots__ = ("by_pair", "size")
+
+    def __init__(self, services: Sequence[api.Service]):
+        self.by_pair: Dict[Tuple[str, str, str], List[int]] = {}
+        self.size: List[int] = []
+        for si, s in enumerate(services):
+            selector = s.spec.selector or {}
+            self.size.append(len(selector))
+            ns = s.metadata.namespace or ""
+            for k, v in selector.items():
+                self.by_pair.setdefault((ns, k, v), []).append(si)
+
+    def match(self, namespace: str, labels: Optional[dict]
+              ) -> Tuple[int, ...]:
+        """The services, by index and ascending, whose selector (not empty)
+        is among ``labels`` and whose namespace is the pod's (a service
+        without one selects in every namespace)."""
+        if not labels or not self.by_pair:
+            return ()
+        hits: Dict[int, int] = {}
+        by_pair = self.by_pair
+        for k, v in labels.items():
+            for ns in (namespace, "") if namespace else ("",):
+                for si in by_pair.get((ns, k, v), ()):
+                    hits[si] = hits.get(si, 0) + 1
+        if not hits:
+            return ()
+        size = self.size
+        return tuple(sorted(si for si, n in hits.items() if n == size[si]))
+
+
 class IncrementalEncoder:
     def __init__(self, policy: Optional[BatchPolicy] = None):
         self.policy = policy or DEFAULT_BATCH_POLICY
@@ -140,7 +212,18 @@ class IncrementalEncoder:
                 "encode_snapshot")
         self._nodes_key: Optional[List[Tuple]] = None
         self._svc_key: Optional[List[Tuple]] = None
+        self._services: List[api.Service] = []
+        self._index = _ServiceIndex(())
         self._pods: Dict[str, _PodRec] = {}
+        # service groups, kept where they are few: (namespace code, service
+        # index) -> {node row (N: off-list): peers there}, and the same
+        # peers by zone ([A, V]) under a zone anti-affinity policy
+        self._peers: Dict[Tuple[int, int], Dict[int, int]] = {}
+        self._zone_peers: Dict[Tuple[int, int], np.ndarray] = {}
+        # the group axis of a wave (_group_bucket): the most groups one
+        # wave has named, and whether that ever passed the floor
+        self._g_seen = 0
+        self._g_wide = False
         self._ports = _Vocab()
         self._sels = _Vocab()
         self._pds = _Vocab()
@@ -157,7 +240,7 @@ class IncrementalEncoder:
         self._N = 0
         # O(changed) accounting, consumed by the tier-1 complexity guards
         # (tests/test_incremental.py): zone_writes counts single-element
-        # zone-plane updates, group_writes the group-count ones;
+        # zone-count updates, group_writes the peer-count ones;
         # evict_writes the per-band evictable-plane updates;
         # node_rebuilds the full resident-plane rebuilds
         self.op_counts: Dict[str, int] = {
@@ -292,11 +375,10 @@ class IncrementalEncoder:
         # node-plane rebuild; same V rule as snapshot_to_host_inputs
         self._zone_V = max(1, int(self._node_zone.max(initial=-1)) + 1)
 
-        # group counts get a fresh [G, N+1] layout (and the zone-count
-        # planes a matching [A, G, V] one); re-apply cached pods
-        self._grp_rows: Dict[Tuple[int, int], int] = {}
-        self._grp_cnt = np.zeros((8, N + 1), np.int32)
-        self._zone_cnt = np.zeros((A, 8, self._zone_V), np.int32)
+        # peer counts are by node row and by zone code: start over and
+        # re-apply the cached pods
+        self._peers = {}
+        self._zone_peers = {}
         # kube-preempt resident planes: [N, B, R] evictable capacity +
         # [N, B] counts over the sticky band vocabulary, plus the
         # per-node pod registry victim materialization reads
@@ -320,81 +402,131 @@ class IncrementalEncoder:
     def _set_services(self, services: Sequence[api.Service]) -> None:
         self._svc_key = [self._svc_fp(s) for s in services]
         self._services = list(services)
-        S = len(services)
-        self._svc_vocab = _Vocab()
-        sv_ij = []
-        for si, s in enumerate(services):
-            for kv in (s.spec.selector or {}).items():
-                sv_ij.append((si, self._svc_vocab.intern(kv)))
-        T = max(1, len(self._svc_vocab))
-        self._svc_req = np.zeros((max(1, S), T), bool)
-        for si, t in sv_ij:
-            self._svc_req[si, t] = True
-        self._svc_req = self._svc_req[:S]
-        self._svc_reqcnt = self._svc_req.sum(axis=1).astype(np.int32)
-        self._svc_ns = np.array(
-            [self._ns.intern(s.metadata.namespace)
-             if s.metadata.namespace else -1 for s in services],
-            np.int32) if S else np.zeros(0, np.int32)
+        self._index = _ServiceIndex(services)
 
     def _services_changed(self, services: Sequence[api.Service]) -> bool:
-        if self._svc_key is None or len(services) != len(self._svc_key):
+        """Whether the service set is another than the one indexed. The
+        store hands out the same objects until an event replaces one, so
+        the common wave compares identities and fingerprints nothing."""
+        known = self._services
+        if self._svc_key is not None and len(services) == len(known) and \
+                all(map(operator.is_, services, known)):
+            return False
+        if self._svc_key is None or len(services) != len(self._svc_key) \
+                or any(self._svc_fp(s) != k
+                       for s, k in zip(services, self._svc_key)):
             return True
-        return any(self._svc_fp(s) != k
-                   for s, k in zip(services, self._svc_key))
+        self._services = list(services)   # relisted, and the same
+        return False
 
-    def _svc_subset_mask(self, pod: api.Pod) -> np.ndarray:
-        """[S] bool: which services' selectors the pod's labels satisfy
-        (subset match; namespace checked per group row at count time)."""
-        S = len(self._services)
-        if not S:
-            return np.zeros(0, bool)
-        feat = np.zeros(self._svc_req.shape[1], bool)
-        for kv in (pod.metadata.labels or {}).items():
-            t = self._svc_vocab.index.get(kv)
-            if t is not None:
-                feat[t] = True
-        hits = (self._svc_req & feat[None, :]).sum(axis=1)
-        return (hits == self._svc_reqcnt) & (self._svc_reqcnt > 0)
-
-    def _new_group_row(self, key: Tuple[int, int]) -> int:
-        """Materialize a sticky (namespace, service) group row, backfilled
-        with every cached existing pod the group's service selects in that
-        namespace — a pod counts toward EVERY matching group, exactly as
-        the full encoder's member_exist matrix does (an existing peer is a
-        peer of any service that selects it, not just its own first)."""
-        row = self._grp_rows[key] = len(self._grp_rows)
-        self._new_epoch("column")   # backfilled from every cached pod
-        if row >= self._grp_cnt.shape[0]:
-            grown = np.zeros((_pow2_pad(row + 1), self._N + 1), np.int32)
-            grown[:self._grp_cnt.shape[0]] = self._grp_cnt
-            self._grp_cnt = grown
-            zgrown = np.zeros((self._zone_cnt.shape[0], grown.shape[0],
-                               self._zone_V), np.int32)
-            zgrown[:, :self._zone_cnt.shape[1]] = self._zone_cnt
-            self._zone_cnt = zgrown
-        ns_code, si = key
-        for rec in self._pods.values():
-            if rec.ns_code == ns_code and si < rec.svc_mask.size and \
-                    rec.svc_mask[si]:
-                self._grp_cnt[row, rec.host_idx] += 1
-                self.op_counts["group_writes"] += 1
-                self._zone_delta(row, rec.host_idx, 1)
-        return row
-
-    def _zone_delta(self, row: int, host_idx: int, d: int) -> None:
-        """Mirror one group-count update into the resident zone planes:
-        the pod on ``host_idx`` adds/removes one peer in that node's zone
-        for every anti-affinity dim. Off-list (host_idx == N) and
-        unlabeled nodes belong to no zone — exactly the nodes the former
-        per-wave one-hot contraction zeroed out."""
-        if host_idx >= self._N:
+    def _sync_services(self, services: Sequence[api.Service]) -> None:
+        """A service was added, dropped or changed: index the new set and
+        match every cached pod again. No node plane holds a service's
+        peers, so no residency epoch ends; the records are replaced, not
+        written, because checkpoints share them."""
+        if not self._services_changed(services):
             return
-        for a in range(self._node_zone.shape[0]):
+        self._set_services(services)
+        self._peers = {}
+        self._zone_peers = {}
+        for uid, old in self._pods.items():
+            rec = _PodRec(old.host_idx, old.req, old.ports, old.pds,
+                          old.ns_code, self._index.match(old.ns, old.labels),
+                          prio=old.prio, name=old.name, ns=old.ns,
+                          labels=old.labels)
+            self._pods[uid] = rec
+            on_node = self._node_pods.get(old.host_idx)
+            if on_node is not None and uid in on_node:
+                on_node[uid] = rec
+            self._count_peer(rec, 1)
+
+    def _count_peer(self, rec: _PodRec, d: int) -> None:
+        """One pod arrives at (d = 1) or leaves (d = -1) the sparse peer
+        counts of every group that selects it — a pod counts toward EVERY
+        matching group, exactly as the full encoder's member_exist matrix
+        does (an existing peer is a peer of any service that selects it,
+        not just its own first). A node's count that falls to zero goes,
+        and so does a group without peers."""
+        i = rec.host_idx
+        for si in rec.svcs:
+            key = (rec.ns_code, si)
+            at = self._peers.get(key)
+            if at is None:
+                at = self._peers[key] = {}
+            n = at.get(i, 0) + d
+            if n:
+                at[i] = n
+            else:
+                del at[i]
+                if not at:
+                    del self._peers[key]
+            self.op_counts["group_writes"] += 1
+            self._zone_delta(key, i, d)
+
+    def _zone_delta(self, key: Tuple[int, int], host_idx: int,
+                    d: int) -> None:
+        """Mirror one peer-count update into the group's zone counts: the
+        pod on ``host_idx`` adds/removes one peer in that node's zone for
+        every anti-affinity dim. Off-list (host_idx == N) and unlabeled
+        nodes belong to no zone — exactly the nodes the former per-wave
+        one-hot contraction zeroed out."""
+        A = self._node_zone.shape[0]
+        if host_idx >= self._N or not A:
+            return
+        for a in range(A):
             zv = int(self._node_zone[a, host_idx])
             if zv >= 0:
-                self._zone_cnt[a, row, zv] += d
+                zones = self._zone_peers.get(key)
+                if zones is None:
+                    zones = self._zone_peers[key] = np.zeros(
+                        (A, self._zone_V), np.int32)
+                zones[a, zv] += d
                 self.op_counts["zone_writes"] += 1
+
+    def _group_bucket(self, n_groups: int, Ppad: int) -> int:
+        """The group axis of a wave that names ``n_groups`` distinct groups
+        among ``Ppad`` (padded) pods. The floor while no wave has named
+        more than the floor; from the first that does, the pod bucket held
+        between the floor and the kernel's cap (so the group axis is a
+        function of the pod axis and adds no program shapes, and the
+        prewarm has nothing to chase); a wave that passes the cap — the
+        wave loop cuts before it (``group_cut``) — takes the pod bucket
+        whole and leaves the kernel for the scan."""
+        self._g_seen = max(self._g_seen, n_groups)
+        if n_groups > GROUP_FLOOR:
+            self._g_wide = True
+        if not self._g_wide:
+            return GROUP_FLOOR
+        G = max(GROUP_FLOOR, min(Ppad, self.group_cap()))
+        return G if n_groups <= G else _pow2_pad(n_groups)
+
+    def group_cap(self, n_nodes: Optional[int] = None) -> int:
+        """The most group rows the Pallas kernel takes at this cluster's
+        size beside the encoder's other vocabularies."""
+        from kubernetes_tpu.ops import pallas_solver
+        return pallas_solver.max_groups(
+            self._N if n_nodes is None else n_nodes,
+            max(2, len(self._resource_names)),
+            self._ports.cap // 32 or 1, self._pds.cap // 32 or 1)
+
+    def group_cut(self, pending: Sequence[api.Pod],
+                  services: Sequence[api.Service], n_nodes: int) -> int:
+        """How many of ``pending``, from the front, one wave may hold: the
+        longest prefix whose pods name no more distinct groups than the
+        kernel takes rows. All of them wherever there is no service."""
+        if not services or \
+                len(pending) <= (cap := self.group_cap(n_nodes)):
+            return len(pending)
+        self._sync_services(list(services))
+        match = self._index.match
+        seen: set = set()
+        for j, p in enumerate(pending):
+            svcs = match(p.metadata.namespace, p.metadata.labels)
+            if svcs:
+                seen.add((p.metadata.namespace, svcs[0]))
+                if len(seen) > cap:
+                    return j
+        return len(pending)
 
     # -- pod deltas ---------------------------------------------------------
     def _grow_cols(self, arr: np.ndarray, cap: int, fill=0) -> np.ndarray:
@@ -480,10 +612,12 @@ class IncrementalEncoder:
                     pds.append(self._pd_col(
                         v.source.gce_persistent_disk.pd_name))
         ns_code = self._ns.intern(pod.metadata.namespace)
-        svc_mask = self._svc_subset_mask(pod)
-        rec = _PodRec(i, req, ports, pds, ns_code, svc_mask,
+        svcs = self._index.match(pod.metadata.namespace,
+                                 pod.metadata.labels)
+        rec = _PodRec(i, req, ports, pds, ns_code, svcs,
                       prio=api.pod_priority(pod), name=pod.metadata.name,
-                      ns=pod.metadata.namespace)
+                      ns=pod.metadata.namespace,
+                      labels=pod.metadata.labels)
         self._pods[uid] = rec
         if i < self._N:
             self._touch(i)
@@ -500,12 +634,7 @@ class IncrementalEncoder:
             self._evict_cnt[i, b] += 1
             self.op_counts["evict_writes"] += 1
             self._node_pods.setdefault(i, {})[uid] = rec
-        if svc_mask.any():
-            for (g_ns, si), row in self._grp_rows.items():
-                if g_ns == ns_code and svc_mask[si]:
-                    self._grp_cnt[row, i] += 1
-                    self.op_counts["group_writes"] += 1
-                    self._zone_delta(row, i, 1)
+        self._count_peer(rec, 1)
 
     def _remove_pod(self, uid: str) -> None:
         rec = self._pods.pop(uid)
@@ -526,12 +655,7 @@ class IncrementalEncoder:
             node = self._node_pods.get(i)
             if node is not None:
                 node.pop(uid, None)
-        if rec.svc_mask.any():
-            for (g_ns, si), row in self._grp_rows.items():
-                if g_ns == rec.ns_code and rec.svc_mask[si]:
-                    self._grp_cnt[row, i] -= 1
-                    self.op_counts["group_writes"] += 1
-                    self._zone_delta(row, i, -1)
+        self._count_peer(rec, -1)
 
     # -- kube-preempt victim materialization --------------------------------
     def resident_on(self, node_idx: int):
@@ -552,13 +676,14 @@ class IncrementalEncoder:
     # un-perform them.
     _CKPT_ARRAYS = ("_cap", "_advertised", "_score_used", "_port_cnt",
                     "_pd_cnt", "_node_sel", "_extra_ok", "_score_static",
-                    "_node_zone", "_grp_cnt", "_zone_cnt", "_evict_cap",
-                    "_evict_cnt", "_svc_req", "_svc_reqcnt", "_svc_ns")
+                    "_node_zone", "_evict_cap", "_evict_cnt")
     _CKPT_LISTS = ("_nodes_key", "_svc_key", "_services", "_resource_names",
                    "_node_names", "_node_labels")
-    _CKPT_DICTS = ("_grp_rows", "_rix", "_node_index")
-    _CKPT_SCALARS = ("_N", "_band_min", "_preempt_emitted", "_zone_V")
-    _CKPT_VOCABS = ("_ports", "_sels", "_pds", "_ns", "_bands", "_svc_vocab")
+    _CKPT_DICTS = ("_rix", "_node_index")
+    # the service index is never written once built: shared, like _PodRec
+    _CKPT_SCALARS = ("_N", "_band_min", "_preempt_emitted", "_zone_V",
+                     "_index", "_g_seen", "_g_wide")
+    _CKPT_VOCABS = ("_ports", "_sels", "_pds", "_ns", "_bands")
 
     def checkpoint(self) -> dict:
         """Capture the resident planes + sticky vocabularies + per-node pod
@@ -586,6 +711,9 @@ class IncrementalEncoder:
             st[a] = dict(getattr(self, a).index)
         st["_pods"] = dict(self._pods)
         st["_node_pods"] = {i: dict(d) for i, d in self._node_pods.items()}
+        st["_peers"] = {k: dict(d) for k, d in self._peers.items()}
+        st["_zone_peers"] = {k: z.copy()
+                             for k, z in self._zone_peers.items()}
         return st
 
     def restore(self, ckpt: dict) -> None:
@@ -607,6 +735,9 @@ class IncrementalEncoder:
         self._pods = dict(ckpt["_pods"])
         self._node_pods = {i: dict(d)
                            for i, d in ckpt["_node_pods"].items()}
+        self._peers = {k: dict(d) for k, d in ckpt["_peers"].items()}
+        self._zone_peers = {k: z.copy()
+                            for k, z in ckpt["_zone_peers"].items()}
         self._new_epoch("restore")
 
     def resident_fingerprint(self) -> tuple:
@@ -628,7 +759,10 @@ class IncrementalEncoder:
         parts.append(("_pods", tuple(sorted(
             (uid, rec.host_idx, rec.prio) for uid, rec in
             self._pods.items()))))
-        parts.append(("_grp_rows", tuple(sorted(self._grp_rows.items()))))
+        parts.append(("_peers", tuple(sorted(
+            (key, tuple(sorted(at.items())))
+            for key, at in self._peers.items()))))
+        parts.append(("_services", tuple(self._svc_key or ())))
         parts.append(("scalars", self._N, self._band_min,
                       self._preempt_emitted, self._zone_V,
                       tuple(self._resource_names)))
@@ -642,14 +776,19 @@ class IncrementalEncoder:
         fill trigger (solver/prewarm.py) compares these against the
         compiled bucket so the next bucket's program compiles BEFORE
         growth crosses the boundary. Axes whose true occupancy the
-        encoder does not track are omitted — absent keys never trigger."""
-        return {
+        encoder does not track are omitted — absent keys never trigger.
+        ``G`` is the most groups one wave has named, while the group axis
+        stands at its floor; once it follows the pod bucket
+        (``_group_bucket``) it is no axis of its own and is left out."""
+        dims = {
             "Wp": len(self._ports) / 32,
             "Wd": len(self._pds) / 32,
             "Ks": len(self._sels),
-            "G": len(self._grp_rows),
             "B": len(self._bands),
         }
+        if not self._g_wide:
+            dims["G"] = self._g_seen
+        return dims
 
     # -- wave encode --------------------------------------------------------
     def encode(self, nodes: Sequence[api.Node],
@@ -660,10 +799,8 @@ class IncrementalEncoder:
         services = list(services)
         if self._nodes_changed(nodes):
             self._rebuild_nodes(nodes, existing_pods, services)
-        elif self._services_changed(services):
-            self._rebuild_nodes(nodes, existing_pods, services,
-                                why="services")
         else:
+            self._sync_services(services)
             cur = {}
             for p in existing_pods:
                 cur[p.metadata.uid] = p
@@ -693,15 +830,15 @@ class IncrementalEncoder:
         """O(changed + pending) wave encode: apply a SimpleModeler.delta
         (upserts first, then removes — see its contract) instead of
         re-walking the whole existing-pod list. Returns None — caller must
-        fall back to encode() with the full list — when the node/service
-        planes changed, or when some node's usage exceeds its capacity:
+        fall back to encode() with the full list — when the node planes
+        changed, or when some node's usage exceeds its capacity:
         the greedy fit accumulators are existing-LIST-order exact there
         (snapshot.greedy_fit_accumulators), and only the full walk carries
         that order."""
         services = list(services)
-        if self._nodes_key is None or self._nodes_changed(nodes) \
-                or self._services_changed(services):
+        if self._nodes_key is None or self._nodes_changed(nodes):
             return None
+        self._sync_services(services)
         for p in upserted:
             rec = self._pods.get(p.metadata.uid)
             host = self._node_index.get(p.status.host, self._N)
@@ -721,6 +858,57 @@ class IncrementalEncoder:
         if not (unconstrained | (self._score_used <= cap)).all():
             return None
         return self._build(None, pending_pods, pad_pods)
+
+    def _wave_groups(self, pending_pods, pod_ns: np.ndarray, Ppad: int):
+        """The service groups of one wave: each pending pod's services by
+        the index, one row for each distinct group a pod names (the FIRST
+        service that selects it, in its namespace: ServiceSpread's "just
+        use the first service", spreading.go:44), every row filled from
+        the sparse peer counts in O(its peers). -> (pod_gid [Ppad], -1
+        without a service; pod_group_member [Ppad, G]; group_counts
+        [G, N+1]; zone_counts0 [A, G, V]). A pod is a member of every row
+        whose service selects it, so its commit counts toward each."""
+        N, P = self._N, len(pending_pods)
+        with tracing.phase("wave.encode.groups", metrics.wave_parts(),
+                           "encode.groups"):
+            pod_gid = np.full(Ppad, -1, np.int32)
+            rows: Dict[Tuple[int, int], int] = {}
+            named: List[Tuple[int, int, Tuple[int, ...]]] = []
+            if self._services:
+                match = self._index.match
+                for j, p in enumerate(pending_pods):
+                    svcs = match(p.metadata.namespace, p.metadata.labels)
+                    if svcs:
+                        ns = int(pod_ns[j])
+                        pod_gid[j] = rows.setdefault((ns, svcs[0]),
+                                                     len(rows))
+                        named.append((j, ns, svcs))
+            G = self._group_bucket(len(rows), Ppad)
+            member = np.zeros((Ppad, G), bool)
+            for j, ns, svcs in named:
+                member[j, pod_gid[j]] = True
+                for si in svcs[1:]:
+                    row = rows.get((ns, si))
+                    if row is not None:
+                        member[j, row] = True
+            group_counts = np.zeros((G, N + 1), np.int32)
+            A = self._node_zone.shape[0]
+            zone_counts0 = np.zeros((A, G, self._zone_V), np.int32)
+            peered = np.zeros(G, bool)
+            for key, row in rows.items():
+                at = self._peers.get(key)
+                if at:
+                    peered[row] = True
+                    group_counts[row, np.fromiter(at, np.int64, len(at))] = \
+                        np.fromiter(at.values(), np.int32, len(at))
+                    zones = self._zone_peers.get(key)
+                    if zones is not None:
+                        zone_counts0[:, row, :] = zones
+            wave_groups().inc(by=len(rows))
+            if named:
+                peered_pods().inc(
+                    by=int(peered[[pod_gid[j] for j, _, _ in named]].sum()))
+        return pod_gid, member, group_counts, zone_counts0
 
     def _build(self, existing_pods, pending_pods, pad_pods) -> ClusterSnapshot:
         """The pending-pod pass + snapshot assembly over the resident
@@ -750,15 +938,10 @@ class IncrementalEncoder:
             pod_can_preempt = np.zeros(Ppad, bool)  # padding never preempts
             pod_names: List[str] = []
             pod_ns = np.zeros(P, np.int32)
-            feats: List[Tuple[int, int]] = []  # (pod, svc-vocab col)
             for j, p in enumerate(pending_pods):
                 meta = p.metadata
                 pod_names.append(f"{meta.namespace}/{meta.name}")
                 pod_ns[j] = self._ns.intern(meta.namespace)
-                for kv in (meta.labels or {}).items():
-                    t = self._svc_vocab.index.get(kv)
-                    if t is not None:
-                        feats.append((j, t))
                 for c in p.spec.containers:
                     for name, q in c.resources.limits.items():
                         r = self._rix.get(name)
@@ -805,37 +988,9 @@ class IncrementalEncoder:
             # the membership of a service
             constrained = pod_sel[:P].any(axis=1) | pod_ports[:P].any(axis=1)
 
-            # -- pending service groups (matmul over the sticky svc vocab) --
-            G = self._grp_cnt.shape[0]
-            pod_gid = np.full(Ppad, -1, np.int32)
-            member = np.zeros((Ppad, G), bool)
-            S = len(self._services)
-            if S and P:
-                T = self._svc_req.shape[1]
-                feat = scatter(feats, P, T).astype(np.float32)
-                hits = feat @ self._svc_req.astype(np.float32).T      # [P, S]
-                subset = hits == self._svc_reqcnt[None, :]
-                eligible = subset & (self._svc_reqcnt[None, :] > 0) & \
-                    ((self._svc_ns[None, :] == -1) |
-                     (self._svc_ns[None, :] == pod_ns[:, None]))
-                has = eligible.any(axis=1)
-                constrained |= has
-                first = np.argmax(eligible, axis=1)
-                for j in np.nonzero(has)[0]:
-                    key = (int(pod_ns[j]), int(first[j]))
-                    row = self._grp_rows.get(key)
-                    if row is None:
-                        row = self._new_group_row(key)
-                    pod_gid[j] = row
-                G = self._grp_cnt.shape[0]
-                if member.shape[1] < G:
-                    member = np.pad(member,
-                                    ((0, 0), (0, G - member.shape[1])))
-                if len(self._grp_rows):
-                    g_ns = np.array([k[0] for k in self._grp_rows], np.int32)
-                    g_si = np.array([k[1] for k in self._grp_rows], np.int64)
-                    member[:P, :len(self._grp_rows)] = \
-                        subset[:, g_si] & (pod_ns[:, None] == g_ns[None, :])
+            pod_gid, member, group_counts, zone_counts0 = \
+                self._wave_groups(pending_pods, pod_ns, Ppad)
+            constrained |= pod_gid[:P] >= 0
             constrained_pods().inc(by=int(constrained.sum()))
 
             tie = _fnv1a64_batch([pod_tie_break_key(p)
@@ -934,11 +1089,11 @@ class IncrementalEncoder:
             pod_ports=pod_ports, pod_sel=pod_sel, pod_pds=pod_pds,
             pod_host_idx=pod_host_idx, tie_hi=tie_hi, tie_lo=tie_lo,
             pod_gid=pod_gid, pod_group_member=member,
-            group_counts=self._grp_cnt.copy(),
+            group_counts=group_counts,
             pod_rid=pod_rid, pod_run_start=pod_run_start,
             score_static=self._score_static,
             node_zone=self._node_zone,
-            zone_counts0=self._zone_cnt.copy(),
+            zone_counts0=zone_counts0,
             pod_prio=pod_prio, pod_can_preempt=pod_can_preempt,
             band_prio=band_prio, evict_cap=evict_cap, evict_cnt=evict_cnt,
             policy=self.policy,

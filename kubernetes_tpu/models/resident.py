@@ -11,6 +11,14 @@ waves, in two forms, and patches both along the node axis:
 - **host form**: the scaled, narrowed, bit-packed numpy planes. A wave
   recomputes the rows the encoder says it touched (``ClusterSnapshot.
   touched_since``) and nothing else; the pod-axis planes are built whole.
+- **the group rows are the wave's own** (``group_counts``, ``ROW_FIELDS``):
+  one row for each group the wave's pending pods name, made by the encoder
+  from its sparse peer counts, on an axis that follows the pod bucket. No
+  row outlives its wave, so nothing of them is kept or patched: a wave
+  that names a group ships its rows whole, as a transfer of their own
+  beside the packed buffer (megabytes of int32 inside the byte buffer cost
+  the apply program minutes of compile for the bitcast); a wave that names
+  none takes a plane of zeros placed once a shape and never written.
 - **device form**: the same planes on the solve's device(s) — one device, or
   sharded over a mesh under ``input_shardings`` — patched by ONE jitted
   apply program a wave, which takes one packed buffer (the pod planes, the
@@ -63,17 +71,21 @@ from kubernetes_tpu.models.batch_solver import SolverInputs
 from kubernetes_tpu.parallel import mesh as pmesh
 from kubernetes_tpu.util import metrics
 
-__all__ = ["ResidentPlanes", "PATCH_FIELDS", "SMALL_FIELDS", "ROW_LADDER",
+__all__ = ["ResidentPlanes", "PATCH_FIELDS", "SMALL_FIELDS", "ROW_FIELDS",
+           "ROW_LADDER",
            "resident_waves", "resident_rows"]
 
 # node-axis planes a bind or a delete changes: patched by rows
 PATCH_FIELDS = ("fit_used", "fit_exceeded", "score_used", "node_ports",
-                "node_pds", "group_counts", "evict_cap", "evict_cnt")
+                "node_pds", "evict_cap", "evict_cnt")
 # resident planes with no node axis: small, they ride with the pod planes
 SMALL_FIELDS = ("zone_counts0", "band_prio")
+# the wave's own group rows: a transfer of their own, or the zeros placed
+# once a shape where the wave names no group
+ROW_FIELDS = ("group_counts",)
 # node planes only an epoch changes: placed once
 STATIC_FIELDS = tuple(f for f in pmesh.RESIDENT_FIELDS
-                      if f not in PATCH_FIELDS + SMALL_FIELDS)
+                      if f not in PATCH_FIELDS + SMALL_FIELDS + ROW_FIELDS)
 # dirty-row buckets of the apply program: one compile a pod bucket and
 # entry; more rows than the last re-place whole. One entry, the scheduler's
 # default wave size: on a v5e the ship and the apply take 1.9-2.1 ms at 32,
@@ -101,8 +113,8 @@ def resident_rows() -> metrics.Counter:
 def _snap_shapes(snap) -> tuple:
     return tuple(None if a is None else a.shape for a in (
         snap.cap, snap.node_ports, snap.node_sel, snap.node_pds,
-        snap.group_counts, snap.node_aff_vals, snap.node_zone,
-        snap.zone_counts0, snap.evict_cap, snap.evict_cnt, snap.band_prio))
+        snap.node_aff_vals, snap.node_zone,
+        snap.evict_cap, snap.evict_cnt, snap.band_prio))
 
 
 def _col_max(a: np.ndarray) -> np.ndarray:
@@ -197,7 +209,7 @@ class ResidentPlanes:
             return None, "sequence"
         rows = np.unique(np.asarray(touched, np.int64))
         k, H, g = len(rows), self._host, self._scales
-        N, R = H["cap"].shape
+        R = H["cap"].shape[1]
         B = H["evict_cap"].shape[1]
         P = snap.req.shape[0]
         # everything this wave brings in resource units, under one divmod:
@@ -229,12 +241,11 @@ class ResidentPlanes:
             H["fit_exceeded"][rows] = snap.fit_exceeded[rows]
             H["node_ports"][rows] = bs._pack_bits(snap.node_ports[rows])
             H["node_pds"][rows] = bs._pack_bits(snap.node_pds[rows])
-            H["group_counts"][:, rows] = snap.group_counts[:, rows]
             if B:
                 H["evict_cap"][rows] = evict_cap
                 H["evict_cnt"][rows] = snap.evict_cnt[rows]
-        # the off-list slot (column N) is no node's row: whole every wave
-        H["group_counts"][:, N] = snap.group_counts[:, N]
+        # the group rows are this wave's own (the snapshot made them anew)
+        H["group_counts"] = np.ascontiguousarray(snap.group_counts)
         H.update(bs.host_small_planes(snap, H["zone_idx"]))
         self._seq = snap.resident_seq
         if self._dev_rows is not None:
@@ -336,10 +347,11 @@ def place_whole(planes: dict, mesh=None) -> Tuple[_Device, int]:
 def apply_rows(dev: _Device, host: SolverInputs, rows: np.ndarray,
                want: Optional[int] = None) -> Tuple[SolverInputs, int]:
     """One wave onto the device form: pack the pod planes, the small
-    planes, the off-list column and ``rows`` of every patched plane (from
-    ``host``, whose node planes are already level) into one buffer, ship
-    it, run the apply program. -> (the wave's device SolverInputs, bytes
-    shipped). ``dev.patch`` is replaced by the program's outputs."""
+    planes and ``rows`` of every patched plane (from ``host``, whose node
+    planes are already level) into one buffer, ship it, run the apply
+    program; the wave's group rows go beside it (``ship_group_rows``).
+    -> (the wave's device SolverInputs, bytes shipped). ``dev.patch`` is
+    replaced by the program's outputs."""
     if not len(rows):
         rows = np.zeros(1, np.int64)    # row 0 onto itself: nothing changes
     want = want or next(b for b in ROW_LADDER if b >= len(rows))
@@ -351,21 +363,46 @@ def apply_rows(dev: _Device, host: SolverInputs, rows: np.ndarray,
         dev.mesh, PartitionSpec()))
     with pmesh.donation_warnings_scoped():
         patched, rest = program(tuple(dev.patch[f] for f in names), placed)
+    group_rows, crossed = ship_group_rows(host, dev.mesh)
     dev.patch = dict(zip(names, patched))
     dev.xla_owned = True
-    return SolverInputs(**dev.static, **dev.patch,
+    return SolverInputs(**dev.static, **dev.patch, group_counts=group_rows,
                         **dict(zip(pmesh.WAVE_FIELDS + SMALL_FIELDS, rest))
-                        ), int(buf.nbytes)
+                        ), int(buf.nbytes) + crossed
+
+
+def ship_group_rows(host: SolverInputs, mesh) -> Tuple[jax.Array, int]:
+    """The wave's ``group_counts`` on the device(s), padded to the mesh and
+    replicated there as ``input_shardings`` has it -> (the plane, bytes
+    that crossed). A wave in which some pod has a service ships its rows;
+    every row of a wave that names no group is zeros, and that plane is
+    placed once a shape and handed to every such wave (no program writes
+    or donates a ``group_counts`` it is given)."""
+    n = int(host.cap.shape[0])
+    pad = 0 if mesh is None else pmesh._pad_width(n, mesh.shape["nodes"])
+    if not (host.pod_gid >= 0).any():
+        return _zero_rows(host.group_counts.shape[0], n + 1 + pad, mesh), 0
+    rows = pmesh.pad_plane("group_counts", host.group_counts, pad)
+    return _place_rows(rows, mesh), int(rows.nbytes)
+
+
+def _place_rows(rows: np.ndarray, mesh) -> jax.Array:
+    return jax.device_put(rows, mesh and pmesh.input_shardings(
+        mesh).group_counts)
+
+
+@functools.lru_cache(maxsize=64)
+def _zero_rows(G: int, width: int, mesh) -> jax.Array:
+    return _place_rows(np.zeros((G, width), np.int32), mesh)
 
 
 def wave_arrays(host: SolverInputs, names: tuple, rows: np.ndarray,
                 want: int) -> list:
-    """What one wave ships, in the order the apply program unpacks it: the
-    pod planes, the small planes, the off-list column of ``group_counts``,
-    the values of ``rows`` in each plane of ``names``, and ``rows`` —
-    brought to ``want`` by repeating the last (``pad_rows_to``)."""
+    """What one wave packs, in the order the apply program unpacks it:
+    the pod planes, the small planes, the values of ``rows`` in each plane
+    of ``names``, and ``rows`` — brought to ``want`` by repeating the last
+    (``pad_rows_to``)."""
     arrays = [getattr(host, f) for f in pmesh.WAVE_FIELDS + SMALL_FIELDS]
-    arrays.append(host.group_counts[:, host.cap.shape[0]])
     rows = pmesh.pad_rows_to(rows, rows, want)[0]
     arrays += [np.take(getattr(host, f), rows, axis=pmesh.PAD_SPEC[f][0])
                for f in names]
@@ -378,23 +415,17 @@ def _apply_program(spec: tuple, names: tuple, n: int, mesh, donate: bool):
     """The apply program of one (pod bucket, row bucket, node-plane shapes,
     arm): ``fn(patch planes, packed buffer) -> (patched planes, pod planes
     + small planes)``. ``spec`` lays the buffer out as ``apply_rows``
-    packs it; ``n`` is the real node count (the off-list column of
-    ``group_counts``, mesh padding or not). On a mesh the planes come in
-    and go out under ``input_shardings``: nothing is resharded on entry to
+    packs it; ``n`` is the real node count (part of the key: the planes'
+    shapes are not in ``spec``). On a mesh the planes come in and go out
+    under ``input_shardings``: nothing is resharded on entry to
     ``sharded_program``."""
     n_rest = len(pmesh.WAVE_FIELDS + SMALL_FIELDS)
 
     def apply(patch, buf):
         parts = bs.unpack_arrays(buf, spec)
-        rest, offlist = parts[:n_rest], parts[n_rest]
-        vals, rows = parts[n_rest + 1:-1], parts[-1]
-        out = []
-        for f, base, v in zip(names, patch, vals):
-            base = pmesh.scatter_rows(base, rows, v, pmesh.PAD_SPEC[f][0])
-            if f == "group_counts":
-                base = base.at[:, n].set(offlist)
-            out.append(base)
-        return tuple(out), rest
+        rest, vals, rows = parts[:n_rest], parts[n_rest:-1], parts[-1]
+        return tuple(pmesh.scatter_rows(base, rows, v, pmesh.PAD_SPEC[f][0])
+                     for f, base, v in zip(names, patch, vals)), rest
 
     if mesh is None:
         return jax.jit(apply, donate_argnums=(0,) if donate else ())

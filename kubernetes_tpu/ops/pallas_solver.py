@@ -33,7 +33,9 @@ state (including anchors) at each scheduling-unit start and a failing
 member rolls the whole run back — solve_jit's gang_step, with the
 checkpoint in a second set of VMEM planes. Fallbacks to the XLA scan:
 waves whose counts could reach 2^15 (the limb domains), >32640 nodes,
->4 affinity labels, or int64 resource planes.
+>4 affinity labels, more group rows than ``max_groups`` (membership rides
+nine 31-bit mask lanes of the pod row; a pod's own counts row is read by a
+dynamic index), or int64 resource planes.
 
 ref: pkg/scheduler/generic_scheduler.go:54-128 (the serial loop being
 batched), plugin/pkg/scheduler/scheduler.go:90-119 (commit-per-decision).
@@ -52,7 +54,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from kubernetes_tpu.models.policy import BatchPolicy
 
-__all__ = ["eligible", "solve_pallas"]
+__all__ = ["eligible", "max_groups", "solve_pallas"]
 
 LANES = 128
 NEG = -1
@@ -63,14 +65,17 @@ _PORTS0 = 8        # Wp port bitmask words (bitcast u32->i32)
 _PDS0 = 16         # Wd pd bitmask words
 _TIE0 = 24         # 4 big-endian 16-bit limbs of the FNV-1a u64
 _GID = 28
-_MEMBER = 29       # member bitmask over groups (G <= 31)
+_MEMBER = 29       # member bitmask over groups 0..30 (the first mask lane)
 _ZREQ = 30         # 1 when the pod requests zero of everything
 _START = 31        # 1 when this pod begins a new scheduling unit (gangs)
 _AFF0 = 32         # L <= 4 ServiceAffinity selector-pinned value codes
+_MEMBER1 = 40      # mask lanes 1..: groups 31..61, 62..92, ... (31 a lane,
+                   # so that every lane is a non-negative i32)
+_MEMBER_BITS = 31
 
 _MAX_R = 8
 _MAX_W = 8
-_MAX_G = 31        # member bitmask must fit a non-negative i32
+_MAX_G = 256       # group rows a wave may carry: 9 mask lanes of 31 bits
 _MAX_N = 32640     # tie-break/limb domains need counts < 2^15
 _MAX_COUNT = 1 << 15
 _MAX_A = 4         # anti-affinity labels carried as V-deep zone planes
@@ -137,7 +142,7 @@ def eligible(inp, pol: Optional[BatchPolicy], gangs: bool,
     NR = max(1, -(-N // LANES))
     Wp, Wd = inp.node_ports.shape[1], inp.node_pds.shape[1]
     state = 2 * R + Wp + Wd + G
-    planes = (state + R + 1) + state + A * V + A     # inputs+scratch+zones
+    planes = _wave_planes(R, Wp, Wd, G) + A * V + A  # + the zone planes
     planes += L                                      # node_aff_vals planes
     if pol.label_prefs:
         planes += 1                                  # static score plane
@@ -147,6 +152,32 @@ def eligible(inp, pol: Optional[BatchPolicy], gangs: bool,
     if planes * NR * LANES * 4 + anchors * G * LANES * 4 > _VMEM_BUDGET:
         return False
     return True
+
+
+def _wave_planes(R: int, Wp: int, Wd: int, G: int) -> int:
+    """Node planes every wave keeps in VMEM: the mutable state (usage twice
+    over, port and disk words, the counts rows) as input and as scratch,
+    and the capacity and the exceeded flag beside the inputs."""
+    state = 2 * R + Wp + Wd + G
+    return (state + R + 1) + state
+
+
+def max_groups(n_nodes: int, R: int = 2, Wp: int = 1, Wd: int = 1) -> int:
+    """The most group rows (a power of two, 8 to ``_MAX_G``) that a wave
+    without gangs may carry at ``n_nodes`` and stay inside ``eligible``'s
+    VMEM account, beside ``R`` resource dimensions and ``Wp`` / ``Wd`` port
+    and disk words: a counts row is a node plane twice over (the input and
+    the scratch it is committed into). What the wave loop cuts a wave at,
+    and the encoder's group bucket stops at; past ``_MAX_N`` nodes no wave
+    takes the kernel and only ``_MAX_G`` bounds the rows."""
+    if n_nodes > _MAX_N:
+        return _MAX_G
+    NR = max(1, -(-n_nodes // LANES))
+    cap = 8
+    while cap * 2 <= _MAX_G and _wave_planes(R, Wp, Wd, cap * 2) \
+            * NR * LANES * 4 <= _VMEM_BUDGET:
+        cap *= 2
+    return cap
 
 
 def _exponent(x_f32: jnp.ndarray) -> jnp.ndarray:
@@ -400,15 +431,16 @@ def _pod_step(p_global, b, pol, gangs, A, V, L, R, Wp, Wd, G, NR, PR,
                     n_dyn = n_dyn + adv.astype(jnp.int32)
             score = score + (total_sc // n_dyn) * w_lr
         if w_spread or A:
-            # counts row of the pod's first service via masked reduction
-            # (no dynamic VMEM indexing needed); gid < 0 matches no group
-            # so the totals are 0 and the scores the no-service defaults.
-            counts_row = jnp.zeros((NR, LANES), jnp.int32)
-            off = jnp.int32(0)
-            for g in range(G):
-                gm = (gid == g).astype(jnp.int32)               # 0-d
-                counts_row = counts_row + counts_ref[g] * gm
-                off = off + offl_ref[g, 0] * gm
+            # counts row of the pod's first service (a dynamic index on
+            # the scratch's leading axis; the off-list peers a scalar in
+            # SMEM); gid < 0 matches no group so the totals are 0 and the
+            # scores the no-service defaults.
+            # one row read whatever G is: the row index is clamped and
+            # the row zeroed for a pod without a service
+            has_g = (gid >= 0).astype(jnp.int32)                # 0-d
+            g_at = jnp.clip(gid, 0, G - 1)
+            counts_row = counts_ref[g_at] * has_g
+            off = offl_ref[g_at] * has_g
         if w_spread:
             max_count = jnp.maximum(jnp.max(counts_row), off)   # 0-d
             spread = _spread_score_i32(max_count, counts_row)
@@ -486,9 +518,11 @@ def _pod_step(p_global, b, pol, gangs, A, V, L, R, Wp, Wd, G, NR, PR,
         for w in range(Wd):
             pw = row[0, _PDS0 + w]
             pds_ref[w] = jnp.where(onehot, pds_ref[w] | pw, pds_ref[w])
-        member = row[0, _MEMBER]                         # 0-d
+        members = [row[0, _member_lane(lane)]            # 0-d each
+                   for lane in range(-(-G // _MEMBER_BITS))]
         for g in range(G):
-            in_g = (member >> g) & 1                     # 0-d
+            lane, bit = divmod(g, _MEMBER_BITS)
+            in_g = (members[lane] >> bit) & 1            # 0-d
             counts_ref[g] = counts_ref[g] + \
                 jnp.where(onehot, in_g, 0)
         if L:
@@ -497,7 +531,13 @@ def _pod_step(p_global, b, pol, gangs, A, V, L, R, Wp, Wd, G, NR, PR,
             # one full-plane masked write per scratch, no G-loop
             g_iota = jax.lax.broadcasted_iota(jnp.int32, (G, LANES), 0)
             l_iota = jax.lax.broadcasted_iota(jnp.int32, (G, LANES), 1)
-            in_g_rows = (jnp.right_shift(member, g_iota) & 1) != 0
+            in_g_rows = jnp.zeros((G, LANES), jnp.bool_)
+            for lane in range(-(-G // _MEMBER_BITS)):
+                bit = g_iota - lane * _MEMBER_BITS
+                here = (bit >= 0) & (bit < _MEMBER_BITS)
+                in_g_rows = in_g_rows | (here & ((jnp.right_shift(
+                    members[lane],
+                    jnp.clip(bit, 0, _MEMBER_BITS - 1)) & 1) != 0))
             newly = in_g_rows & (has_ref[:] == 0) & any_f
             newvals = jnp.zeros((G, LANES), jnp.int32)
             for l in range(L):
@@ -529,6 +569,11 @@ def _pod_step(p_global, b, pol, gangs, A, V, L, R, Wp, Wd, G, NR, PR,
         win_ref[:] = jnp.where(oh_p, jnp.where(any_f, top, NEG),
                                win_ref[:])
     return failed
+
+
+def _member_lane(lane: int) -> int:
+    """The podrow lane of the ``lane``-th membership mask."""
+    return _MEMBER if lane == 0 else _MEMBER1 + lane - 1
 
 
 def _pad_nodes(x, Npad, fill=0):
@@ -649,7 +694,7 @@ def _solve_pallas_x32(cap_in, advertises, fit_used, fit_exceeded,
         jnp.zeros((1, N + 1), jnp.int32)
     counts0 = _pad_nodes(gc[:, :N].astype(jnp.int32), Npad, 0)
     counts0 = counts0.reshape(G, NR, LANES)
-    offl = jnp.broadcast_to(gc[:, N:N + 1].astype(jnp.int32), (G, LANES))
+    offl = gc[:, N].astype(jnp.int32)                    # [G], to SMEM
     advx = plane(advertises)
     # NodeLabelPriority static score plane + ServiceAffinity planes/anchors
     extra_args, extra_specs = [], []
@@ -680,13 +725,14 @@ def _solve_pallas_x32(cap_in, advertises, fit_used, fit_exceeded,
         jax.lax.bitcast_convert_type(pod_pds, jnp.int32))
     podrow = podrow.at[:, _TIE0:_TIE0 + 4].set(tie_limbs)
     podrow = podrow.at[:, _GID].set(pod_gid.astype(jnp.int32))
-    member_bits = jnp.sum(
-        pod_group_member.astype(jnp.int32)
-        * (jnp.int32(1) << jnp.arange(pod_group_member.shape[1],
-                                      dtype=jnp.int32)
-           )[None, :], axis=1) if pod_group_member.shape[1] else \
-        jnp.zeros(P, jnp.int32)
-    podrow = podrow.at[:, _MEMBER].set(member_bits)
+    for lane in range(-(-pod_group_member.shape[1] // _MEMBER_BITS)):
+        part = pod_group_member[:, lane * _MEMBER_BITS:
+                                (lane + 1) * _MEMBER_BITS]
+        member_bits = jnp.sum(
+            part.astype(jnp.int32)
+            * (jnp.int32(1) << jnp.arange(part.shape[1], dtype=jnp.int32)
+               )[None, :], axis=1)
+        podrow = podrow.at[:, _member_lane(lane)].set(member_bits)
     podrow = podrow.at[:, _ZREQ].set(
         jnp.all(req_in == 0, axis=1).astype(jnp.int32))
     if gangs:
@@ -749,7 +795,7 @@ def _solve_pallas_x32(cap_in, advertises, fit_used, fit_exceeded,
             pl.BlockSpec(ports0.shape, lambda p: (0, 0, 0)),
             pl.BlockSpec(pds0.shape, lambda p: (0, 0, 0)),
             pl.BlockSpec((G, NR, LANES), lambda p: (0, 0, 0)),   # counts0
-            pl.BlockSpec((G, LANES), lambda p: (0, 0)),          # offl
+            pl.BlockSpec(memory_space=pltpu.SMEM),               # offl
             pl.BlockSpec(advx.shape, lambda p: (0, 0, 0)),
         ] + extra_specs + zone_specs,
         out_specs=[

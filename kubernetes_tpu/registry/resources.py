@@ -293,10 +293,15 @@ def make_rc_registry(helper: StoreHelper) -> GenericRegistry:
 
 
 class IPAllocator:
-    """Bitmap allocator over a /24-ish CIDR
-    (ref: pkg/registry/service/ip_allocator.go:29-241)."""
+    """Allocator over the portal CIDR
+    (ref: pkg/registry/service/ip_allocator.go:29-241). The default is the
+    /16 that upstream's cluster scripts give a cluster (``PORTAL_NET`` in
+    cluster/gce/config-default.sh), room for tens of thousands of
+    services; an allocation takes up the search where the last one ended
+    (a release moves that point back, so the lowest free address still
+    goes out first), so filling the range is linear and not quadratic."""
 
-    def __init__(self, cidr: str = "10.0.0.0/24"):
+    def __init__(self, cidr: str = "10.0.0.0/16"):
         import ipaddress
 
         self.network = ipaddress.ip_network(cidr)
@@ -304,6 +309,7 @@ class IPAllocator:
         self._used = set()
         # network and broadcast addresses are never handed out
         self._reserved = {self.network.network_address, self.network.broadcast_address}
+        self._next = 1        # offset into the network of the next try
 
     def allocate(self, ip: Optional[str] = None) -> str:
         import ipaddress
@@ -318,9 +324,13 @@ class IPAllocator:
                     raise errors.new_conflict("Service", ip, f"portal IP {ip} already allocated")
                 self._used.add(addr)
                 return str(addr)
-            for addr in self.network.hosts():
+            size = self.network.num_addresses
+            for step in range(size):
+                addr = self.network.network_address + \
+                    (self._next + step) % size
                 if addr not in self._used and addr not in self._reserved:
                     self._used.add(addr)
+                    self._next = (self._next + step + 1) % size
                     return str(addr)
             raise errors.new_internal_error("portal IP range exhausted")
 
@@ -328,7 +338,12 @@ class IPAllocator:
         import ipaddress
 
         with self._lock:
-            self._used.discard(ipaddress.ip_address(ip))
+            addr = ipaddress.ip_address(ip)
+            self._used.discard(addr)
+            # the lowest free address goes out first, as it always did
+            if addr in self.network:
+                self._next = min(self._next,
+                                 int(addr) - int(self.network.network_address))
 
 
 class ServiceStrategy(Strategy):

@@ -36,7 +36,8 @@ from kubernetes_tpu.models.batch_solver import (decisions_to_names,
                                                 snapshot_to_host_inputs,
                                                 solve, warm_compile,
                                                 wave_parts)
-from kubernetes_tpu.models.incremental import IncrementalEncoder
+from kubernetes_tpu.models.incremental import (GROUP_FLOOR,
+                                               IncrementalEncoder)
 from kubernetes_tpu.models.policy import BatchPolicy, batch_policy_from
 from kubernetes_tpu.models.resident import ResidentPlanes
 from kubernetes_tpu.models.snapshot import encode_snapshot
@@ -95,6 +96,11 @@ class _WaveMetrics:
         self.queue_left = reg.counter(
             "scheduler_wave_queue_left_total",
             "Pods still in the FIFO when a wave's drain ended, summed")
+        self.group_cuts = reg.counter(
+            "scheduler_wave_group_cuts_total",
+            "Waves cut short because their pods named more distinct "
+            "service groups than the kernel takes rows; the pods cut off "
+            "head the next wave")
         self.resyncs = reg.counter(
             "scheduler_wave_encode_resyncs_total",
             "Full-list encoder syncs (vs O(changed) delta waves)")
@@ -250,6 +256,9 @@ class BatchScheduler:
         # and the context handed from _drain_wave to the wave it drained
         self._wait = None
         self._drain_tctx = None
+        # the pods a wave was cut short of (_cut_at_group_cap): they head
+        # the next wave, in the order they were drained
+        self._carry: List[api.Pod] = []
         self._bind_t: "OrderedDict[str, float]" = OrderedDict()
         # deliveries that beat the arming loop: the batch bind commits
         # server-side before bind_many returns, so the reflector can
@@ -294,8 +303,11 @@ class BatchScheduler:
         since, tctx = self._wait
         with tracing.phase("wave.drain.wait", wm.part, "drain.wait",
                            parent=tctx, since=since) as ph:
+            pods: List[api.Pod] = self._carry
+            self._carry = []
             try:
-                pods: List[api.Pod] = [self.config.next_pod(timeout)]
+                if not pods:
+                    pods = [self.config.next_pod(timeout)]
             except TimeoutError:
                 # an empty tick is no wave: its wait belongs to the wave
                 # that follows (self._wait stays)
@@ -363,6 +375,7 @@ class BatchScheduler:
         try:
             nodes = c.minion_lister.list().items
             services = self.factory.service_store.list()
+            pods = self._cut_at_group_cap(pods, services, len(nodes))
             pending, starved = self._gate_gang_quorum(pods, get_existing)
         except Exception as e:
             for pod in pods:
@@ -378,6 +391,23 @@ class BatchScheduler:
         if not pending:
             return None
         return gang.order_wave(pending), nodes, services, get_existing
+
+    def _cut_at_group_cap(self, pods: List[api.Pod], services,
+                          n_nodes: int) -> List[api.Pod]:
+        """One rule for every deployment: a wave holds no more distinct
+        service groups than the kernel takes group rows
+        (``IncrementalEncoder.group_cut``). The pods cut off head the next
+        wave. A wave with a PodGroup is left whole (its members must meet
+        the quorum gate together); past the cap it takes the scan."""
+        if self._encoder is None:
+            return pods
+        keep = self._encoder.group_cut(pods, services, n_nodes)
+        if keep >= len(pods) or \
+                any(gang.gang_key(p) is not None for p in pods):
+            return pods
+        _wave_metrics().group_cuts.inc()
+        self._carry = pods[keep:]
+        return pods[:keep]
 
     # -- solving ------------------------------------------------------------
     def _encode_wave(self, nodes, pending, services, get_existing,
@@ -591,6 +621,10 @@ class BatchScheduler:
         for k, v in dims.items():
             t.setdefault(k, v)
         t["N1"] = t["N"] + 1
+        if t["G"] > GROUP_FLOOR:
+            # past its floor the group axis is no axis of its own: it
+            # follows the pod bucket (IncrementalEncoder._group_bucket)
+            t["G"] = max(GROUP_FLOOR, min(t["P"], self._encoder.group_cap()))
         warm_compile(_pad_inputs(host, t), snap.policy, snap.has_gangs,
                      peer_bound_of(host), mesh=self._mesh)
 

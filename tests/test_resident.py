@@ -205,7 +205,6 @@ def _int32_to_int64(c):
 
 REBUILDS = {
     "nodes": _node_set_changed,
-    "services": _service_changed,
     "column": _column_grown,
     "restore": _restored,
     "scale": _scale_shrinks,
@@ -227,6 +226,28 @@ def test_a_wave_that_cannot_be_patched_is_rebuilt_and_says_why(mesh, reason):
     before = _outcomes()
     c.wave(c.pods(2))                 # and the next is patched again
     assert _grown(before) == {("patched", ""): 1}
+
+
+def test_a_changed_service_set_ends_no_epoch_and_the_wave_is_patched(mesh):
+    """No node plane holds a service's peers (the group rows are the
+    wave's own), so a service added, dropped or changed re-indexes the
+    encoder and rebuilds nothing."""
+    c = _settled(mesh)
+    before = _outcomes()
+    snap = c.wave(_service_changed(c))
+    assert _grown(before) == {("patched", ""): 1}
+    _assert_device_equals_fresh(c, snap)
+    c.services = [api.Service(
+        metadata=api.ObjectMeta(name="all", namespace="default"),
+        spec=api.ServiceSpec(selector={"app": "web"}))]
+    before = _outcomes()
+    pending = c.pods(3)
+    for p in pending:
+        p.metadata.labels = {"app": "web"}
+    snap = c.wave(pending)            # names a group: its rows are shipped
+    assert _grown(before) == {("patched", ""): 1}
+    assert (snap.pod_gid[:3] == 0).all()
+    _assert_device_equals_fresh(c, snap)
 
 
 def test_more_dirty_rows_than_the_planes_are_worth_places_whole(mesh):

@@ -102,10 +102,17 @@ def _compile_kernel(snap, host, sharding):
     pytest.param(2_000, 0, {"gang_groups": 1_000, "gang_size": 8},
                  id="gang"),
     pytest.param(32_000, 1_024, {}, id="kernel_edge"),
+    # a wave of the load test's mix: as many group rows as the kernel takes
+    # at 5,000 nodes, membership over nine mask lanes, the pod's own row
+    # read by a dynamic index, the off-list peers from SMEM
+    pytest.param(5_000, 256, {"n_services": 256}, id="group_rows"),
 ])
 def test_kernel_compiles(one_chip, no_persistent_cache, n_nodes, n_pods, kw):
     snap, host = _wave(n_nodes, n_pods, **kw)
-    assert snap.has_gangs == bool(kw)
+    assert snap.has_gangs == bool(kw.get("gang_groups"))
+    if "n_services" in kw:
+        assert host.group_counts.shape[0] == kw["n_services"] == \
+            pallas_solver.max_groups(n_nodes)
     compiled = _compile_kernel(snap, host, one_chip)
     # the [P, NR, 128] int32 static mask dominates: it must fit HBM
     assert compiled.memory_analysis().temp_size_in_bytes < (8 << 30)
