@@ -45,7 +45,9 @@ from kubernetes_tpu.apiserver import fairshed as fairshed_mod
 from kubernetes_tpu.auth import AuthRequest
 from kubernetes_tpu.util import chaos
 from kubernetes_tpu.util import gcpolicy
+from kubernetes_tpu.util import interpprobe
 from kubernetes_tpu.util import metrics as metrics_pkg
+from kubernetes_tpu.util import reqparts
 from kubernetes_tpu.util import tracing
 
 _httplog = logging.getLogger("kubernetes_tpu.apiserver.httplog")
@@ -142,6 +144,8 @@ class _Handler(BaseHTTPRequestHandler):
         100-continue, 431 on oversized headers). The stdlib path builds
         an email.message.Message per request via feedparser — measurably
         the single biggest fixed cost per request under churn."""
+        # the request's first line is here: its clock starts, in ``read``
+        self._parts = reqparts.RequestParts(getattr(self, "_cpu_ns", None))
         self.command = None
         self.request_version = "HTTP/0.9"
         self.close_connection = True
@@ -229,26 +233,25 @@ class _Handler(BaseHTTPRequestHandler):
             log("%s %s" % (self.address_string(), fmt % args))
 
     def _send_json(self, code: int, payload: str, extra_headers=()):
-        body = payload.encode("utf-8")
+        self._send_text(code, payload, "application/json", extra_headers)
+
+    def _send_text(self, code: int, text: str,
+                   ctype="text/plain; charset=utf-8", extra_headers=()):
+        self._parts.mark(reqparts.SEND)
+        body = text.encode("utf-8")
         self.send_response(code)
-        self.send_header("Content-Type", "application/json")
+        self.send_header("Content-Type", ctype)
         self.send_header("Content-Length", str(len(body)))
         for k, v in extra_headers:
             self.send_header(k, v)
         self.end_headers()
         self.wfile.write(body)
-
-    def _send_text(self, code: int, text: str, ctype="text/plain; charset=utf-8"):
-        body = text.encode("utf-8")
-        self.send_response(code)
-        self.send_header("Content-Type", ctype)
-        self.send_header("Content-Length", str(len(body)))
-        self.end_headers()
-        self.wfile.write(body)
+        self._parts.mark(reqparts.OTHER)
 
     def _send_status_error(self, e: errors.StatusError, version: str,
                            extra_headers=()):
         apisrv = self.server.api  # type: ignore[attr-defined]
+        self._parts.mark(reqparts.ENCODE)   # whatever part the error ended
         try:
             payload = apisrv.scheme.encode(e.status, version)
         except Exception:
@@ -324,6 +327,8 @@ class _Handler(BaseHTTPRequestHandler):
                                    self.client_address[0], str(code))
         apisrv.metric_latency.observe(time.monotonic() - started,
                                       "options", resource)
+        self._cpu_ns = self._parts.end(apisrv.request_parts, "options",
+                                       resource)
         _httplog.log(logging.DEBUG, "OPTIONS %s -> %d from %s",
                      self.path, code, self.client_address[0])
 
@@ -394,6 +399,7 @@ class _Handler(BaseHTTPRequestHandler):
         # Always drain the body up front: unread bytes would desync the
         # keep-alive connection (next request parses them as a request line).
         raw_body = self._read_body()
+        self._parts.mark(reqparts.OTHER)
         # kube-trace: a request carrying X-KTPU-Trace joins its caller's
         # trace (the scheduler wave's commit leg, a client's list). Only
         # traced requests record spans — untraced churn traffic must not
@@ -495,6 +501,8 @@ class _Handler(BaseHTTPRequestHandler):
                 logging.INFO if code >= 500 else logging.DEBUG,
                 "%s %s -> %d (%.1fms) from %s", method, self.path, code,
                 elapsed * 1000.0, self.client_address[0])
+            self._cpu_ns = self._parts.end(
+                apisrv.request_parts, verb_label, self._metric_resource)
 
     def _version_of(self, parts) -> str:
         apisrv = self.server.api  # type: ignore[attr-defined]
@@ -669,18 +677,14 @@ class _Handler(BaseHTTPRequestHandler):
                 return self._handle_patch(version, resource, namespace, name,
                                           subresource, raw_body, user)
             if raw_body:
-                try:
-                    body_obj = apisrv.scheme.decode(
-                        raw_body, default_version=version)
-                except Exception as e:
-                    raise errors.new_bad_request(f"cannot decode body: {e}")
+                body_obj = self._decode_body(raw_body, version)
 
         verb = {"GET": "get" if name else "list", "POST": "create",
                 "PUT": "update", "DELETE": "delete"}[method]
         out = apisrv.master.dispatch(
             verb, resource, namespace=namespace, name=name, body=body_obj,
             subresource=subresource, label_selector=label_sel,
-            field_selector=field_sel, user=user)
+            field_selector=field_sel, user=user, parts=self._parts)
         code = 201 if verb == "create" else 200
         fs = apisrv.fairshed
         if fs is not None and resource == "pods":
@@ -693,6 +697,7 @@ class _Handler(BaseHTTPRequestHandler):
                 fs.note_pods_bound(1)
             elif verb == "delete" and not subresource:
                 fs.note_pod_deleted()
+        self._parts.mark(reqparts.ENCODE)
         if out is None:
             ok = api.Status(status=api.StatusSuccess, code=code)
             self._send_json(code, apisrv.scheme.encode(ok, version))
@@ -703,6 +708,16 @@ class _Handler(BaseHTTPRequestHandler):
             self._send_json(code, apisrv.encode_response(
                 out, version, written=verb in ("create", "update")))
         return code
+
+    def _decode_body(self, raw_body: bytes, version: str):
+        apisrv = self.server.api  # type: ignore[attr-defined]
+        self._parts.mark(reqparts.DECODE)
+        try:
+            return apisrv.scheme.decode(raw_body, default_version=version)
+        except Exception as e:
+            raise errors.new_bad_request(f"cannot decode body: {e}")
+        finally:
+            self._parts.mark(reqparts.OTHER)
 
     def _handle_batch_bind(self, version: str, namespace: str,
                            raw_body: bytes, user) -> int:
@@ -716,10 +731,7 @@ class _Handler(BaseHTTPRequestHandler):
         if not raw_body:
             raise errors.new_bad_request(
                 "bindings:batch requires a BindingList body")
-        try:
-            body = apisrv.scheme.decode(raw_body, default_version=version)
-        except Exception as e:
-            raise errors.new_bad_request(f"cannot decode body: {e}")
+        body = self._decode_body(raw_body, version)
         if isinstance(body, api.Binding):
             body = api.BindingList(items=[body])
         if not isinstance(body, api.BindingList):
@@ -727,11 +739,13 @@ class _Handler(BaseHTTPRequestHandler):
                 "bindings:batch body must be a BindingList")
         out = apisrv.master.bind_batch(
             namespace or api.NamespaceDefault, body, user=user,
+            parts=self._parts,
             # encode-once at commit: each bound pod's new revision is
             # serialized here, where the write lands, so the watch fan-out
             # of its CAS event is a byte copy for every watcher
             on_bound=lambda pod: apisrv.seed_frame(pod, version,
                                                    written=True))
+        self._parts.mark(reqparts.ENCODE)
         payload = apisrv.scheme.encode(out, version)
         if apisrv.fairshed is not None:
             bound = sum(1 for item in out.items if not item.error)
@@ -1090,6 +1104,8 @@ class APIServer:
         self.metric_latency = self.metrics_registry.histogram(
             "apiserver_request_latencies_seconds", "Request latency",
             ("verb", "resource"), buckets=metrics_pkg.APISERVER_BUCKETS)
+        # the same requests' wall by part of the handler, and off the CPU
+        self.request_parts = reqparts.PartTotals(self.metrics_registry)
         # the apiserver hot-path family (docs/design/apiserver-hotpath.md):
         # frame-cache effectiveness, fan-out write batching, lag drops,
         # and the batch-bind endpoint's size/latency envelope
@@ -1212,6 +1228,7 @@ class APIServer:
     def start(self) -> "APIServer":
         # the process that serves the API holds the cluster's objects
         gcpolicy.ensure()
+        interpprobe.ensure()
         self._thread = threading.Thread(target=self._httpd.serve_forever,
                                         kwargs={"poll_interval": 0.05},
                                         daemon=True, name="apiserver-http")

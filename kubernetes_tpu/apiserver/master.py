@@ -26,6 +26,7 @@ from kubernetes_tpu.registry import resources as reg
 from kubernetes_tpu.registry.generic import Context
 from kubernetes_tpu.storage.helper import StoreHelper
 from kubernetes_tpu.storage.memstore import MemStore
+from kubernetes_tpu.util import reqparts
 
 __all__ = ["Master", "MasterConfig"]
 
@@ -182,8 +183,8 @@ class Master:
             self.authorizer.authorize(user, attrs)
 
     def bind_batch(self, namespace: str, bindings: api.BindingList,
-                   user: Any = None,
-                   on_bound: Optional[Any] = None) -> api.BindingResultList:
+                   user: Any = None, on_bound: Optional[Any] = None,
+                   parts=reqparts.NO_PARTS) -> api.BindingResultList:
         """POST /api/{v}/ns/{ns}/bindings:batch — one wave of CAS binds in
         one request. Authorization and admission run ONCE against the
         request namespace (the same checks the per-pod bind path runs per
@@ -191,13 +192,15 @@ class Master:
         BindingREST.create_many, so nothing escapes the single check);
         per-item CAS semantics and partial success are preserved by
         create_many/atomic_update_many."""
-        ctx = Context(namespace=namespace, user=user)
+        ctx = Context(namespace=namespace, user=user, parts=parts)
         attrs = admission_pkg.Attributes(
             operation=admission_pkg.CREATE, resource="bindings",
             namespace=namespace, obj=bindings, user=user)
+        parts.mark(reqparts.ADMIT)
         self._authorize(user, attrs)
         self.admission.admit(attrs)
         self._authorize_victims(user, namespace, bindings.items)
+        parts.mark(reqparts.VALIDATE)
         return self.bindings.create_many(ctx, bindings, on_bound=on_bound)
 
     def _authorize_victims(self, user, namespace: str, bindings) -> None:
@@ -220,15 +223,22 @@ class Master:
                  name: str = "", body: Any = None, subresource: str = "",
                  label_selector: str = "", field_selector: str = "",
                  resource_version: str = "", user: Any = None,
-                 lag_limit: Optional[int] = None) -> Any:
+                 lag_limit: Optional[int] = None,
+                 parts=reqparts.NO_PARTS) -> Any:
         """The generic REST entry (ref: resthandler.go Get/List/Create/Update/
         Delete/Watch Resource). Verbs: get, list, create, update, delete,
-        watch. Returns API objects, or a watch.Watcher for watch."""
+        watch. Returns API objects, or a watch.Watcher for watch.
+
+        ``parts`` is the HTTP request's clock by part (util/reqparts.py):
+        every verb starts by being authorized and admitted, and marks what
+        it hands the registry — its rules, then the store's own marks, for
+        a write; the store for a read."""
         canonical, registry = self._registry(resource)
-        ctx = Context(namespace=namespace, user=user)
+        ctx = Context(namespace=namespace, user=user, parts=parts)
         attrs = admission_pkg.Attributes(
             operation="", resource=canonical, namespace=namespace, name=name,
             obj=body, user=user, subresource=subresource)
+        parts.mark(reqparts.ADMIT)
 
         if subresource:
             sub = self.subresources.get((canonical, subresource))
@@ -244,25 +254,30 @@ class Master:
                     items = list(getattr(body, "items", None) or [body])
                     if any(getattr(b, "victims", None) for b in items):
                         self._authorize_victims(user, namespace, items)
+                parts.mark(reqparts.VALIDATE)
                 return sub.create(ctx, body)
             if verb == "update":
                 attrs.operation = admission_pkg.UPDATE
                 self._authorize(user, attrs)
                 self.admission.admit(attrs)
+                parts.mark(reqparts.VALIDATE)
                 return sub.update(ctx, body)
             raise errors.new_method_not_supported(canonical, verb)
 
         if verb == "get":
             self._authorize(user, attrs)
+            parts.mark(reqparts.STORE)
             return self._stamp_self_links(canonical, registry.get(ctx, name))
         if verb == "list":
             self._authorize(user, attrs)
+            parts.mark(reqparts.STORE)
             return self._stamp_self_links(
                 canonical, registry.list(ctx, parse_selector(label_selector),
                                          parse_field_selector(field_selector)),
                 namespace=namespace)
         if verb == "watch":
             self._authorize(user, attrs)
+            parts.mark(reqparts.OTHER)
             return registry.watch(ctx, parse_selector(label_selector),
                                   parse_field_selector(field_selector),
                                   resource_version=resource_version)
@@ -271,6 +286,7 @@ class Master:
             # store events + a translate callable, driven by the
             # connection's own thread — see GenericRegistry.watch_raw
             self._authorize(user, attrs)
+            parts.mark(reqparts.OTHER)
             raw_fn = getattr(registry, "watch_raw", None)
             if raw_fn is None:
                 # non-generic storage (e.g. bindings): the plain watch verb
@@ -288,15 +304,18 @@ class Master:
             attrs.name = getattr(getattr(body, "metadata", None), "name", name)
             self._authorize(user, attrs)
             self.admission.admit(attrs)
+            parts.mark(reqparts.VALIDATE)
             return self._stamp_self_links(canonical, registry.create(ctx, body))
         if verb == "update":
             attrs.operation = admission_pkg.UPDATE
             self._authorize(user, attrs)
             self.admission.admit(attrs)
+            parts.mark(reqparts.VALIDATE)
             return self._stamp_self_links(canonical, registry.update(ctx, body))
         if verb == "delete":
             attrs.operation = admission_pkg.DELETE
             self._authorize(user, attrs)
             self.admission.admit(attrs)
+            parts.mark(reqparts.STORE)
             return registry.delete(ctx, name)
         raise errors.new_method_not_supported(canonical, verb)

@@ -61,8 +61,9 @@ def build_manager(opts):
 def controller_manager_server(argv: List[str],
                               ready: Optional[threading.Event] = None,
                               stop: Optional[threading.Event] = None) -> int:
-    from kubernetes_tpu.util import gcpolicy
+    from kubernetes_tpu.util import gcpolicy, interpprobe
     gcpolicy.ensure()
+    interpprobe.ensure()
     try:
         opts = build_parser().parse_args(argv)
         manager = build_manager(opts)

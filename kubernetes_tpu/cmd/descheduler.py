@@ -97,8 +97,9 @@ def _descheduler_health(master: str):
 def descheduler_server(argv: List[str],
                        ready: Optional[threading.Event] = None,
                        stop: Optional[threading.Event] = None) -> int:
-    from kubernetes_tpu.util import gcpolicy
+    from kubernetes_tpu.util import gcpolicy, interpprobe
     gcpolicy.ensure()
+    interpprobe.ensure()
     try:
         opts = build_parser().parse_args(argv)
     except argparse.ArgumentError as e:

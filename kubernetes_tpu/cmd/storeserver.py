@@ -67,8 +67,9 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
-    from kubernetes_tpu.util import gcpolicy
+    from kubernetes_tpu.util import gcpolicy, interpprobe
     gcpolicy.ensure()
+    interpprobe.ensure()
     try:
         opts = build_parser().parse_args(argv)
     except argparse.ArgumentError as e:

@@ -27,6 +27,7 @@ from kubernetes_tpu.api.fields import FieldSelector
 from kubernetes_tpu.api.labels import Selector
 from kubernetes_tpu.api.meta import accessor
 from kubernetes_tpu.storage.helper import StoreHelper
+from kubernetes_tpu.util import reqparts
 from kubernetes_tpu.util import tracing
 
 __all__ = ["Context", "Strategy", "GenericRegistry", "default_attr_func"]
@@ -46,13 +47,16 @@ def _next_uid() -> str:
 
 @dataclass
 class Context:
-    """Request context (ref: pkg/api/context.go): namespace + caller identity."""
+    """Request context (ref: pkg/api/context.go): namespace + caller identity,
+    and the HTTP request's clock by part, for the boundaries crossed down
+    here (util/reqparts.py)."""
 
     namespace: str = ""
     user: Optional[Any] = None
+    parts: Any = reqparts.NO_PARTS
 
     def with_namespace(self, ns: str) -> "Context":
-        return Context(namespace=ns, user=self.user)
+        return Context(namespace=ns, user=self.user, parts=self.parts)
 
 
 class Strategy:
@@ -160,7 +164,7 @@ class GenericRegistry:
         with tracing.child_span("store.create", kind=self.kind):
             return self.helper.create_obj(
                 self.key(ctx.with_namespace(m.namespace), m.name),
-                obj, ttl=ttl)
+                obj, ttl=ttl, parts=ctx.parts)
 
     def get(self, ctx: Context, name: str) -> Any:
         return self.helper.extract_obj(self.key(ctx, name), self.kind, name)
@@ -199,7 +203,7 @@ class GenericRegistry:
             m.resource_version = accessor.resource_version(old)
         ttl = self.ttl_func(obj) if self.ttl_func else None
         with tracing.child_span("store.update", kind=self.kind):
-            return self.helper.set_obj(key, obj, ttl=ttl)
+            return self.helper.set_obj(key, obj, ttl=ttl, parts=ctx.parts)
 
     def delete(self, ctx: Context, name: str) -> api.Status:
         self.helper.delete_obj(self.key(ctx, name), self.kind, name)

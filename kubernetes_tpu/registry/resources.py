@@ -27,6 +27,7 @@ from kubernetes_tpu.api import validation
 from kubernetes_tpu.api.meta import accessor
 from kubernetes_tpu.registry.generic import Context, GenericRegistry, Strategy
 from kubernetes_tpu.storage.helper import StoreHelper
+from kubernetes_tpu.util import reqparts
 from kubernetes_tpu.util import tracing
 
 __all__ = [
@@ -146,7 +147,8 @@ class BindingREST:
             return api.Status(status=api.StatusSuccess)
         key = self.pods.key(ctx, name)
         self.pods.helper.atomic_update(key, api.Pod,
-                                       self._assign_fn(name, binding.host))
+                                       self._assign_fn(name, binding.host),
+                                       parts=ctx.parts)
         return api.Status(status=api.StatusSuccess)
 
     def create_many(self, ctx: Context, bindings: api.BindingList,
@@ -215,9 +217,11 @@ class BindingREST:
             slot_map.append(i)
         with tracing.child_span("store.bind_batch", bindings=len(updates),
                                 evict_binds=len(evict_items)):
-            outcomes = self.pods.helper.atomic_update_many(api.Pod, updates)
+            outcomes = self.pods.helper.atomic_update_many(
+                api.Pod, updates, parts=ctx.parts)
             evict_outcomes = self.pods.helper.atomic_bind_evict_many(
-                api.Pod, evict_items) if evict_items else []
+                api.Pod, evict_items, parts=ctx.parts) if evict_items else []
+        ctx.parts.mark(reqparts.ENCODE)     # on_bound seeds the frames
         for i, oc in zip(slot_map + evict_slots,
                          list(outcomes) + list(evict_outcomes)):
             if isinstance(oc, errors.StatusError):

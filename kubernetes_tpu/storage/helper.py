@@ -19,6 +19,7 @@ from kubernetes_tpu import watch as watchpkg
 from kubernetes_tpu.api import errors
 from kubernetes_tpu.api.meta import accessor
 from kubernetes_tpu.util import metrics
+from kubernetes_tpu.util import reqparts
 from kubernetes_tpu.storage.memstore import (
     ErrCASConflict,
     ErrIndexOutdated,
@@ -195,20 +196,32 @@ class StoreHelper:
         return wire
 
     # -- CRUD ---------------------------------------------------------------
-    def create_obj(self, key: str, obj: Any, ttl: Optional[float] = None) -> Any:
+    # The write verbs take the HTTP request's clock by part (``parts``,
+    # util/reqparts.py) and mark what only they can tell apart: the walk
+    # (``walk``), and the store with the reads before it and ``_landed``
+    # after it (``store``). They leave it at ``other``.
+    def create_obj(self, key: str, obj: Any, ttl: Optional[float] = None,
+                   parts=reqparts.NO_PARTS) -> Any:
         """ref: etcd_helper.go:205 CreateObj."""
+        parts.mark(reqparts.WALK)
         encoded, walked = self._walk(obj)
+        parts.mark(reqparts.STORE)
         try:
             kv = self.store.create(key, encoded, ttl=ttl)
         except ErrKeyExists:
             raise errors.new_already_exists(accessor.kind(obj), accessor.name(obj))
-        return self._landed(kv, *walked)
+        out = self._landed(kv, *walked)
+        parts.mark(reqparts.OTHER)
+        return out
 
-    def set_obj(self, key: str, obj: Any, ttl: Optional[float] = None) -> Any:
+    def set_obj(self, key: str, obj: Any, ttl: Optional[float] = None,
+                parts=reqparts.NO_PARTS) -> Any:
         """Write; CAS on the object's resourceVersion when set
         (ref: etcd_helper.go:236 SetObj)."""
         rv = accessor.resource_version(obj)
+        parts.mark(reqparts.WALK)
         encoded, walked = self._walk(obj)
+        parts.mark(reqparts.STORE)
         try:
             if rv:
                 kv = self.store.compare_and_swap(key, encoded, int(rv), ttl=ttl)
@@ -218,7 +231,9 @@ class StoreHelper:
             raise errors.new_conflict(accessor.kind(obj), accessor.name(obj))
         except ErrKeyNotFound:
             raise errors.new_not_found(accessor.kind(obj), accessor.name(obj))
-        return self._landed(kv, *walked)
+        out = self._landed(kv, *walked)
+        parts.mark(reqparts.OTHER)
+        return out
 
     def extract_obj(self, key: str, kind: str = "", name: str = "") -> Any:
         """ref: etcd_helper.go:144 ExtractObj."""
@@ -247,7 +262,8 @@ class StoreHelper:
                       update_fn: Callable[[Any], Any],
                       ignore_not_found: bool = False,
                       ttl: Optional[float] = None,
-                      max_retries: int = 100) -> Any:
+                      max_retries: int = 100,
+                      parts=reqparts.NO_PARTS) -> Any:
         """Read-modify-CAS loop (ref: etcd_helper.go:311-345 AtomicUpdate).
 
         ``update_fn`` receives the current object (or a fresh ``obj_type()``
@@ -257,6 +273,7 @@ class StoreHelper:
         decrements all go through it.
         """
         for _ in range(max_retries):
+            parts.mark(reqparts.STORE)
             try:
                 kv = self.store.get(key)
                 # isolate: update_fn mutates what it is handed
@@ -268,7 +285,9 @@ class StoreHelper:
                 current = obj_type()
                 prev_index = None
             desired = update_fn(current)
+            parts.mark(reqparts.WALK)
             encoded, walked = self._walk(desired)
+            parts.mark(reqparts.STORE)
             try:
                 if prev_index is None:
                     kv = self.store.create(key, encoded, ttl=ttl)
@@ -277,12 +296,15 @@ class StoreHelper:
             except (ErrCASConflict, ErrKeyExists, ErrKeyNotFound):
                 continue  # re-read and retry
             # desired is already private (isolated decode above)
-            return self._landed(kv, *walked)
+            out = self._landed(kv, *walked)
+            parts.mark(reqparts.OTHER)
+            return out
         raise errors.new_conflict(obj_type.__name__, key, "too many CAS retries")
 
     def atomic_update_many(self, obj_type: Type,
                            updates: "list[tuple[str, Callable[[Any], Any]]]",
-                           max_retries: int = 100) -> list:
+                           max_retries: int = 100,
+                           parts=reqparts.NO_PARTS) -> list:
         """Batched read-modify-CAS over many keys — the wave-commit path
         (SURVEY §7 hard part (e)): one get_many + one compare_and_swap_many
         per round instead of two store round-trips per object. Each key is
@@ -290,13 +312,18 @@ class StoreHelper:
         the updated object or the errors.StatusError that update raised /
         the key's terminal store error. CAS-conflicted slots re-read and
         retry, exactly like atomic_update, without holding back the rest.
+        A round is three parts, not three an item: the read (``store``),
+        every item's isolating copy, update and walk (``walk``), the swap
+        and what landed (``store``).
         """
         results: list = [None] * len(updates)
         live = list(range(len(updates)))
         for _ in range(max_retries):
             if not live:
-                return results
+                break
+            parts.mark(reqparts.STORE)
             kvs = self.store.get_many([updates[i][0] for i in live])
+            parts.mark(reqparts.WALK)
             batch = []            # (slot, key, encoded, walked, prev_index)
             for i, kv in zip(live, kvs):
                 key, fn = updates[i]
@@ -311,6 +338,7 @@ class StoreHelper:
                     continue
                 encoded, walked = self._walk(desired)
                 batch.append((i, key, encoded, walked, kv.modified_index))
+            parts.mark(reqparts.STORE)
             outcomes = self.store.compare_and_swap_many(
                 [(key, enc, prev) for _, key, enc, _, prev in batch])
             live = []
@@ -324,6 +352,7 @@ class StoreHelper:
                     results[i] = errors.new_internal_error(str(oc))
                 else:
                     results[i] = self._landed(oc, *walked)
+        parts.mark(reqparts.OTHER)
         for i in live:
             results[i] = errors.new_conflict(obj_type.__name__, updates[i][0],
                                              "too many CAS retries")
@@ -331,7 +360,8 @@ class StoreHelper:
 
     def atomic_bind_evict_many(self, obj_type: Type,
                                items: "list[tuple]",
-                               max_retries: int = 100) -> list:
+                               max_retries: int = 100,
+                               parts=reqparts.NO_PARTS) -> list:
         """kube-preempt's commit primitive: per item, delete every victim
         AND apply the pod update in ONE store transaction (MemStore
         .txn_many) — all-or-nothing per item, items independent. Each
@@ -339,12 +369,16 @@ class StoreHelper:
         ``(victim_key, expected_uid)``; a victim whose uid no longer
         matches is a 409 (the world moved — the caller must re-solve),
         while an already-absent victim counts as evicted. CAS conflicts
-        re-read and retry like atomic_update_many."""
+        re-read and retry like atomic_update_many. A round is two parts:
+        its items' reads, copies and walks together (``walk``: the reads
+        are an item each and not told apart), the transaction and what
+        landed (``store``)."""
         results: list = [None] * len(items)
         live = list(range(len(items)))
         for _ in range(max_retries):
             if not live:
-                return results
+                break
+            parts.mark(reqparts.WALK)
             txn = []       # (slot, cas_ops, delete_ops, walked)
             for i in live:
                 pod_key, fn, victims = items[i]
@@ -385,7 +419,8 @@ class StoreHelper:
                             deletes, walked))
             if not txn:
                 live = []
-                return results
+                break
+            parts.mark(reqparts.STORE)
             outcomes = self.store.txn_many(
                 [(cas, dels) for _i, cas, dels, _d in txn])
             live = []
@@ -396,6 +431,7 @@ class StoreHelper:
                     results[i] = errors.new_internal_error(str(oc))
                 else:
                     results[i] = self._landed(oc[0], *walked)
+        parts.mark(reqparts.OTHER)
         for i in live:
             results[i] = errors.new_conflict(obj_type.__name__,
                                              items[i][0],
