@@ -1,6 +1,7 @@
 """The deployment under test, brought up in the process that holds the chip:
 Master + HTTP APIServer on a loopback port + ConfigFactory reflectors +
-BatchScheduler with its defaults, zero kubelets — what
+BatchScheduler with its defaults under the configuration's provider and
+Policy (``build_scheduler``), zero kubelets — what
 ``cmd/standalone.py --algorithm tpu-batch`` builds and ``chip_smoke.py``'s
 served phase proved on the chip. ``feed``, ``wait_for``,
 ``CountingRecorder``, ``CompileLog``, ``program_counts`` and
@@ -12,6 +13,7 @@ from __future__ import annotations
 
 import contextlib
 import gc
+import json
 import random
 import threading
 import time
@@ -201,6 +203,31 @@ def make_services(config: dict) -> list:
         for s in dep.services(config)]
 
 
+def build_scheduler(config: dict, factory, client, recorder):
+    """The wave scheduler of the configuration's provider and Policy, built
+    as ``cmd/scheduler.py build_scheduler`` builds it from
+    ``--algorithm-provider`` and ``--policy-config-file``, but with no
+    fallback to the serial driver: a cell has to drive the device, so a
+    Policy that the batch solver cannot model is a BenchFailure."""
+    from kubernetes_tpu.models.policy import (UnsupportedPolicy,
+                                              batch_policy_from)
+    from kubernetes_tpu.scheduler import plugins
+    from kubernetes_tpu.scheduler.tpu_batch import BatchScheduler
+
+    stated = dep.scheduler(config)
+    policy = None if stated["policy"] is None else \
+        plugins.load_policy(json.dumps(stated["policy"]))
+    try:
+        batch_policy = batch_policy_from(stated["provider"], policy)
+    except UnsupportedPolicy as e:
+        raise BenchFailure(f"the wave scheduler cannot run the "
+                           f"configuration's scheduler: {e}") from e
+    return BatchScheduler(
+        factory.create(provider=stated["provider"], policy=policy,
+                       recorder=recorder),
+        factory, client, batch_policy=batch_policy)
+
+
 class ControlPlane:
     """Bring-up and tear-down. ``sched`` is the BatchScheduler the window
     drives; ``waves`` is what its solves decided, recorded from here."""
@@ -214,7 +241,6 @@ class ControlPlane:
         from kubernetes_tpu.client.record import (AsyncEventRecorder,
                                                   EventRecorder)
         from kubernetes_tpu.scheduler.driver import ConfigFactory
-        from kubernetes_tpu.scheduler.tpu_batch import BatchScheduler
 
         self.config = config
         self.clog = clog
@@ -241,12 +267,12 @@ class ControlPlane:
                 qps=50.0, burst=100)
             self.recorder = CountingRecorder(self.events)
             self.factory = ConfigFactory(client)
-            self.sched = BatchScheduler(
-                self.factory.create(recorder=self.recorder), self.factory,
-                client)
+            self.sched = build_scheduler(config, self.factory, client,
+                                         self.recorder)
             self._record_waves()
             self._gate = threading.Event()
             self._gate.set()
+            self._gate_lock = threading.Lock()
             self._install_gate()
             wait_for(lambda: len(self.factory.node_store.list())
                      == int(config["nodes"])
@@ -294,14 +320,20 @@ class ControlPlane:
 
     def _install_gate(self) -> None:
         """A gate before the wave loop's ``next_pod``: open, it passes every
-        call through; shut (set-up only), the loop waits."""
-        config, inner, gate = self.sched.config, \
-            self.sched.config.next_pod, self._gate
+        call through; shut (set-up only), the loop waits. The look at the
+        gate and the pop are one step under ``_gate_lock``, which the shut
+        takes too: a pop that passed the open gate ends before the gate
+        shuts, however late the loop came to it."""
+        config, inner, gate, lock = self.sched.config, \
+            self.sched.config.next_pod, self._gate, self._gate_lock
 
         def gated(timeout=None):
             if not gate.wait(timeout):
                 raise TimeoutError("the warm-up gate is shut")
-            return inner(timeout)
+            with lock:
+                if not gate.is_set():
+                    raise TimeoutError("the warm-up gate is shut")
+                return inner(timeout)
 
         config.next_pod = gated
 
@@ -309,9 +341,10 @@ class ControlPlane:
     def gate_shut(self):
         """Set-up only: a warm-up round is created behind the shut gate and
         reaches the wave loop whole, as one wave."""
-        self._gate.clear()
-        # the loop may have passed the open gate and be inside its 0.2 s
-        # pop on the empty queue: let that one run out
+        with self._gate_lock:
+            self._gate.clear()
+        # the pause a round has had since the shut gate was a sleep that let
+        # a pop begun before it run out: set-up keeps its length
         time.sleep(0.25)
         try:
             yield

@@ -25,9 +25,13 @@ def load_reference(config: dict):
 
 def replay(ref, config: dict, waves: list, pod_of: dict, solve=None) -> list:
     """The reference's (host, score) for every pod of every wave.
-    ``pod_of``: {pod name: (uid, its pod template)}; the nodes and the
-    services are the configuration's own, each as it states them."""
-    cluster = ref.Cluster(dep.nodes_of(config), dep.services(config))
+    ``pod_of``: {pod name: (uid, its pod template)}; the nodes, the
+    services and the scheduler's Policy, where it states one, are the
+    configuration's own, each as it states them."""
+    policy = dep.scheduler(config)["policy"]
+    stated = {} if policy is None else {"policy": policy}
+    cluster = ref.Cluster(dep.nodes_of(config), dep.services(config),
+                          **stated)
     solve = solve or ref.solve_wave
     return [solve(cluster, [pod_of[name] for name in w["pods"]])
             for w in waves]

@@ -21,6 +21,23 @@ Keys of a configuration (all optional; without them the one
                     "warm" (the suite's init pods: warm-up rounds only) and
                     "window" (its measured pods), both by default.
     services        [{name, namespace, selector{}}]; absent or 0: none.
+    scheduler       {provider, policy, ...}: the scheduler the deployment
+                    runs, as ``kube-scheduler --algorithm-provider`` and
+                    ``--policy-config-file`` state it. ``provider`` is
+                    "DefaultProvider" when absent; ``policy``, when
+                    present, is a Policy object inline, in exactly the JSON
+                    format that the policy file holds:
+                      {"predicates": [{name, argument?}],
+                       "priorities": [{name, weight?, argument?}]}
+                    a predicate's ``argument`` {"serviceAffinity":
+                    {labels[]}} or {"labelsPresence": {labels[], presence}},
+                    a priority's {"serviceAntiAffinity": {label}} or
+                    {"labelPreference": {label, presence}}; an entry
+                    without one names a plugin of the vocabulary below. A
+                    Policy replaces the provider's sets whole. The other
+                    keys of the block (wave size, linger) are what the
+                    program's defaults are, written down and read by
+                    nothing.
 """
 
 from __future__ import annotations
@@ -96,6 +113,80 @@ def services(config: dict) -> list:
              "namespace": s.get("namespace", config["namespace"]),
              "selector": dict(s["selector"])}
             for s in config.get("services") or []]
+
+
+PROVIDERS = ("DefaultProvider",)
+# the Policy vocabulary (upstream factory/plugins.go, defaults.go and
+# api/types.go): plugins by name, and the argument kinds that make an entry
+# of any name a plugin of their own
+PREDICATES = ("PodFitsPorts", "PodFitsResources", "NoDiskConflict",
+              "MatchNodeSelector", "HostName", "Schedulable")
+PRIORITIES = ("LeastRequestedPriority", "ServiceSpreadingPriority",
+              "EqualPriority")
+PREDICATE_ARGUMENTS = {"serviceAffinity": {"labels": list},
+                       "labelsPresence": {"labels": list, "presence": bool}}
+PRIORITY_ARGUMENTS = {"serviceAntiAffinity": {"label": str},
+                      "labelPreference": {"label": str, "presence": bool}}
+
+
+def _policy_entry(kind: str, entry, names: tuple, arguments: dict) -> None:
+    if not isinstance(entry, dict) or not isinstance(entry.get("name"), str):
+        raise ConfigError(f"policy {kind} {entry!r}: an object with a name")
+    allowed = {"name", "argument", "weight"} if kind == "priority" \
+        else {"name", "argument"}
+    extra = set(entry) - allowed
+    if extra:
+        raise ConfigError(f"policy {kind} {entry['name']!r} has "
+                          f"{sorted(extra)}")
+    if kind == "priority" and type(entry.get("weight", 1)) is not int:
+        raise ConfigError(f"policy priority {entry['name']!r} weight "
+                          f"{entry.get('weight')!r}: a whole number")
+    argument = entry.get("argument")
+    if argument is None:
+        if entry["name"] not in names:
+            raise ConfigError(f"policy {kind} {entry['name']!r} is not in the "
+                              f"vocabulary {list(names)} and has no argument "
+                              f"of {list(arguments)}")
+        return
+    if not isinstance(argument, dict) or len(argument) != 1 or \
+            next(iter(argument)) not in arguments:
+        raise ConfigError(f"policy {kind} {entry['name']!r} argument "
+                          f"{argument!r}: one of {list(arguments)}")
+    (what, value), = argument.items()
+    types = arguments[what]
+    sound = isinstance(value, dict) and not set(value) - set(types) and \
+        all(isinstance(value[k], t) for k, t in types.items() if k in value)
+    if sound and "labels" in value:
+        sound = all(isinstance(label, str) for label in value["labels"])
+    if not sound:
+        raise ConfigError(f"policy {kind} {entry['name']!r} {what} "
+                          f"{value!r}: keys of "
+                          f"{ {k: t.__name__ for k, t in types.items()} }")
+
+
+def scheduler(config: dict) -> dict:
+    """{"provider": name, "policy": the Policy object or None}, as the
+    configuration states them; a plugin outside the vocabulary, or a
+    provider there is none of, is a ConfigError."""
+    block = config.get("scheduler") or {}
+    provider = block.get("provider", PROVIDERS[0])
+    if provider not in PROVIDERS:
+        raise ConfigError(f"scheduler provider {provider!r}: of {PROVIDERS}")
+    policy = block.get("policy")
+    if policy is None:
+        return {"provider": provider, "policy": None}
+    if not isinstance(policy, dict) or \
+            set(policy) - {"kind", "apiVersion", "predicates", "priorities"}:
+        raise ConfigError(f"scheduler policy {policy!r}: a Policy object")
+    for key, kind, names, arguments in (
+            ("predicates", "predicate", PREDICATES, PREDICATE_ARGUMENTS),
+            ("priorities", "priority", PRIORITIES, PRIORITY_ARGUMENTS)):
+        entries = policy.get(key, [])
+        if not isinstance(entries, list):
+            raise ConfigError(f"scheduler policy {key} {entries!r}: a list")
+        for entry in entries:
+            _policy_entry(kind, entry, names, arguments)
+    return {"provider": provider, "policy": policy}
 
 
 def pod_plan(templates: list, phase: str, seed: int, count: int) -> list:

@@ -143,6 +143,8 @@ def run(args) -> int:
             config_path = path
         else:
             traffic_path = path
+    from benchmarks.harness import deployment as dep
+    dep.scheduler(config)      # a plugin it does not know ends it here
     for key, value in config.get("env", {}).items():
         os.environ[key] = str(value)           # pinned, not defaulted
     if args.rehearse:

@@ -4,8 +4,11 @@ Kubernetes scalability load test's mix): the committed configuration is what
 split, namespaces and selectors are the source's; what the feeder posts and
 the control plane registers for it is what PR 36's tree posted
 (``goldens/sched-load-5000n.json``); the cell rehearses end to end on the CPU
-at 500 nodes, ``serial_default`` deciding; and its four per-layer metrics
-have file, reader and cells."""
+at 500 nodes, ``serial_default`` deciding; its four per-layer metrics have
+file, reader and cells; and the policy fixture
+(``fixtures/policy-load-5000n.json``: the cell's services and pods on nodes
+labelled by region and zone, under OpenShift's default ``scheduler.json``)
+rehearses over the cell, ``serial_policy`` deciding."""
 
 import collections
 import hashlib
@@ -242,3 +245,36 @@ def test_the_cell_rehearses_at_500_nodes():
     assert set(side["programs"]) == {"pallas@cpu"}
     assert side["resident"]["window"] == \
         {"patched": side["window_waves"]}, side["resident"]
+
+
+def test_the_policy_fixture_rehearses_over_the_cell_with_serial_policy():
+    """The fixture cut to 60 nodes, ten a zone, over the load cell's
+    services and pods: ServiceAffinity takes every wave through the full
+    encoder, and every wave stays on the kernel."""
+    fixture = _load(HERE, "fixtures", "policy-load-5000n.json")
+    templates = fixture["node_templates"]
+    for t in templates:
+        t["count"] = 10
+    args = ["--config-set", "nodes=60",
+            "--config-set", f"node_templates={json.dumps(templates)}",
+            "--config-set", f"scheduler={json.dumps(fixture['scheduler'])}",
+            "--config-set", f"reference={json.dumps(fixture['reference'])}",
+            "--traffic-set", "warm_rounds=[1, 2, 4, 8, 16, 32, 64, 128]"]
+    proc = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmarks", "run.py"),
+         "--workload", CELL, "--seed", str(2 ** 31 + 4141),
+         "--seconds", "6", "--trace", "0", "--rehearse", "1",
+         "--control", "1"] + args,
+        capture_output=True, text=True, cwd=ROOT, timeout=1500)
+    lines = [ln for ln in proc.stdout.splitlines() if ln.strip()]
+    assert proc.returncode == 0 and lines, proc.stderr[-3000:]
+    res = json.loads(lines[-1])
+    compared = res["compared"]
+    control = compared.pop("control.decisions_differ")
+    assert res["correct"] is True, compared
+    assert all(v["value"] == 0 for v in compared.values())
+    assert control["value"] > 20 and res["failed"] == 0
+    side = json.loads([ln for ln in proc.stderr.splitlines()
+                       if ln.startswith("run.py: {")][-1][len("run.py: "):])
+    assert side["compared_pods"] > 500
+    assert set(side["programs"]) == {"pallas@cpu"}, side["resident"]

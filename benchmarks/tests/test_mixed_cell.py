@@ -94,19 +94,23 @@ def test_the_three_new_metrics_have_file_reader_and_cells():
     for name in NEW_METRICS:
         entry = entries[name]
         doc = _load(ROOT, "benchmarks", "metrics", name + ".json")
-        assert {k: doc[k] for k in entry} == entry
-        assert entry["workloads"] == [control, CELL]
+        # the entry's cells are BENCHMARK.json's to widen (PR 36 added its
+        # cell there); the file keeps the cells it was written with
+        same = set(entry) - {"workloads"}
+        assert {k: doc[k] for k in same} == {k: entry[k] for k in same}
+        assert {control, CELL} <= set(entry["workloads"])
         assert entry["moves"] == "pods_per_s"
         reader = importlib.import_module(
             f"benchmarks.readers.{doc['reader']}")
         assert callable(reader.read)
-    assert [m["name"] for m in bench["per_layer"]][-3:] == list(NEW_METRICS)
-    cell = bench["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) \
-        == (CELL, CONFIG, "backlog", 1)
-    # the cell reports whatever its control reports
+    cell, = [w for w in bench["workloads"] if w["name"] == CELL]
+    assert (cell["config"], cell["traffic"], cell["chips"]) \
+        == (CONFIG, "backlog", 1)
+    # the cell reports whatever its control reports, but the metrics of
+    # service groups (PR 36): this cell has no service
+    groups = {"encode_groups_ms", "wave_groups", "spread_peered_pods_share"}
     for m in bench["per_layer"]:
-        if control in m.get("workloads", []):
+        if control in m.get("workloads", []) and m["name"] not in groups:
             assert CELL in m["workloads"], m["name"]
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
     # a reader that finds no such series on the parent returns nothing or
