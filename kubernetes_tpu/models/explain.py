@@ -60,8 +60,9 @@ already failing pods.
 
 Unsupported waves (diagnosis declines, never guesses): gang waves (the
 checkpoint/rollback carry would need replaying), CheckServiceAffinity
-policies (anchor state is arrival-order dependent — the incremental
-encoder refuses them for the same reason), and all-infeasible policies
+policies (the encoders hand over each group's anchor as the wave began,
+but a group's first commit inside the wave sets it for the pods after, and
+the host-side replay below carries no anchor), and all-infeasible policies
 (no prioritizers: the serial path fails every pod before filters run).
 """
 
@@ -265,7 +266,8 @@ def explain_wave(snap: ClusterSnapshot, chosen, scores
     pol = snap.policy
     if pol.has_affinity:
         raise ExplainUnsupported(
-            "CheckServiceAffinity policies are arrival-order dependent")
+            "CheckServiceAffinity anchors set inside the wave are not "
+            "replayed")
     if pol.all_infeasible:
         raise ExplainUnsupported(
             "no prioritizers configured: every pod fails before filters")

@@ -38,11 +38,24 @@ The caller keeps the same lister-shaped interface as the full encoder —
 against its cached state by object identity + uid, so it slots into the
 BatchScheduler without plumbing watch events through the scheduler.
 
-Not supported: policies with CheckServiceAffinity labels (anchor state is
-first-peer-in-list-order dependent, so removal would need order-replay);
-construction raises ValueError and the scheduler falls back to the full
-encoder. Pod specs are treated as immutable after creation (they are, in
-the reference's API: only status/host change post-bind).
+- **ServiceAffinity anchors in commit order**: under a CheckServiceAffinity
+  policy each label has a sticky value vocabulary feeding the resident
+  ``node_aff_vals`` plane (rebuilt with the node planes, patched by no
+  wave), and each service group keeps its bound peers in the order they
+  were committed. A group's anchor is its first committed peer that is
+  still bound; when that peer leaves, the next one takes over in O(1),
+  with nothing replayed. A wave carries the anchors of its group rows and
+  its pods' selector-pinned codes (``_wave_anchors``).
+
+The anchor rule is "first committed", as ``benchmarks/references/
+serial_policy.py`` states it (upstream takes ``[0]`` of a store listing,
+which has no order). The full encoder takes the first peer of the list it
+is handed; the two agree wherever that list is in commit order, which is
+the modeler's order while no relist reorders it. Where a relist does, the
+incremental encoder keeps the first committed peer: a pod it already holds
+keeps its place, also across a node-plane rebuild. Pod specs are treated as
+immutable after creation (they are, in the reference's API: only
+status/host change post-bind).
 
 Decision equivalence (not byte equivalence — vocab order and padding
 differ) against encode_snapshot is fuzz-tested under churn in
@@ -55,6 +68,7 @@ import functools
 import itertools
 import operator
 import os
+from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -110,6 +124,14 @@ def peered_pods() -> metrics.Counter:
         "Pending pods whose service group had a committed peer when their "
         "wave was built: the pods whose ServiceSpreading term could tell "
         "nodes apart (beside scheduler_wave_pods_total)")
+
+
+def anchored_pods() -> metrics.Counter:
+    return metrics.default_registry().counter(
+        "scheduler_wave_anchored_pods_total",
+        "Pending pods whose ServiceAffinity value came from the anchor of "
+        "their service group (a committed peer still bound) when their wave "
+        "was built (beside scheduler_wave_pods_total)")
 
 
 def constrained_pods() -> metrics.Counter:
@@ -205,11 +227,6 @@ class _ServiceIndex:
 class IncrementalEncoder:
     def __init__(self, policy: Optional[BatchPolicy] = None):
         self.policy = policy or DEFAULT_BATCH_POLICY
-        if self.policy.affinity_labels:
-            raise ValueError(
-                "IncrementalEncoder does not support CheckServiceAffinity "
-                "policies (anchor state is arrival-order dependent); use "
-                "encode_snapshot")
         self._nodes_key: Optional[List[Tuple]] = None
         self._svc_key: Optional[List[Tuple]] = None
         self._services: List[api.Service] = []
@@ -220,6 +237,11 @@ class IncrementalEncoder:
         # peers by zone ([A, V]) under a zone anti-affinity policy
         self._peers: Dict[Tuple[int, int], Dict[int, int]] = {}
         self._zone_peers: Dict[Tuple[int, int], np.ndarray] = {}
+        # ServiceAffinity: a sticky value vocabulary a label, and each
+        # group's bound peers in commit order (uid -> node row; N: off-list)
+        # whose first is the group's anchor — kept under such a policy only
+        self._aff_vals = [_Vocab() for _ in self.policy.affinity_labels]
+        self._anchors: Dict[Tuple[int, int], OrderedDict] = {}
         # the group axis of a wave (_group_bucket): the most groups one
         # wave has named, and whether that ever passed the floor
         self._g_seen = 0
@@ -240,12 +262,13 @@ class IncrementalEncoder:
         self._N = 0
         # O(changed) accounting, consumed by the tier-1 complexity guards
         # (tests/test_incremental.py): zone_writes counts single-element
-        # zone-count updates, group_writes the peer-count ones;
+        # zone-count updates, group_writes the peer-count ones,
+        # anchor_writes the commit-order peer list ones;
         # evict_writes the per-band evictable-plane updates;
         # node_rebuilds the full resident-plane rebuilds
         self.op_counts: Dict[str, int] = {
-            "zone_writes": 0, "group_writes": 0, "node_rebuilds": 0,
-            "evict_writes": 0}
+            "zone_writes": 0, "group_writes": 0, "anchor_writes": 0,
+            "node_rebuilds": 0, "evict_writes": 0}
         # residency (models/resident.py): the node rows _add_pod and
         # _remove_pod wrote, in order, under a sequence number; the epoch
         # changes whenever the rows alone no longer say what changed
@@ -374,11 +397,26 @@ class IncrementalEncoder:
         # zone codes are node-label-derived, so V is fixed until the next
         # node-plane rebuild; same V rule as snapshot_to_host_inputs
         self._zone_V = max(1, int(self._node_zone.max(initial=-1)) + 1)
+        # ServiceAffinity value codes, from the sticky vocabularies
+        self._node_aff = np.full((N, len(self._aff_vals)), -1, np.int32)
+        for li, label in enumerate(self.policy.affinity_labels):
+            vocab = self._aff_vals[li]
+            for i, lbls in enumerate(self._node_labels):
+                v = lbls.get(label)
+                if v is not None:
+                    self._node_aff[i, li] = vocab.intern(v)
 
         # peer counts are by node row and by zone code: start over and
         # re-apply the cached pods
         self._peers = {}
         self._zone_peers = {}
+        self._anchors = {}
+        if self._aff_vals and self._pods:
+            # the pods already held keep their commit order (the anchors'),
+            # whatever order the list hands them in
+            rank = {uid: k for k, uid in enumerate(self._pods)}
+            existing = sorted(existing, key=lambda p: rank.get(
+                p.metadata.uid, len(rank)))
         # kube-preempt resident planes: [N, B, R] evictable capacity +
         # [N, B] counts over the sticky band vocabulary, plus the
         # per-node pod registry victim materialization reads
@@ -429,6 +467,7 @@ class IncrementalEncoder:
         self._set_services(services)
         self._peers = {}
         self._zone_peers = {}
+        self._anchors = {}
         for uid, old in self._pods.items():
             rec = _PodRec(old.host_idx, old.req, old.ports, old.pds,
                           old.ns_code, self._index.match(old.ns, old.labels),
@@ -438,15 +477,17 @@ class IncrementalEncoder:
             on_node = self._node_pods.get(old.host_idx)
             if on_node is not None and uid in on_node:
                 on_node[uid] = rec
-            self._count_peer(rec, 1)
+            self._count_peer(uid, rec, 1)
 
-    def _count_peer(self, rec: _PodRec, d: int) -> None:
+    def _count_peer(self, uid: str, rec: _PodRec, d: int) -> None:
         """One pod arrives at (d = 1) or leaves (d = -1) the sparse peer
         counts of every group that selects it — a pod counts toward EVERY
         matching group, exactly as the full encoder's member_exist matrix
         does (an existing peer is a peer of any service that selects it,
         not just its own first). A node's count that falls to zero goes,
-        and so does a group without peers."""
+        and so does a group without peers. Under a ServiceAffinity policy
+        the pod also joins the end of (leaves its place in) each group's
+        commit-order peer list, whose first is the group's anchor."""
         i = rec.host_idx
         for si in rec.svcs:
             key = (rec.ns_code, si)
@@ -462,6 +503,25 @@ class IncrementalEncoder:
                     del self._peers[key]
             self.op_counts["group_writes"] += 1
             self._zone_delta(key, i, d)
+            if self._aff_vals:
+                self._anchor_delta(key, uid, i, d)
+
+    def _anchor_delta(self, key: Tuple[int, int], uid: str, host_idx: int,
+                      d: int) -> None:
+        """One peer joins the end of (d = 1) or leaves (d = -1) the group's
+        peers in commit order; a group without peers goes. O(1) either way:
+        the anchor, the first of the list, moves on to the next peer by
+        itself."""
+        order = self._anchors.get(key)
+        if d > 0:
+            if order is None:
+                order = self._anchors[key] = OrderedDict()
+            order[uid] = host_idx
+        else:
+            del order[uid]
+            if not order:
+                del self._anchors[key]
+        self.op_counts["anchor_writes"] += 1
 
     def _zone_delta(self, key: Tuple[int, int], host_idx: int,
                     d: int) -> None:
@@ -634,7 +694,7 @@ class IncrementalEncoder:
             self._evict_cnt[i, b] += 1
             self.op_counts["evict_writes"] += 1
             self._node_pods.setdefault(i, {})[uid] = rec
-        self._count_peer(rec, 1)
+        self._count_peer(uid, rec, 1)
 
     def _remove_pod(self, uid: str) -> None:
         rec = self._pods.pop(uid)
@@ -655,7 +715,7 @@ class IncrementalEncoder:
             node = self._node_pods.get(i)
             if node is not None:
                 node.pop(uid, None)
-        self._count_peer(rec, -1)
+        self._count_peer(uid, rec, -1)
 
     # -- kube-preempt victim materialization --------------------------------
     def resident_on(self, node_idx: int):
@@ -676,7 +736,7 @@ class IncrementalEncoder:
     # un-perform them.
     _CKPT_ARRAYS = ("_cap", "_advertised", "_score_used", "_port_cnt",
                     "_pd_cnt", "_node_sel", "_extra_ok", "_score_static",
-                    "_node_zone", "_evict_cap", "_evict_cnt")
+                    "_node_zone", "_node_aff", "_evict_cap", "_evict_cnt")
     _CKPT_LISTS = ("_nodes_key", "_svc_key", "_services", "_resource_names",
                    "_node_names", "_node_labels")
     _CKPT_DICTS = ("_rix", "_node_index")
@@ -714,6 +774,8 @@ class IncrementalEncoder:
         st["_peers"] = {k: dict(d) for k, d in self._peers.items()}
         st["_zone_peers"] = {k: z.copy()
                              for k, z in self._zone_peers.items()}
+        st["_aff_vals"] = [dict(v.index) for v in self._aff_vals]
+        st["_anchors"] = {k: OrderedDict(o) for k, o in self._anchors.items()}
         return st
 
     def restore(self, ckpt: dict) -> None:
@@ -738,6 +800,13 @@ class IncrementalEncoder:
         self._peers = {k: dict(d) for k, d in ckpt["_peers"].items()}
         self._zone_peers = {k: z.copy()
                             for k, z in ckpt["_zone_peers"].items()}
+        self._aff_vals = []
+        for index in ckpt["_aff_vals"]:
+            v = _Vocab()
+            v.index = dict(index)
+            self._aff_vals.append(v)
+        self._anchors = {k: OrderedDict(o)
+                         for k, o in ckpt["_anchors"].items()}
         self._new_epoch("restore")
 
     def resident_fingerprint(self) -> tuple:
@@ -762,6 +831,9 @@ class IncrementalEncoder:
         parts.append(("_peers", tuple(sorted(
             (key, tuple(sorted(at.items())))
             for key, at in self._peers.items()))))
+        parts.append(("_anchors", tuple(sorted(
+            (key, tuple(order.items()))
+            for key, order in self._anchors.items()))))
         parts.append(("_services", tuple(self._svc_key or ())))
         parts.append(("scalars", self._N, self._band_min,
                       self._preempt_emitted, self._zone_V,
@@ -866,8 +938,9 @@ class IncrementalEncoder:
         use the first service", spreading.go:44), every row filled from
         the sparse peer counts in O(its peers). -> (pod_gid [Ppad], -1
         without a service; pod_group_member [Ppad, G]; group_counts
-        [G, N+1]; zone_counts0 [A, G, V]). A pod is a member of every row
-        whose service selects it, so its commit counts toward each."""
+        [G, N+1]; zone_counts0 [A, G, V]; the rows, (namespace code,
+        service) -> row). A pod is a member of every row whose service
+        selects it, so its commit counts toward each."""
         N, P = self._N, len(pending_pods)
         with tracing.phase("wave.encode.groups", metrics.wave_parts(),
                            "encode.groups"):
@@ -908,7 +981,49 @@ class IncrementalEncoder:
             if named:
                 peered_pods().inc(
                     by=int(peered[[pod_gid[j] for j, _, _ in named]].sum()))
-        return pod_gid, member, group_counts, zone_counts0
+        return pod_gid, member, group_counts, zone_counts0, rows
+
+    def _wave_anchors(self, pending_pods, pod_gid: np.ndarray,
+                      rows: Dict[Tuple[int, int], int], G: int, Ppad: int):
+        """CheckServiceAffinity's planes of one wave, as the full encoder
+        makes them: each pod's selector-pinned value codes (-2 where its
+        selector leaves a label open), and each group row's anchor — the
+        node values of the group's first committed peer still bound (-1
+        where that node lacks a label, -3 without an anchor). A pod whose
+        anchor is on no listed node and that leaves a label open is pinned
+        to the impossible code -100: the serial scheduler fails it there
+        (predicates.go:238-324). -> (pod_aff_static [Ppad, L],
+        anchor_vals0 [G, L], has_anchor0 [G])."""
+        N, P, L = self._N, len(pending_pods), len(self._aff_vals)
+        with tracing.phase("wave.encode.anchors", metrics.wave_parts(),
+                           "encode.anchors"):
+            pod_aff = np.full((Ppad, L), -2, np.int32)
+            labels = self.policy.affinity_labels
+            for j, p in enumerate(pending_pods):
+                selector = p.spec.node_selector
+                if selector:
+                    for li, label in enumerate(labels):
+                        v = selector.get(label)
+                        if v is not None:
+                            pod_aff[j, li] = self._aff_vals[li].intern(v)
+            anchor_vals0 = np.full((G, L), -3, np.int32)
+            has_anchor0 = np.zeros(G, bool)
+            off_list = np.zeros(G, bool)
+            for key, row in rows.items():
+                order = self._anchors.get(key)
+                if order:
+                    has_anchor0[row] = True
+                    host = next(iter(order.values()))
+                    if host < N:
+                        anchor_vals0[row] = self._node_aff[host]
+                    else:
+                        off_list[row] = True
+            gid = np.maximum(pod_gid[:P], 0)
+            anchored = (pod_gid[:P] >= 0) & has_anchor0[gid] & \
+                (pod_aff[:P] == -2).any(axis=1)
+            pod_aff[np.flatnonzero(anchored & off_list[gid]), 0] = -100
+            anchored_pods().inc(by=int(anchored.sum()))
+        return pod_aff, anchor_vals0, has_anchor0
 
     def _build(self, existing_pods, pending_pods, pad_pods) -> ClusterSnapshot:
         """The pending-pod pass + snapshot assembly over the resident
@@ -988,10 +1103,15 @@ class IncrementalEncoder:
             # the membership of a service
             constrained = pod_sel[:P].any(axis=1) | pod_ports[:P].any(axis=1)
 
-            pod_gid, member, group_counts, zone_counts0 = \
+            pod_gid, member, group_counts, zone_counts0, rows = \
                 self._wave_groups(pending_pods, pod_ns, Ppad)
             constrained |= pod_gid[:P] >= 0
             constrained_pods().inc(by=int(constrained.sum()))
+            node_aff = pod_aff = anchor_vals0 = has_anchor0 = None
+            if self._aff_vals:
+                node_aff = self._node_aff
+                pod_aff, anchor_vals0, has_anchor0 = self._wave_anchors(
+                    pending_pods, pod_gid, rows, member.shape[1], Ppad)
 
             tie = _fnv1a64_batch([pod_tie_break_key(p)
                                   for p in pending_pods])
@@ -1092,6 +1212,8 @@ class IncrementalEncoder:
             group_counts=group_counts,
             pod_rid=pod_rid, pod_run_start=pod_run_start,
             score_static=self._score_static,
+            node_aff_vals=node_aff, pod_aff_static=pod_aff,
+            anchor_vals0=anchor_vals0, has_anchor0=has_anchor0,
             node_zone=self._node_zone,
             zone_counts0=zone_counts0,
             pod_prio=pod_prio, pod_can_preempt=pod_can_preempt,

@@ -200,15 +200,11 @@ class BatchScheduler:
             # a daemon wave solves under the daemon's own --mesh; this
             # covers the in-process fallback when the daemon is away
             self.solver.fallback_mesh = self._mesh
-        try:
-            # delta-maintained node planes + sticky vocabularies: per-wave
-            # encode cost is O(changed pods), and pow-2 bucketing keeps the
-            # compiled-shape count bounded under churn
-            self._encoder = IncrementalEncoder(self.batch_policy)
-        except ValueError:
-            # CheckServiceAffinity policies are arrival-order dependent;
-            # full re-encode per wave stays authoritative
-            self._encoder = None
+        # delta-maintained node planes + sticky vocabularies: per-wave
+        # encode cost is O(changed pods), and pow-2 bucketing keeps the
+        # compiled-shape count bounded under churn — under every policy the
+        # batch solver models, CheckServiceAffinity's anchors included
+        self._encoder = IncrementalEncoder(self.batch_policy)
         # modeler changelog cursor for the O(changed) wave path; None
         # until the first full sync establishes the resident planes
         self._delta_token = None
@@ -234,7 +230,6 @@ class BatchScheduler:
         # own; the prewarm thread compiles on planes of its own
         self._resident = ResidentPlanes()
         if self.solver is None and self._using_default_solve and \
-                self._encoder is not None and \
                 os.environ.get("KTPU_PREWARM", "auto") != "off":
             from kubernetes_tpu.solver.prewarm import PrewarmController
             self._prewarm = PrewarmController(self._prewarm_compile,
@@ -399,8 +394,6 @@ class BatchScheduler:
         (``IncrementalEncoder.group_cut``). The pods cut off head the next
         wave. A wave with a PodGroup is left whole (its members must meet
         the quorum gate together); past the cap it takes the scan."""
-        if self._encoder is None:
-            return pods
         keep = self._encoder.group_cut(pods, services, n_nodes)
         if keep >= len(pods) or \
                 any(gang.gang_key(p) is not None for p in pods):
@@ -415,12 +408,8 @@ class BatchScheduler:
         wm = _wave_metrics()
         with tracing.phase("wave.encode", wm.encode, parent=tctx,
                            pods=len(pending)) as ph:
-            if self._encoder is not None:
-                snap = self._encode_incremental(nodes, pending, services,
-                                                get_existing)
-            else:
-                snap = encode_snapshot(nodes, get_existing(), pending,
-                                       services, policy=self.batch_policy)
+            snap = self._encode_incremental(nodes, pending, services,
+                                            get_existing)
         wm.note_stall(ph.wall_s)
         return snap
 
@@ -455,8 +444,7 @@ class BatchScheduler:
                     host = self._resident.host_inputs(snap)
                     self._prewarm_snap = snap
                     actual = {"P": n_pending}
-                    if self._encoder is not None:
-                        actual.update(self._encoder.fill_dims())
+                    actual.update(self._encoder.fill_dims())
                     from kubernetes_tpu.solver.service import _dims_of
                     self._prewarm.observe(actual, _dims_of(host))
                 chosen, scores = solve(snap, host=host, mesh=self._mesh,
@@ -471,24 +459,9 @@ class BatchScheduler:
             victims = [None] * len(hosts)
             if any(preempt_mod.is_preempt_score(int(s))
                    for s in scores[:len(hosts)]):
-                if self._encoder is not None:
-                    victims = preempt_mod.assign_victims(
-                        chosen, scores, snap.band_prio, n_pods=len(hosts),
-                        node_pods=self._encoder.resident_on)
-                else:
-                    # the full-encoder path has no resident pod registry
-                    # to name victims from: fail those pods back to the
-                    # queue (preemption requires the incremental encoder;
-                    # policies it cannot model keep the serial
-                    # no-preemption behavior)
-                    if not getattr(self, "_warned_preempt_encoder", False):
-                        self._warned_preempt_encoder = True
-                        _log.warning(
-                            "preemption decisions need the incremental "
-                            "encoder's pod registry; requeueing preempting "
-                            "pods (policy forces the full encoder)")
-                    hosts = [None if preempt_mod.is_preempt_score(int(s))
-                             else h for h, s in zip(hosts, scores)]
+                victims = preempt_mod.assign_victims(
+                    chosen, scores, snap.band_prio, n_pods=len(hosts),
+                    node_pods=self._encoder.resident_on)
         return _WaveDecisions(hosts, victims, t0, snap, chosen, scores)
 
     def _default_solve(self, nodes, existing, pending, services, tctx=None):

@@ -186,9 +186,192 @@ def test_existing_pod_counts_in_every_matching_group():
     assert_wave_equivalent(enc, nodes, existing, pending, services)
 
 
-def test_affinity_policy_rejected():
-    with pytest.raises(ValueError):
-        IncrementalEncoder(BatchPolicy(affinity_labels=("rack",)))
+# -- ServiceAffinity anchors (CheckServiceAffinity) ---------------------------
+
+AFFINITY = BatchPolicy(affinity_labels=("region",),
+                       anti_affinity=(("zone", 2),))
+
+
+def _region_nodes(rng, n):
+    """Two regions of zones, some nodes without a region or a zone."""
+    nodes = []
+    for i in range(n):
+        r = rng.choice(["r1", "r2"])
+        labels = {"region": r, "zone": f"{r}-{rng.choice('ab')}"}
+        if rng.random() < 0.15:
+            labels.pop("region")
+        if rng.random() < 0.1:
+            labels.pop("zone")
+        nodes.append(mk_node(f"n{i:02d}", cpu_m=rng.choice([1000, 2000]),
+                             mem=4 << 30, labels=labels))
+    return nodes
+
+
+_SERVICES = [api.Service(metadata=api.ObjectMeta(name=f"s{k}",
+                                                 namespace=ns),
+                         spec=api.ServiceSpec(port=80, selector=sel))
+             for k, (ns, sel) in enumerate([
+                 ("default", {"app": "web"}), ("default", {"app": "db"}),
+                 ("default", {"tier": "front"}), ("tenant", {"app": "web"})])]
+
+
+def _affinity_pod(rng, name):
+    kw = dict(cpu_m=rng.choice([0, 100, 300]), mem=64 << 20,
+              ns=rng.choice(["default", "default", "tenant"]))
+    if rng.random() < 0.85:
+        kw["labels"] = {"app": rng.choice(["web", "db"])}
+        if rng.random() < 0.3:
+            kw["labels"]["tier"] = "front"
+    if rng.random() < 0.15:
+        kw["node_selector"] = {"region": rng.choice(["r1", "r2", "r9"])}
+    return mk_pod(name, **kw)
+
+
+def _decide_both(enc, nodes, existing, pending, snap=None):
+    """Decisions of the incremental snapshot (``snap``, else encode())
+    and of the full encoder over the same list; they have to agree."""
+    inc = snap if snap is not None else enc.encode(nodes, existing, pending,
+                                                   _SERVICES)
+    got = decisions_to_names(inc, solve(inc)[0])
+    full = encode_snapshot(nodes, existing, pending, _SERVICES,
+                           policy=enc.policy)
+    want = decisions_to_names(full, solve(full)[0])
+    assert got == want, f"incremental={got}\nfull       ={want}"
+    return inc, got
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_fuzz_affinity_churn_equivalence(seed):
+    """CheckServiceAffinity + zone anti-affinity under churn, through both
+    of the encoder's doors (encode and encode_delta): binds, removal of a
+    group's anchor peer (the next takes over), an anchor on a node off the
+    list, an identical relist. The existing list stays in commit order, so
+    the full encoder's first-in-list peer is the first committed one."""
+    import copy
+    rng = random.Random(4200 + seed)
+    nodes = _region_nodes(rng, rng.randint(6, 14))
+    ghost = mk_pod("ghost", labels={"app": "db"}, host="gone")
+    existing = [ghost] if seed % 2 else []
+    enc = IncrementalEncoder(AFFINITY)
+    upserted, removed = [], []
+    anchored = unknown = 0
+    for wave in range(6):
+        pending = [_affinity_pod(rng, f"w{wave}p{i}")
+                   for i in range(rng.randint(1, 10))]
+        snap = None
+        if wave and rng.random() < 0.5:
+            snap = enc.encode_delta(nodes, upserted, removed, pending,
+                                    _SERVICES)
+        inc, got = _decide_both(enc, nodes, existing, pending, snap)
+        gid = inc.pod_gid[:len(pending)]
+        open_ = (inc.pod_aff_static[:len(pending)] == -2).all(axis=1)
+        anchored += int((open_ & (gid >= 0)
+                         & inc.has_anchor0[np.maximum(gid, 0)]).sum())
+        unknown += int((inc.pod_aff_static[:, 0] == -100).sum())
+        upserted, removed = [], []
+        for p, h in zip(pending, got):
+            if h:
+                p.status.host = h
+                existing.append(p)
+                upserted.append(p)
+        # the anchor of some group leaves: its first committed peer (the
+        # ghost, off the list, stays until the fourth wave)
+        peers = [p for p in existing if p is not ghost or wave == 3]
+        if peers and (rng.random() < 0.6 or wave == 3):
+            existing.remove(peers[0])
+            removed.append(peers[0])
+        if rng.random() < 0.3:
+            # a relist: new objects, the same pods in the same order
+            existing = [copy.deepcopy(p) for p in existing]
+            upserted = []
+            removed = []
+            inc, _ = _decide_both(enc, nodes, existing, [])
+    assert anchored > 0
+    if seed % 2:
+        assert unknown > 0      # the ghost anchored some db pods nowhere
+
+
+def test_the_anchor_is_the_first_committed_peer_through_a_reordering_relist():
+    """A relist that reorders the list: the full encoder takes the first
+    peer of the list; this encoder keeps the first committed, as
+    serial_policy's rule says — across a node-plane rebuild too."""
+    nodes = [mk_node("a", labels={"region": "r1", "zone": "z1"}),
+             mk_node("b", labels={"region": "r2", "zone": "z2"})]
+    svc = _SERVICES[:1]
+    first = mk_pod("first", labels={"app": "web"}, host="a")
+    second = mk_pod("second", labels={"app": "web"}, host="b")
+    enc = IncrementalEncoder(AFFINITY)
+    pend = [mk_pod("p", labels={"app": "web"})]
+    snap = enc.encode(nodes, [first, second], pend, svc)
+    assert snap.has_anchor0[0] and snap.anchor_vals0[0, 0] == \
+        snap.node_aff_vals[0, 0]
+    relisted = [second, first]
+    snap = enc.encode(nodes, relisted, pend, svc)
+    assert decisions_to_names(snap, solve(snap)[0]) == ["a"]
+    full = encode_snapshot(nodes, relisted, pend, svc, policy=AFFINITY)
+    assert decisions_to_names(full, solve(full)[0]) == ["b"]
+    nodes = nodes + [mk_node("c", labels={"region": "r2", "zone": "z3"})]
+    snap = enc.encode(nodes, relisted, pend, svc)
+    assert decisions_to_names(snap, solve(snap)[0]) == ["a"]
+    # the first committed peer leaves: the next one is the anchor (r2),
+    # and inside r2 the zone without a peer
+    snap = enc.encode(nodes, [second], pend, svc)
+    assert decisions_to_names(snap, solve(snap)[0]) == ["c"]
+
+
+def test_anchor_maintenance_is_o_changed():
+    """One bind and one delete write the commit-order peer lists once a
+    group each — whatever the number of bound peers — and rebuild no node
+    plane."""
+    rng = random.Random(9)
+    nodes = _region_nodes(rng, 24)
+    existing = [mk_pod(f"e{i}", labels={"app": "web"},
+                       host=f"n{i % 24:02d}") for i in range(200)]
+    enc = IncrementalEncoder(AFFINITY)
+    enc.encode(nodes, existing, [mk_pod("warm", labels={"app": "web"})],
+               _SERVICES)
+    rebuilds = enc.op_counts["node_rebuilds"]
+    aw0 = enc.op_counts["anchor_writes"]
+    newpod = mk_pod("np", labels={"app": "web"}, host="n03")
+    snap = enc.encode_delta(nodes, [newpod], [existing[0]],
+                            [mk_pod("pend", labels={"app": "web"})],
+                            _SERVICES)
+    assert snap is not None and snap.has_anchor0[0]
+    assert enc.op_counts["node_rebuilds"] == rebuilds
+    # one group ({"app": "web"} in default), two changed pods
+    assert enc.op_counts["anchor_writes"] - aw0 == 2
+    # the anchor moved on to the next committed peer
+    want = snap.node_aff_vals[enc._node_index[existing[1].status.host]]
+    assert np.array_equal(snap.anchor_vals0[0], want)
+    # an encoder without ServiceAffinity keeps no such list
+    plain = IncrementalEncoder()
+    plain.encode(nodes, existing, [], _SERVICES)
+    assert plain.op_counts["anchor_writes"] == 0
+
+
+def test_checkpoint_restore_carries_the_anchors():
+    rng = random.Random(17)
+    nodes = _region_nodes(rng, 12)
+    existing = [mk_pod(f"e{i}", labels={"app": rng.choice(["web", "db"])},
+                       host=f"n{rng.randrange(12):02d}") for i in range(30)]
+    enc = IncrementalEncoder(AFFINITY)
+    pending = [_affinity_pod(rng, f"p{i}") for i in range(8)]
+    snap0 = enc.encode(nodes, existing, pending, _SERVICES)
+    ckpt = enc.checkpoint()
+    # every peer of both groups leaves, then the checkpoint comes back
+    gone = [p for p in existing if p.metadata.namespace == "default"]
+    snap = enc.encode_delta(nodes, [], gone, pending, _SERVICES)
+    assert snap is not None and not snap.has_anchor0.any()
+    enc.restore(ckpt)
+    snap = enc.encode_delta(nodes, [], [], pending, _SERVICES)
+    assert np.array_equal(snap.has_anchor0, snap0.has_anchor0)
+    assert np.array_equal(snap.anchor_vals0, snap0.anchor_vals0)
+    assert np.array_equal(snap.pod_aff_static, snap0.pod_aff_static)
+    _decide_both(enc, nodes, existing, pending, snap)
+    # the checkpoint stays restorable: the same anchors a second time
+    enc.restore(ckpt)
+    assert enc.resident_fingerprint() == \
+        (enc.restore(ckpt) or enc.resident_fingerprint())
 
 
 def test_compiled_shape_count_bounded_under_churn():

@@ -126,6 +126,54 @@ def test_kernel_compiles_affinity_policy(one_chip, no_persistent_cache):
     _compile_kernel(snap, host, one_chip)
 
 
+def test_kernel_compiles_policy_cell_wave(one_chip, no_persistent_cache):
+    """A wave of ``policy-5000n-backlog`` as the incremental encoder builds
+    it: 5,000 nodes in two regions of three zones, 256 group rows (the cap
+    at 5,000 nodes) under OpenShift's default Policy — one ServiceAffinity
+    label's value plane and anchor scratches, one zone plane of six values,
+    beside the group rows, the thinnest VMEM margin a cell runs."""
+    import json
+    import os
+
+    from kubernetes_tpu.api import types as api
+    from kubernetes_tpu.api.quantity import Quantity
+    from kubernetes_tpu.models.incremental import IncrementalEncoder
+    from kubernetes_tpu.scheduler import plugins
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "sched-policy-5000n.json")) as f:
+        config = json.load(f)
+    pools = [t for t in config["node_templates"] for _ in range(t["count"])]
+    nodes = [api.Node(
+        metadata=api.ObjectMeta(name=f"node-{i:05d}",
+                                labels=dict(t["labels"])),
+        spec=api.NodeSpec(capacity={k: Quantity(v)
+                                    for k, v in t["capacity"].items()}))
+        for i, t in enumerate(pools)]
+    services = [api.Service(
+        metadata=api.ObjectMeta(name=f"s{i}", namespace="default"),
+        spec=api.ServiceSpec(port=80, selector={"name": f"s{i}"}))
+        for i in range(256)]
+    pending = [api.Pod(
+        metadata=api.ObjectMeta(name=f"p{i}", namespace="default",
+                                uid=f"u{i}", labels={"name": f"s{i}"}),
+        spec=api.PodSpec(containers=[api.Container(
+            name="c", image="i", resources=api.ResourceRequirements(
+                limits={"cpu": Quantity("10m"),
+                        "memory": Quantity("26214400")}))]))
+        for i in range(256)]
+    pol = batch_policy_from(policy=plugins.load_policy(json.dumps(
+        config["scheduler"]["policy"])))
+    snap = IncrementalEncoder(pol).encode(nodes, [], pending, services)
+    host = bs.snapshot_to_host_inputs(snap)
+    assert host.group_counts.shape[0] == 256 == \
+        pallas_solver.max_groups(len(nodes))
+    assert host.node_aff_vals.shape == (5_000, 1)
+    assert host.zone_counts0.shape == (1, 256, 6)
+    _compile_kernel(snap, host, one_chip)
+
+
 def test_scan_compiles_with_preempt_bands(one_chip, no_persistent_cache):
     """kube-preempt waves are outside the kernel's domain (eligible() is
     False for B > 0), so on the chip priority waves take the XLA scan."""
