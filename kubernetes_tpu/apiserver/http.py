@@ -42,6 +42,7 @@ from kubernetes_tpu import watch as watchpkg
 from kubernetes_tpu.api import errors
 from kubernetes_tpu.api import types as api
 from kubernetes_tpu.apiserver import fairshed as fairshed_mod
+from kubernetes_tpu.apiserver.watchwriter import WatchWriter
 from kubernetes_tpu.auth import AuthRequest
 from kubernetes_tpu.util import chaos
 from kubernetes_tpu.util import gcpolicy
@@ -846,54 +847,20 @@ class _Handler(BaseHTTPRequestHandler):
         self._send_text(200, body)
         return 200
 
-    def _translate_batch(self, batch, translate, version, ws_frames: bool):
-        """Map one drained batch of raw store events to wire byte parts.
-        Returns (parts, lagged): ``lagged`` means the bounded-lag resync
-        marker was hit — its 410 ERROR frame is the last part and the
-        stream must end. The encode (if any) happens here exactly once
-        per (revision, version); every other watcher of the same event
-        copies cached bytes."""
-        apisrv = self.server.api  # type: ignore[attr-defined]
-        idx = 2 if ws_frames else 1
-        parts = []
-        for ev in batch:
-            if ev.type == watchpkg.ERROR and ev.object is None:
-                # bounded-lag drop-to-resync marker from the store layer
-                parts.append(apisrv.lag_resync_entry(version)[idx])
-                apisrv.metric_watch_lag_drops.inc()
-                return parts, True
-            try:
-                tev = translate(ev)
-                if tev is None:
-                    continue
-                if isinstance(tev, tuple):  # fast path: (type, rv, thunk)
-                    ev_type, rv, thunk = tev
-                    parts.append(
-                        apisrv.frame_entry(ev_type, thunk, version,
-                                           rv=rv)[idx])
-                else:
-                    parts.append(apisrv.frame_entry(tev.type, tev.object,
-                                                    version)[idx])
-            except Exception as e:  # undecodable payload: surface, keep going
-                parts.append(apisrv.frame_entry(
-                    watchpkg.ERROR,
-                    errors.new_internal_error(str(e)).status, version)[idx])
-        return parts, False
-
     def _stream_watch(self, watcher: watchpkg.Watcher, translate,
                       version: str, gate_tag: str = ""):
-        """Chunked-JSON watch stream as a byte WRITER: this connection's
-        thread drains raw store events in batches, maps them through the
-        shared frame-bytes cache, and writes each batch with ONE send —
-        no per-watcher pump thread, no per-watcher encode, one syscall
-        per batch instead of four per event
-        (ref: pkg/apiserver/watch.go:62-142).
+        """Chunked-JSON watch stream (ref: pkg/apiserver/watch.go:62-142):
+        this connection's thread sends the head, then hands the stream to
+        the server's one watch writer (apiserver/watchwriter.py) and parks
+        until it ends — every chunked stream's frames are drained, mapped
+        through the shared frame-bytes cache and written by that one
+        thread, one send a stream a batch.
 
         ``gate_tag`` (the ``chaosGate`` query param) names an optional
-        chaos gate this writer parks on before draining: a test can hold
-        ONE watcher's consumer still — deterministically growing the
-        producer-side queue past lag_limit — while siblings stream
-        freely. Untagged watchers never touch the seam."""
+        chaos gate: while it is armed the writer skips this stream — a
+        test can hold ONE watcher's consumer still, deterministically
+        growing the producer-side queue past lag_limit, while siblings
+        stream freely. Untagged streams never touch the seam."""
         from kubernetes_tpu.util import websocket as ws
 
         if ws.wants_websocket(self.headers):
@@ -915,32 +882,10 @@ class _Handler(BaseHTTPRequestHandler):
         ticket = getattr(self, "_fs_ticket", None)
         if ticket is not None:
             ticket.release()
-        tracing.role("watch_send")   # this thread streams from here on
         try:
-            lagged = False
-            while not lagged:
-                if gate_tag:
-                    chaos.gate_if_armed("apiserver.watch.write." + gate_tag)
-                batch = watcher.next_batch(
-                    linger=apisrv.watch_write_linger)
-                if batch is None:
-                    break
-                with tracing.phase("watch.send", traced=False):
-                    t0 = time.monotonic()
-                    parts, lagged = self._translate_batch(
-                        batch, translate, version, ws_frames=False)
-                    if parts:
-                        apisrv.metric_fanout_frames.observe(len(parts))
-                        self.wfile.write(b"".join(parts))
-                        self.wfile.flush()
-                        apisrv.metric_fanout_seconds.observe(
-                            time.monotonic() - t0)
-            self.wfile.write(b"0\r\n\r\n")
-            self.wfile.flush()
-        except (BrokenPipeError, ConnectionResetError, socket.timeout):
-            pass
+            apisrv.watch_writer.serve(watcher, translate, version,
+                                      self.connection, gate_tag)
         finally:
-            tracing.role("http")
             watcher.stop()
             apisrv.untrack_watcher(watcher)
             self.close_connection = True
@@ -1000,8 +945,8 @@ class _Handler(BaseHTTPRequestHandler):
                 if batch is None:
                     break
                 t0 = time.monotonic()
-                parts, lagged = self._translate_batch(batch, translate,
-                                                      version, ws_frames=True)
+                parts, lagged = apisrv.translate_batch(batch, translate,
+                                                       version, ws_frames=True)
                 if parts:
                     apisrv.metric_fanout_frames.observe(len(parts))
                     with wlock:
@@ -1103,10 +1048,11 @@ class APIServer:
         # (the scheduler's own reflectors) must ride the queue, while a
         # watcher minutes behind gets the 410 and re-lists.
         self.watch_lag_limit = watch_lag_limit or None
-        # fan-out write linger: accumulate this long after a batch's
-        # first event before draining+writing, so a steady event stream
-        # costs each watcher one wakeup and one syscall per BATCH, not
-        # per event (see Watcher.next_batch)
+        # fan-out write linger: the watch writer sleeps this long after
+        # its first ready stream before draining+writing, so a steady
+        # event stream costs one wakeup and one syscall per stream per
+        # BATCH, not per event, and every stream with events in the
+        # window shares the one wakeup (apiserver/watchwriter.py)
         self.watch_write_linger = 0.004
         # CORS origin allow-list, each entry a regex (ref: handlers.go CORS
         # + --cors_allowed_origins; empty list = CORS disabled)
@@ -1160,6 +1106,13 @@ class APIServer:
             "apiserver_watch_write_frames",
             "Frames per fan-out write (write-coalescing depth)",
             buckets=(1, 2, 4, 8, 16, 32, 64, 128))
+        self.metric_fanout_wakes = self.metrics_registry.counter(
+            "apiserver_watch_fanout_wakes_total",
+            "Wakes of the watch writer that wrote to at least one stream")
+        self.metric_fanout_streams_written = self.metrics_registry.counter(
+            "apiserver_watch_fanout_streams_written_total",
+            "Streams the watch writer wrote a batch to, one per stream a "
+            "wake")
         self.metric_batch_bind_size = self.metrics_registry.histogram(
             "apiserver_batch_bind_size",
             "Bindings per bindings:batch request",
@@ -1199,6 +1152,9 @@ class APIServer:
             float(share.worker_index) if share is not None else -1.0)
         self._watchers: set = set()
         self._watch_lock = threading.Lock()
+        # one thread writes every chunked watch stream, started with the
+        # first one
+        self.watch_writer = WatchWriter(self)
         # Encode-once fan-out caches (one lock guards both):
         #  _wire_cache:  (resourceVersion, wire version) -> the object's
         #      wire JSON string. The store's modified_index is globally
@@ -1267,6 +1223,7 @@ class APIServer:
             watchers = list(self._watchers)
         for w in watchers:
             w.stop()
+        self.watch_writer.stop()
         self._httpd.shutdown()
         self._httpd.server_close()
         if self._thread:
@@ -1512,6 +1469,39 @@ class APIServer:
                 w.set()
         finally:
             self._share_drain_lock.release()
+
+    def translate_batch(self, batch, translate, version, ws_frames: bool):
+        """Map one drained batch of raw store events to wire byte parts.
+        Returns (parts, lagged): ``lagged`` means the bounded-lag resync
+        marker was hit — its 410 ERROR frame is the last part and the
+        stream must end. The encode (if any) happens here exactly once
+        per (revision, version); every other watcher of the same event
+        copies cached bytes."""
+        idx = 2 if ws_frames else 1
+        parts = []
+        for ev in batch:
+            if ev.type == watchpkg.ERROR and ev.object is None:
+                # bounded-lag drop-to-resync marker from the store layer
+                parts.append(self.lag_resync_entry(version)[idx])
+                self.metric_watch_lag_drops.inc()
+                return parts, True
+            try:
+                tev = translate(ev)
+                if tev is None:
+                    continue
+                if isinstance(tev, tuple):  # fast path: (type, rv, thunk)
+                    ev_type, rv, thunk = tev
+                    parts.append(
+                        self.frame_entry(ev_type, thunk, version,
+                                         rv=rv)[idx])
+                else:
+                    parts.append(self.frame_entry(tev.type, tev.object,
+                                                  version)[idx])
+            except Exception as e:  # undecodable payload: surface, keep going
+                parts.append(self.frame_entry(
+                    watchpkg.ERROR,
+                    errors.new_internal_error(str(e)).status, version)[idx])
+        return parts, False
 
     def event_frame(self, ev, version: str) -> str:
         """One JSON watch frame per (object revision, event type, wire

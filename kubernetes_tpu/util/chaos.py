@@ -37,7 +37,7 @@ from typing import Dict, Optional
 __all__ = ["SimulatedCrash", "inject_crash", "inject_error",
            "inject_delay", "inject_flag", "inject_gate", "release_gate",
            "crash_if_armed", "error_if_armed", "delay_if_armed",
-           "take_flag", "gate_if_armed", "armed", "clear",
+           "take_flag", "gate_armed", "armed", "clear",
            "parse_duration"]
 
 
@@ -109,12 +109,12 @@ def inject_flag(point: str, skip: int = 0, times: int = 1) -> None:
 
 
 def inject_gate(point: str) -> None:
-    """Arm ``point`` as a blocking gate: the next thread reaching it
-    parks until ``release_gate(point)`` (or ``clear()``) — the
-    deterministic replacement for "hope the reader is slow enough". A
-    gated watch writer, for example, stops draining its fan-out queue so
-    the producer-side lag machinery (coalesce / drop-to-resync) fires on
-    exact queue depth instead of on kernel socket-buffer luck."""
+    """Arm ``point`` as a gate: the seam holds its work back
+    (``gate_armed``) until ``release_gate(point)`` (or ``clear()``) — the
+    deterministic replacement for "hope the reader is slow enough". The
+    watch writer, for example, stops draining a gated stream's fan-out
+    queue so the producer-side lag machinery (coalesce / drop-to-resync)
+    fires on exact queue depth instead of on kernel socket-buffer luck."""
     _arm(point, "gate", 0, 1, payload=threading.Event())
 
 
@@ -176,19 +176,18 @@ def take_flag(point: str) -> bool:
     return _take(point, "flag") is not None
 
 
-def gate_if_armed(point: str, timeout: float = 30.0) -> None:
-    """Park on an armed gate until released. The wait happens outside
-    the registry lock (release/clear must be able to run), with a safety
-    timeout so a test that forgets to release cannot hang a suite."""
+def gate_armed(point: str) -> bool:
+    """True while ``point`` is an armed gate: the seam holds its work back
+    and looks again later, never blocking (the watch writer serves every
+    stream from one thread, so it skips a gated stream)."""
     if not _arms:
-        return
+        return False
     with _lock:
         a = _arms.get(point)
         if a is None or a.kind != "gate":
-            return
+            return False
         a.hits += 1
-        ev = a.payload
-    ev.wait(timeout)
+        return True
 
 
 def armed(point: str) -> Optional[dict]:

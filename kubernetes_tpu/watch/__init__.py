@@ -20,6 +20,11 @@ its consumer falls behind:
    marker). Clients handle it with the Reflector contract: re-list and
    re-watch from the fresh resourceVersion.
 
+A consumer that serves many watchers from one thread (the apiserver's
+watch writer) sets a ready hook (``set_ready_hook``) and takes batches
+with ``drain``, which never blocks: the hook runs on the first send, or
+the stream's end, after the consumer's last ``drain``.
+
 Both degradations are counted (``watch_events_coalesced_total``,
 ``watch_lag_resyncs_total``) so fan-out loss is observable, never
 silent; plain bounded watchers that overflow count
@@ -33,7 +38,7 @@ import queue
 import threading
 import time
 from dataclasses import dataclass
-from typing import Any, Callable, Iterator, List, Optional
+from typing import Any, Callable, Iterator, List, Optional, Tuple
 
 from kubernetes_tpu.util import interpprobe
 
@@ -102,6 +107,8 @@ class Watcher:
         self._coalesce = coalesce
         self._lagged = False
         self._warned_drop = False
+        self._on_ready = None
+        self._ready_marked = False
 
     # producer side -------------------------------------------------------
     def send(self, event: Event, timeout: Optional[float] = None) -> bool:
@@ -110,15 +117,30 @@ class Watcher:
         if self._lag_limit is not None \
                 and self._q.qsize() >= self._lag_limit:
             if self._coalesce is not None and self._try_coalesce(event):
+                self._ready()
                 return True
             self.drop_to_resync()
             return False
         try:
             self._q.put(event, timeout=timeout)
-            return True
         except queue.Full:
             self._count_drop()
             return False
+        self._ready()
+        return True
+
+    def set_ready_hook(self, hook: Callable[["Watcher"], None]) -> None:
+        """``hook(self)`` runs on the first send, or end of stream, after
+        the consumer's last ``drain`` (and on none while the mark stands):
+        once a batch, never once an event."""
+        self._on_ready = hook
+
+    def _ready(self) -> None:
+        # the mark is cleared by drain() BEFORE it takes the queue: a send
+        # that lands after the take finds it clear and marks again
+        if self._on_ready is not None and not self._ready_marked:
+            self._ready_marked = True
+            self._on_ready(self)
 
     def _count_drop(self) -> None:
         _watch_metrics().dropped.inc()
@@ -179,6 +201,7 @@ class Watcher:
             self._q.queue.append(Event(ERROR, None))
             self._q.queue.append(_SENTINEL)
             self._q.not_empty.notify_all()
+        self._ready()
 
     @property
     def lagged(self) -> bool:
@@ -196,6 +219,7 @@ class Watcher:
         while True:
             try:
                 self._q.put_nowait(_SENTINEL)
+                self._ready()
                 return
             except queue.Full:
                 try:
@@ -265,6 +289,25 @@ class Watcher:
                 break
             out.append(ev)
         return out
+
+    def drain(self, max_items: int = 128) -> Tuple[List[Event], bool]:
+        """Up to ``max_items`` queued events without blocking, and whether
+        the stream has ended after them — a hooked consumer's unit of work.
+        Clears the ready mark first (see ``set_ready_hook``)."""
+        self._ready_marked = False
+        out: List[Event] = []
+        with self._q.mutex:
+            dq = self._q.queue
+            while dq and len(out) < max_items and dq[0] is not _SENTINEL:
+                out.append(dq.popleft())
+            ended = bool(dq) and dq[0] is _SENTINEL
+            if out:
+                self._q.not_full.notify_all()
+        return out, ended
+
+    def queued(self) -> int:
+        """Events (and the end marker) waiting in the queue."""
+        return len(self._q.queue)
 
     def __iter__(self) -> Iterator[Event]:
         while True:
