@@ -562,20 +562,21 @@ class IncrementalEncoder:
 
     def group_cap(self, n_nodes: Optional[int] = None) -> int:
         """The most group rows the Pallas kernel takes at this cluster's
-        size beside the encoder's other vocabularies."""
+        size beside the encoder's other vocabularies and its policy's
+        planes."""
         from kubernetes_tpu.ops import pallas_solver
         return pallas_solver.max_groups(
             self._N if n_nodes is None else n_nodes,
             max(2, len(self._resource_names)),
-            self._ports.cap // 32 or 1, self._pds.cap // 32 or 1)
+            self._ports.cap // 32 or 1, self._pds.cap // 32 or 1,
+            pol=self.policy)
 
     def group_cut(self, pending: Sequence[api.Pod],
-                  services: Sequence[api.Service], n_nodes: int) -> int:
+                  services: Sequence[api.Service], cap: int) -> int:
         """How many of ``pending``, from the front, one wave may hold: the
-        longest prefix whose pods name no more distinct groups than the
-        kernel takes rows. All of them wherever there is no service."""
-        if not services or \
-                len(pending) <= (cap := self.group_cap(n_nodes)):
+        longest prefix whose pods name no more than ``cap`` (``group_cap``)
+        distinct groups. All of them wherever there is no service."""
+        if not services or len(pending) <= cap:
             return len(pending)
         self._sync_services(list(services))
         match = self._index.match
@@ -965,8 +966,6 @@ class IncrementalEncoder:
                     if row is not None:
                         member[j, row] = True
             group_counts = np.zeros((G, N + 1), np.int32)
-            A = self._node_zone.shape[0]
-            zone_counts0 = np.zeros((A, G, self._zone_V), np.int32)
             peered = np.zeros(G, bool)
             for key, row in rows.items():
                 at = self._peers.get(key)
@@ -974,14 +973,29 @@ class IncrementalEncoder:
                     peered[row] = True
                     group_counts[row, np.fromiter(at, np.int64, len(at))] = \
                         np.fromiter(at.values(), np.int32, len(at))
-                    zones = self._zone_peers.get(key)
-                    if zones is not None:
-                        zone_counts0[:, row, :] = zones
+            zone_counts0 = self._wave_zones(rows, G)
             wave_groups().inc(by=len(rows))
             if named:
                 peered_pods().inc(
                     by=int(peered[[pod_gid[j] for j, _, _ in named]].sum()))
         return pod_gid, member, group_counts, zone_counts0, rows
+
+    def _wave_zones(self, rows: Dict[Tuple[int, int], int],
+                    G: int) -> np.ndarray:
+        """The anti-affinity zone counts of one wave's group rows, from each
+        group's peers by zone (``_zone_delta``) -> zone_counts0 [A, G, V];
+        its own span under an anti-affinity policy only."""
+        A = self._node_zone.shape[0]
+        zone_counts0 = np.zeros((A, G, self._zone_V), np.int32)
+        if not A:
+            return zone_counts0
+        with tracing.phase("wave.encode.zones", metrics.wave_parts(),
+                           "encode.zones"):
+            for key, row in rows.items():
+                zones = self._zone_peers.get(key)
+                if zones is not None:
+                    zone_counts0[:, row, :] = zones
+        return zone_counts0
 
     def _wave_anchors(self, pending_pods, pod_gid: np.ndarray,
                       rows: Dict[Tuple[int, int], int], G: int, Ppad: int):

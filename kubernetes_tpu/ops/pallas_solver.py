@@ -27,10 +27,11 @@ solve_jit), CheckNodeLabelPresence (static mask), CheckServiceAffinity
 flag lane-replicated in a sibling scratch so commits need no cross-lane
 broadcast), LeastRequested/ServiceSpreading/Equal priorities,
 NodeLabelPriority (static additive plane), and ServiceAntiAffinity
-(V-deep zone reduction planes) — int32 resource waves. Gang (PodGroup
-all-or-nothing) waves are in-domain: the kernel checkpoints the committed
-state (including anchors) at each scheduling-unit start and a failing
-member rolls the whole run back — solve_jit's gang_step, with the
+(one zone-index plane a label; each value's mask built in the kernel and
+the label folded over its own value count) — int32 resource waves. Gang
+(PodGroup all-or-nothing) waves are in-domain: the kernel checkpoints the
+committed state (including anchors) at each scheduling-unit start and a
+failing member rolls the whole run back — solve_jit's gang_step, with the
 checkpoint in a second set of VMEM planes. Fallbacks to the XLA scan:
 waves whose counts could reach 2^15 (the limb domains), >32640 nodes,
 >4 affinity labels, more group rows than ``max_groups`` (membership rides
@@ -78,8 +79,8 @@ _MAX_W = 8
 _MAX_G = 256       # group rows a wave may carry: 9 mask lanes of 31 bits
 _MAX_N = 32640     # tie-break/limb domains need counts < 2^15
 _MAX_COUNT = 1 << 15
-_MAX_A = 4         # anti-affinity labels carried as V-deep zone planes
-_MAX_V = 64
+_MAX_A = 4         # anti-affinity labels, one zone-index plane each
+_FOLD = 4          # zone values folded a loop step (independent reductions)
 _MAX_L = 4         # ServiceAffinity labels riding podrow lanes 32..35
 _VMEM_BUDGET = 12 << 20   # leave headroom under the ~16MB per-core VMEM
 
@@ -95,9 +96,9 @@ def eligible(inp, pol: Optional[BatchPolicy], gangs: bool,
     must stay below 2^15 for the limb arithmetic. Gang waves are
     in-domain: the kernel carries a checkpoint copy of the committed
     state and rolls a failed run back, mirroring solve_jit's gang_step.
-    Zone anti-affinity is in-domain via per-zone reduction planes;
-    ServiceAffinity anchors live in two tiny [G, LANES] scratches;
-    NodeLabelPriority is one extra static plane."""
+    Zone anti-affinity is in-domain via one zone-index plane a label,
+    whatever its value count; ServiceAffinity anchors live in two tiny
+    [G, LANES] scratches; NodeLabelPriority is one extra static plane."""
     if pol is None:
         return False
     if pol.all_infeasible:
@@ -117,12 +118,10 @@ def eligible(inp, pol: Optional[BatchPolicy], gangs: bool,
             and inp.node_pds.shape[1] <= _MAX_W and G <= _MAX_G
             and N <= _MAX_N):
         return False
-    A = V = 0
+    A = 0
     if pol.anti_affinity:
         A = inp.zone_idx.shape[0]
-        V = inp.zone_counts0.shape[2]
-        if not (0 < A <= _MAX_A and V <= _MAX_V
-                and A == len(pol.anti_affinity)):
+        if not (0 < A <= _MAX_A and A == len(pol.anti_affinity)):
             return False
     L = 0
     if pol.has_affinity:
@@ -135,23 +134,13 @@ def eligible(inp, pol: Optional[BatchPolicy], gangs: bool,
     # every wave commit
     if peer_bound + inp.req.shape[0] >= _MAX_COUNT:
         return False
-    # VMEM budget: every node plane (inputs, scratch state, gang
-    # checkpoints, zone one-hots) is VMEM-resident; a wave that would
-    # exceed the ~16MB per-core VMEM must take the XLA scan instead of
-    # dying in a Mosaic RESOURCE_EXHAUSTED compile error
-    NR = max(1, -(-N // LANES))
+    # VMEM budget: a wave that would exceed the ~16MB per-core VMEM must
+    # take the XLA scan instead of dying in a Mosaic RESOURCE_EXHAUSTED
+    # compile error
     Wp, Wd = inp.node_ports.shape[1], inp.node_pds.shape[1]
-    state = 2 * R + Wp + Wd + G
-    planes = _wave_planes(R, Wp, Wd, G) + A * V + A  # + the zone planes
-    planes += L                                      # node_aff_vals planes
-    if pol.label_prefs:
-        planes += 1                                  # static score plane
-    if gangs:
-        planes += state + 1                          # checkpoint copy
-    anchors = 6 if pol.has_affinity else 0   # in+scratch+ckpt aff/has rows
-    if planes * NR * LANES * 4 + anchors * G * LANES * 4 > _VMEM_BUDGET:
-        return False
-    return True
+    return _vmem_bytes(N, R, Wp, Wd, G, L=L, A=A,
+                       label_prefs=bool(pol.label_prefs),
+                       gangs=gangs) <= _VMEM_BUDGET
 
 
 def _wave_planes(R: int, Wp: int, Wd: int, G: int) -> int:
@@ -162,20 +151,45 @@ def _wave_planes(R: int, Wp: int, Wd: int, G: int) -> int:
     return (state + R + 1) + state
 
 
-def max_groups(n_nodes: int, R: int = 2, Wp: int = 1, Wd: int = 1) -> int:
+def _vmem_bytes(N: int, R: int, Wp: int, Wd: int, G: int, *, L: int = 0,
+                A: int = 0, label_prefs: bool = False,
+                gangs: bool = False) -> int:
+    """The one VMEM account of a wave, which ``eligible`` and ``max_groups``
+    both read: every node plane (inputs, scratch state, gang checkpoints)
+    is VMEM-resident, beside one value plane a ServiceAffinity label, one
+    zone-index plane an anti-affinity label (whatever its value count: the
+    kernel folds a label's values from that plane), the static score plane
+    of label preferences, and the anchor rows of ServiceAffinity (input,
+    scratch and checkpoint copies of the values and the has-flags)."""
+    NR = max(1, -(-N // LANES))
+    planes = _wave_planes(R, Wp, Wd, G) + L + A
+    if label_prefs:
+        planes += 1
+    if gangs:
+        planes += 2 * R + Wp + Wd + G + 1            # checkpoint copy
+    anchors = 6 if L else 0
+    return planes * NR * LANES * 4 + anchors * G * LANES * 4
+
+
+def max_groups(n_nodes: int, R: int = 2, Wp: int = 1, Wd: int = 1,
+               pol: Optional[BatchPolicy] = None) -> int:
     """The most group rows (a power of two, 8 to ``_MAX_G``) that a wave
     without gangs may carry at ``n_nodes`` and stay inside ``eligible``'s
-    VMEM account, beside ``R`` resource dimensions and ``Wp`` / ``Wd`` port
-    and disk words: a counts row is a node plane twice over (the input and
-    the scratch it is committed into). What the wave loop cuts a wave at,
-    and the encoder's group bucket stops at; past ``_MAX_N`` nodes no wave
-    takes the kernel and only ``_MAX_G`` bounds the rows."""
+    VMEM account, beside ``R`` resource dimensions, ``Wp`` / ``Wd`` port
+    and disk words and the planes of ``pol`` (its ServiceAffinity labels,
+    anti-affinity labels and label preferences): a counts row is a node
+    plane twice over (the input and the scratch it is committed into).
+    What the wave loop cuts a wave at, and the encoder's group bucket stops
+    at; past ``_MAX_N`` nodes no wave takes the kernel and only ``_MAX_G``
+    bounds the rows."""
     if n_nodes > _MAX_N:
         return _MAX_G
-    NR = max(1, -(-n_nodes // LANES))
+    pol = pol or BatchPolicy()
+    sizes = dict(L=len(pol.affinity_labels), A=len(pol.anti_affinity),
+                 label_prefs=bool(pol.label_prefs))
     cap = 8
-    while cap * 2 <= _MAX_G and _wave_planes(R, Wp, Wd, cap * 2) \
-            * NR * LANES * 4 <= _VMEM_BUDGET:
+    while cap * 2 <= _MAX_G and _vmem_bytes(
+            n_nodes, R, Wp, Wd, cap * 2, **sizes) <= _VMEM_BUDGET:
         cap *= 2
     return cap
 
@@ -252,11 +266,11 @@ def _spread_score_i32(total, counts):
 
 
 def _make_kernel(P, NR, PR, R, Wp, Wd, G, pol: BatchPolicy,
-                 gangs: bool = False, V: int = 0, B: int = 1, L: int = 0):
+                 gangs: bool = False, B: int = 1, L: int = 0):
     """Build the kernel body for static shapes/policy. Argument order:
     inputs (smask, podrow, cap, fit0, score0, fitexc, ports0, pds0,
     counts0, offl, advx[, sstat when label-prefs][, affv, anchor0, has0
-    when service-affinity][, zones, zlab when anti-affinity]), outputs
+    when service-affinity][, zidx, nvals when anti-affinity]), outputs
     (chosen, win), scratches (fit, score, ports, pds, counts[, aff, has
     when service-affinity][, the matching ckpt_* copies and flags when
     gangs]).
@@ -275,7 +289,7 @@ def _make_kernel(P, NR, PR, R, Wp, Wd, G, pol: BatchPolicy,
                advx_ref, *rest):
         i = 0
         sstat_ref = affv_ref = anchor0_ref = has0_ref = None
-        zones_ref = zlab_ref = None
+        zidx_ref = nvals_ref = None
         if has_sstat:
             sstat_ref = rest[i]
             i += 1
@@ -283,7 +297,7 @@ def _make_kernel(P, NR, PR, R, Wp, Wd, G, pol: BatchPolicy,
             affv_ref, anchor0_ref, has0_ref = rest[i:i + 3]
             i += 3
         if A:
-            zones_ref, zlab_ref = rest[i], rest[i + 1]
+            zidx_ref, nvals_ref = rest[i], rest[i + 1]
             i += 2
         chosen_ref, win_ref = rest[i], rest[i + 1]
         i += 2
@@ -318,11 +332,11 @@ def _make_kernel(P, NR, PR, R, Wp, Wd, G, pol: BatchPolicy,
             failed = flags_ref[0, 0] != 0            # 0-d bool
         for b in range(B):
             failed = _pod_step(
-                p * B + b, b, pol, gangs, A, V, L, R, Wp, Wd, G, NR, PR,
+                p * B + b, b, pol, gangs, A, L, R, Wp, Wd, G, NR, PR,
                 w_lr, w_spread, w_equal,
                 smask_ref, podrow_ref, cap_ref, fitexc_ref, offl_ref,
                 advx_ref, sstat_ref, affv_ref,
-                zones_ref, zlab_ref,
+                zidx_ref, nvals_ref,
                 chosen_ref, win_ref, tuple(state_refs), aff_refs,
                 ckpt_refs if gangs else None,
                 failed if gangs else None)
@@ -333,10 +347,10 @@ def _make_kernel(P, NR, PR, R, Wp, Wd, G, pol: BatchPolicy,
     return kernel
 
 
-def _pod_step(p_global, b, pol, gangs, A, V, L, R, Wp, Wd, G, NR, PR,
+def _pod_step(p_global, b, pol, gangs, A, L, R, Wp, Wd, G, NR, PR,
               w_lr, w_spread, w_equal,
               smask_ref, podrow_ref, cap_ref, fitexc_ref, offl_ref,
-              advx_ref, sstat_ref, affv_ref, zones_ref, zlab_ref,
+              advx_ref, sstat_ref, affv_ref, zidx_ref, nvals_ref,
               chosen_ref, win_ref, state_refs, aff_refs, ckpt_refs, failed):
     """One pod's filter/score/select/commit against the live VMEM state.
     Returns the threaded gang failed-flag (None when not a gang wave)."""
@@ -445,21 +459,32 @@ def _pod_step(p_global, b, pol, gangs, A, V, L, R, Wp, Wd, G, NR, PR,
             max_count = jnp.maximum(jnp.max(counts_row), off)   # 0-d
             spread = _spread_score_i32(max_count, counts_row)
             score = score + spread * w_spread
-        for a, (_label, w) in enumerate(pol.anti_affinity):
+        if A:
             # ServiceAntiAffinity (spreading.go:104-168): per-zone peer
             # counts restricted to feasible nodes (the serial path scores
             # over the filtered list); num counts ALL peers, off-list
-            # included. V-deep reduction planes replace solve_jit's
-            # one-hot matmuls — exact int32 throughout.
+            # included. Exact int32 throughout.
             num = jnp.sum(counts_row) + off                     # 0-d
             c = counts_row * feasible.astype(jnp.int32)
-            cnt = jnp.zeros((NR, LANES), jnp.int32)
-            for v in range(V):
-                zv = zones_ref[a * V + v]                       # [NR,128]
-                zc_v = jnp.sum(zv * c)                          # 0-d
-                cnt = cnt + zv * zc_v
+        for a, (_label, w) in enumerate(pol.anti_affinity):
+            # each value's mask comes from the label's index plane, the
+            # label folded over its own value count (nvals, SMEM) in a
+            # loop of _FOLD values a step; a code past the label's last
+            # matches no node, so the step's overshoot folds nothing
+            zi = zidx_ref[a]                                    # [NR,128]
+
+            def fold(k, cnt, zi=zi):
+                for u in range(_FOLD):
+                    zv = zi == k * _FOLD + u
+                    zc_v = jnp.sum(jnp.where(zv, c, 0))         # 0-d
+                    cnt = jnp.where(zv, zc_v, cnt)
+                return cnt
+
+            cnt = jax.lax.fori_loop(
+                0, (nvals_ref[a] + _FOLD - 1) // _FOLD, fold,
+                jnp.zeros((NR, LANES), jnp.int32))
             s = _spread_score_i32(num, cnt)
-            s = s * (zlab_ref[a] != 0)
+            s = jnp.where(zi >= 0, s, 0)                        # labelled
             score = score + s * w
         if pol.label_prefs:
             # NodeLabelPriority: static additive plane (priorities.go:98-134)
@@ -576,6 +601,13 @@ def _member_lane(lane: int) -> int:
     return _MEMBER if lane == 0 else _MEMBER1 + lane - 1
 
 
+def _value_counts(zidx):
+    """Each anti-affinity label's own value count, from its [N] row of
+    zone codes (0.. in first-seen order, -1 unlabelled): the values the
+    kernel folds for that label."""
+    return jnp.max(zidx, axis=1) + 1
+
+
 def _pad_nodes(x, Npad, fill=0):
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, Npad - x.shape[-1])],
                    constant_values=fill)
@@ -628,7 +660,7 @@ def _x32_operands(inp, limbs) -> tuple:
             inp.node_extra_ok, inp.req, inp.pod_ports, inp.pod_sel,
             inp.pod_pds, inp.pod_host_idx, limbs, inp.pod_gid,
             inp.pod_group_member, inp.group_counts, inp.gang_start,
-            inp.zone_idx, inp.zone_counts0,
+            inp.zone_idx,
             inp.score_static, inp.node_aff_vals, inp.pod_aff_static,
             inp.anchor_vals0, inp.has_anchor0)
 
@@ -639,7 +671,7 @@ def _solve_pallas_x32(cap_in, advertises, fit_used, fit_exceeded,
                       score_used, node_ports, node_sel, node_pds,
                       node_extra_ok, req_in, pod_ports, pod_sel, pod_pds,
                       pod_host_idx, tie_limbs, pod_gid, pod_group_member,
-                      group_counts, gang_start, zone_idx, zone_counts0,
+                      group_counts, gang_start, zone_idx,
                       score_static, node_aff_vals, pod_aff_static,
                       anchor_vals0, has_anchor0,
                       *, pol: BatchPolicy, interpret: bool, gangs: bool,
@@ -741,25 +773,19 @@ def _solve_pallas_x32(cap_in, advertises, fit_used, fit_exceeded,
         podrow = podrow.at[:, _AFF0:_AFF0 + L].set(
             pod_aff_static.astype(jnp.int32))
 
-    # ---- zone planes for anti-affinity ([A*V, NR, 128] i32 one-hots) -----
-    # The kernel consumes per-zone reduction planes; they are derived ON
-    # DEVICE from the compact [A, N] zone-index plane once per wave (the
-    # wire/encoder no longer materializes an [A, N, V] one-hot).
+    # ---- anti-affinity: the [A, N] zone-index plane, -1 unlabelled ------
+    # (padding nodes too), and each label's own value count, to SMEM: the
+    # kernel builds each value's mask from the index plane, so VMEM holds
+    # one plane a label whatever its vocabulary
     A = len(pol.anti_affinity)
-    V = zone_counts0.shape[2] if A else 0
     zone_args, zone_specs = [], []
     if A:
         zidx = zone_idx.astype(jnp.int32)              # [A, N]
-        zones = (zidx[:, None, :] ==
-                 jnp.arange(V, dtype=jnp.int32)[None, :, None]
-                 ).astype(jnp.int32).reshape(A * V, N)
-        zones = _pad_nodes(zones, Npad, 0).reshape(A * V, NR, LANES)
-        zlab = _pad_nodes((zidx >= 0).astype(jnp.int32), Npad, 0)
-        zlab = zlab.reshape(A, NR, LANES)
-        zone_args = [zones, zlab]
-        zone_specs = [pl.BlockSpec((A * V, NR, LANES),
-                                   lambda p: (0, 0, 0)),
-                      pl.BlockSpec((A, NR, LANES), lambda p: (0, 0, 0))]
+        nvals = _value_counts(zidx)                    # [A]
+        zidx = _pad_nodes(zidx, Npad, -1).reshape(A, NR, LANES)
+        zone_args = [zidx, nvals]
+        zone_specs = [pl.BlockSpec((A, NR, LANES), lambda p: (0, 0, 0)),
+                      pl.BlockSpec(memory_space=pltpu.SMEM)]
 
     # B pods per grid step (strictly in pod order): padding rows get an
     # all-zero static mask, so they are infeasible everywhere, commit
@@ -771,7 +797,7 @@ def _solve_pallas_x32(cap_in, advertises, fit_used, fit_exceeded,
         smask = jnp.pad(smask, ((0, Ppad - P), (0, 0), (0, 0)))
         podrow = jnp.pad(podrow, ((0, Ppad - P), (0, 0)))
 
-    kernel = _make_kernel(P, NR, PR, R, Wp, Wd, G, pol, gangs, V, B, L)
+    kernel = _make_kernel(P, NR, PR, R, Wp, Wd, G, pol, gangs, B, L)
     state_shapes = [
         pltpu.VMEM((R, NR, LANES), jnp.int32),   # fit
         pltpu.VMEM((R, NR, LANES), jnp.int32),   # score_used

@@ -101,6 +101,10 @@ class _WaveMetrics:
             "Waves cut short because their pods named more distinct "
             "service groups than the kernel takes rows; the pods cut off "
             "head the next wave")
+        self.group_cap_rows = reg.counter(
+            "scheduler_wave_group_cap_rows_total",
+            "The most group rows the kernel takes (the cap a wave is cut "
+            "at, IncrementalEncoder.group_cap), added once a wave")
         self.resyncs = reg.counter(
             "scheduler_wave_encode_resyncs_total",
             "Full-list encoder syncs (vs O(changed) delta waves)")
@@ -394,7 +398,9 @@ class BatchScheduler:
         (``IncrementalEncoder.group_cut``). The pods cut off head the next
         wave. A wave with a PodGroup is left whole (its members must meet
         the quorum gate together); past the cap it takes the scan."""
-        keep = self._encoder.group_cut(pods, services, n_nodes)
+        cap = self._encoder.group_cap(n_nodes)
+        _wave_metrics().group_cap_rows.inc(by=cap)
+        keep = self._encoder.group_cut(pods, services, cap)
         if keep >= len(pods) or \
                 any(gang.gang_key(p) is not None for p in pods):
             return pods

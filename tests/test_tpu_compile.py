@@ -126,12 +126,11 @@ def test_kernel_compiles_affinity_policy(one_chip, no_persistent_cache):
     _compile_kernel(snap, host, one_chip)
 
 
-def test_kernel_compiles_policy_cell_wave(one_chip, no_persistent_cache):
-    """A wave of ``policy-5000n-backlog`` as the incremental encoder builds
-    it: 5,000 nodes in two regions of three zones, 256 group rows (the cap
-    at 5,000 nodes) under OpenShift's default Policy — one ServiceAffinity
-    label's value plane and anchor scratches, one zone plane of six values,
-    beside the group rows, the thinnest VMEM margin a cell runs."""
+def _cell_wave(config_name):
+    """(snapshot, host SolverInputs) of one wave of a Policy cell as the
+    incremental encoder builds it: the configuration's 5,000 labelled
+    nodes, 256 pods of 256 services (the cap at 5,000 nodes), under the
+    configuration's Policy."""
     import json
     import os
 
@@ -142,7 +141,7 @@ def test_kernel_compiles_policy_cell_wave(one_chip, no_persistent_cache):
 
     with open(os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "benchmarks", "configs",
-            "sched-policy-5000n.json")) as f:
+            config_name + ".json")) as f:
         config = json.load(f)
     pools = [t for t in config["node_templates"] for _ in range(t["count"])]
     nodes = [api.Node(
@@ -168,9 +167,31 @@ def test_kernel_compiles_policy_cell_wave(one_chip, no_persistent_cache):
     snap = IncrementalEncoder(pol).encode(nodes, [], pending, services)
     host = bs.snapshot_to_host_inputs(snap)
     assert host.group_counts.shape[0] == 256 == \
-        pallas_solver.max_groups(len(nodes))
+        pallas_solver.max_groups(len(nodes), pol=pol)
+    return snap, host
+
+
+def test_kernel_compiles_policy_cell_wave(one_chip, no_persistent_cache):
+    """A wave of ``policy-5000n-backlog``: 5,000 nodes in two regions of
+    three zones, 256 group rows under OpenShift's default Policy — one
+    ServiceAffinity label's value plane and anchor scratches, one zone
+    label of six values, beside the group rows."""
+    snap, host = _cell_wave("sched-policy-5000n")
     assert host.node_aff_vals.shape == (5_000, 1)
     assert host.zone_counts0.shape == (1, 256, 6)
+    _compile_kernel(snap, host, one_chip)
+
+
+def test_kernel_compiles_topology_cell_wave(one_chip, no_persistent_cache):
+    """A wave of ``topology-5000n-backlog``: 5,000 nodes in 2 regions, 6
+    zones and 126 racks, 256 group rows under the region/zone -> rack
+    topology Policy — the region's and the zone's value planes and anchor
+    scratches, one zone-index plane whose label the kernel folds over 126
+    values, beside the group rows: the thinnest VMEM margin a cell runs."""
+    snap, host = _cell_wave("sched-topology-5000n")
+    assert host.node_aff_vals.shape == (5_000, 2)
+    assert host.zone_counts0.shape == (1, 256, 126)
+    assert [int(z.max()) + 1 for z in host.zone_idx] == [126]
     _compile_kernel(snap, host, one_chip)
 
 
